@@ -185,7 +185,10 @@ def parse_serve_flags(argv=None):
     ap.add_argument("--draft_num_layers", type=int, default=2)
     # telemetry
     ap.add_argument("--metrics_log", type=str, default="")
-    ap.add_argument("--compilation_cache_dir", type=str, default="")
+    ap.add_argument("--compilation_cache_dir", type=str, default="",
+                    help="explicit compile-cache location; empty = "
+                    "$JAX_COMPILATION_CACHE_DIR if set, else "
+                    "<checkout>/.jax_cache (tpukit/cache.py)")
     return ap.parse_args(argv)
 
 
@@ -208,6 +211,7 @@ def main(argv=None):
 
     from tpukit import checkpoint as ckpt_lib
     from tpukit import reshard as reshard_lib
+    from tpukit.cache import enable_compilation_cache
     from tpukit.data import get_tokenizer
     from tpukit.mesh import create_mesh, initialize_runtime, is_process_zero
     from tpukit.model import GPTConfig
@@ -223,10 +227,7 @@ def main(argv=None):
     from tpukit.train import TrainState, create_train_state, make_optimizer
 
     initialize_runtime()
-    if flags.compilation_cache_dir:
-        from tpukit.cache import enable_compilation_cache
-
-        enable_compilation_cache(flags.compilation_cache_dir)
+    enable_compilation_cache(flags.compilation_cache_dir)
 
     tokenizer = get_tokenizer()
     tokenizer.pad_token_id = 2  # every recipe pins pad to 2 (main-single.py:23)
@@ -494,7 +495,9 @@ def main(argv=None):
             print(f"serve telemetry -> {flags.metrics_log} "
                   f"(render: python tools/report.py {flags.metrics_log})")
     logger.close()
-    return 0
+    # like the training recipes' FitResult: what came out, for a caller that
+    # drives main(argv) in-process (chip_smoke.py); run_recipe ignores it
+    return completions
 
 
 def _apply_request_knobs(requests, flags):
@@ -580,20 +583,35 @@ def _run_fleet_procs(flags, cfg, tokenizer, buckets) -> int:
 
     from tpukit import chaos as chaos_lib
     from tpukit.obs import FlightRecorder, StepLogger
-    from tpukit.serve import ProcessFleet, synthetic_request_stream
+    from tpukit.serve import (
+        ProcessFleet,
+        local_tpu_chips,
+        synthetic_request_stream,
+        worker_chip_env,
+    )
 
     if not flags.fleet_dir:
         raise ValueError(
             "--fleet_procs requires --fleet_dir: the ledger directory is "
             "the only channel between supervisor and worker processes"
         )
+    # One process per chip: this supervisor never initialises a backend,
+    # and worker i is bound to chip i by the TPU runtime's own environment.
+    # Built for every worker BEFORE any spawn, so more workers than chips
+    # is a named error here, not a hang later.
+    n_chips = local_tpu_chips()
+    chip_env = [worker_chip_env(i, flags.replicas, n_chips)
+                for i in range(flags.replicas)]
+    print(f"process fleet: {flags.replicas} worker process(es); "
+          + (f"{n_chips} TPU chip(s) on this host, worker i bound to chip i"
+             if n_chips else "no TPU on this host, nothing to bind"))
     logger = StepLogger(flags.metrics_log)
     recorder = FlightRecorder()
 
     def spawn(idx):
         argv = ([sys.executable, sys.argv[0]] + list(sys.argv[1:])
                 + ["--fleet_worker", str(idx)])
-        return subprocess.Popen(argv, env=dict(os.environ))
+        return subprocess.Popen(argv, env={**os.environ, **chip_env[idx]})
 
     requests = _apply_request_knobs(
         synthetic_request_stream(

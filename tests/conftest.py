@@ -15,22 +15,17 @@ if "xla_force_host_platform_device_count" not in flags:
 
 import jax  # noqa: E402
 
-# Belt and braces: if a pytest plugin imported jax before this conftest, the
-# env var alone is too late, but the config flag still wins as long as no
+# Belt and braces: if anything imported jax before this conftest, the env
+# var alone is too late, but the config flag still wins as long as no
 # backend has been initialized yet.
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # jax < 0.5 has no jax_num_cpu_devices config option; the XLA_FLAGS
-    # host-platform device count set above covers those versions.
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
 jax.config.update("jax_threefry_partitionable", True)
 
 # Persistent compile cache: repeat test runs skip recompilation.
-_CACHE_DIR = os.path.join(os.path.dirname(__file__), os.pardir, ".jax_cache")
-jax.config.update("jax_compilation_cache_dir", os.path.abspath(_CACHE_DIR))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+from tpukit.cache import enable_compilation_cache  # noqa: E402
+
+enable_compilation_cache()
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402

@@ -27,15 +27,14 @@ def main() -> int:
     # params come from the SAME PRNGKey(1) stream, so the worker must draw
     # with the same threefry flavor or parity is dead on arrival
     jax.config.update("jax_threefry_partitionable", True)
-    cache = Path(__file__).resolve().parent.parent / ".jax_cache"
-    jax.config.update("jax_compilation_cache_dir", str(cache))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
 
+    from tpukit.cache import enable_compilation_cache
     from tpukit.data import WordTokenizer, synthetic_stories
     from tpukit.model import GPTConfig, init_params
     from tpukit.serve import ServeConfig, ServeEngine
     from tpukit.serve.ledger import serve_from_ledger
 
+    enable_compilation_cache()
     tok = WordTokenizer(synthetic_stories(64))
     cfg = GPTConfig(
         dim=32, head_dim=8, heads=4, num_layers=2, vocab_size=tok.vocab_size,

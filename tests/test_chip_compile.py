@@ -1,0 +1,283 @@
+"""What only the chip's compiler can say, asked from the CPU sandbox.
+
+Interpret mode runs a Pallas kernel as plain XLA, so it cannot see what
+Mosaic refuses: a block whose last two dims are neither (8, 128)-divisible
+nor whole, a relayout it has no rule for, a dynamic one-row store into a
+packed tile, more VMEM than a core has. Two of this repo's four kernel
+families passed every interpret-mode test and were refused by the v5e
+compiler. These cases compile each family for a DESCRIBED `v5e:2x2` device
+(jax.experimental.topologies — nothing runs, nothing is attached) at
+GPT-small head and lane widths and assert the kernel is in the module as a
+`tpu_custom_call`. Token counts are small: Mosaic's compile time follows the
+tile, and the whole file must stay under half a minute.
+
+A compile that passes is a compile, never a chip run — `python chip_smoke.py`
+is the chip run.
+
+The rest of the file pins the rules that keep a run from succeeding without
+the device: one helper decides "is this a TPU", an unknown TPU has no peak,
+the compile cache is placed from outside, one worker process per chip.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from tpukit.obs.xla import collective_bytes, kernel_calls
+from tpukit.ops import pallas_attention
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+HEADS, HEAD_DIM, DIM, VOCAB = 12, 64, 768, 50257  # GPT-small, GPT-2 vocab
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described devices of a v5e:2x2 host, with the persistent
+    compile cache off: a TPU executable written to it from here cannot be
+    read back without a chip, and the next compile would warn."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu in this environment
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {exc}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _flash(shard=None, masked=False):
+    from tpukit.ops.pallas_attention import flash_causal_attention
+
+    def fn(q, k, v, *mask):
+        def loss(q, k, v):
+            out = flash_causal_attention(
+                q, k, v, scale=HEAD_DIM**-0.5, shard=shard,
+                pad_mask=mask[0] if masked else None,
+            )
+            return jnp.sum(out.astype(F32))
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    return fn
+
+
+def _head_ce(train, shard=None):
+    from tpukit.ops.fused_head_ce import fused_head_ce
+
+    def fn(h, w, t):
+        if not train:
+            return fused_head_ce(h, w, t, VOCAB, with_accuracy=True, shard=shard)
+        loss = lambda h, w: fused_head_ce(h, w, t, VOCAB, shard=shard)[0]  # noqa: E731
+        return jax.value_and_grad(loss, argnums=(0, 1))(h, w)
+
+    return fn
+
+
+def _grouped_ffn(xs, wu, bu, wd, bd, offsets):
+    from tpukit.ops.moe_gemm import grouped_ffn
+
+    loss = lambda *bank: jnp.sum(grouped_ffn(*bank, offsets).astype(F32))  # noqa: E731
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(xs, wu, bu, wd, bd)
+
+
+def _paged(*operands):
+    from tpukit.ops.paged_attention import paged_attend
+
+    return paged_attend(*operands)
+
+
+def _cases():
+    """name -> (fn(mesh), [(shape, dtype, spec)], kernels expected). `spec`
+    is the operand's PartitionSpec on the 2 x 2 (data, model) mesh; the
+    single-device cases use one device and `P()` throughout."""
+    b, s, n = 2, 1024, 512
+    v_pad = -(-VOCAB // 128) * 128
+    qkv = ((b, HEADS, s, HEAD_DIM), BF16)
+    head = [((n, DIM), BF16, P()), ((DIM, v_pad), BF16, P()), ((n,), I32, P())]
+    m, d, f, e = 128, 256, 1024, 8  # the bench's e8 bank, one small row block
+    bank = [((m, d), BF16), ((e, d, f), BF16), ((e, f), BF16),
+            ((e, f, d), BF16), ((e, d), BF16), ((e + 1,), I32)]
+    pages, page, mp, slots = 129, 16, 16, 8
+    nb = page * HEAD_DIM // 256
+    pool = lambda dt: [((pages, HEADS, page, HEAD_DIM), dt, P())] * 2  # noqa: E731
+    slot = [((slots, mp), I32, P()), ((slots,), I32, P())] + [
+        ((slots, HEADS, HEAD_DIM), BF16, P())] * 3
+    scales = [((pages, HEADS, nb), F32, P())] * 2
+    sharded = P("data", "model", None, None)
+    return {
+        "flash_unmasked": (lambda mesh: _flash(), [(*qkv, P())] * 3,
+                           ("flash_fwd", "flash_bwd")),
+        "flash_masked": (lambda mesh: _flash(masked=True),
+                         [(*qkv, P())] * 3 + [((b, s), jnp.bool_, P())],
+                         ("flash_fwd", "flash_bwd")),
+        # under a GSPMD jit the kernels run per shard through an explicit
+        # shard_map: libtpu cannot compile custom_partitioning
+        "flash_sharded_data_x_model": (
+            lambda mesh: _flash(shard=(mesh, "data", "model")),
+            [(*qkv, sharded)] * 3, ("flash_fwd", "flash_bwd")),
+        "head_ce_train": (lambda mesh: _head_ce(True), head,
+                          ("head_ce_fwd", "head_ce_bwd")),
+        "head_ce_eval": (lambda mesh: _head_ce(False), head, ("head_ce_fwd",)),
+        "head_ce_train_sharded_tokens": (
+            lambda mesh: _head_ce(True, shard=(mesh, ("data", "model"))),
+            [(head[0][0], BF16, P(("data", "model"), None)), head[1],
+             (head[2][0], I32, P(("data", "model")))],
+            ("head_ce_fwd", "head_ce_bwd")),
+        "grouped_ffn": (lambda mesh: _grouped_ffn, [(*x, P()) for x in bank],
+                        ("moe_ffn_fwd", "moe_ffn_bwd")),
+        "paged_attend_bf16": (
+            lambda mesh: lambda pk, pv, *rest: _paged(pk, pv, None, None, *rest),
+            pool(BF16) + slot, ("paged_attend",)),
+        "paged_attend_int8": (lambda mesh: _paged, pool(I8) + scales + slot,
+                              ("paged_attend",)),
+    }
+
+
+@pytest.mark.parametrize("name", list(_cases()))
+def test_v5e_compiles(v5e, monkeypatch, name):
+    make_fn, operands, expect = _cases()[name]
+    # steer the kernels to the chip's compiler: THE one helper, no option
+    monkeypatch.setattr(pallas_attention, "on_tpu_backend", lambda: True)
+    if any(spec != P() for _, _, spec in operands):
+        mesh = Mesh(np.array(v5e).reshape(2, 2), ("data", "model"))
+        place = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+    else:
+        mesh, one = None, SingleDeviceSharding(v5e[0])
+        place = lambda spec: one  # noqa: E731
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=place(spec))
+        for shape, dtype, spec in operands
+    ]
+    compiled = jax.jit(make_fn(mesh)).lower(*args).compile()
+    kernels = kernel_calls(compiled.as_text())
+    assert set(expect) <= set(kernels), kernels
+
+
+# ---------------------------------------------------------------------------
+# Nothing hides the device
+# ---------------------------------------------------------------------------
+
+
+class _Device:
+    def __init__(self, platform):
+        self.platform = platform
+
+
+def test_interpret_mode_is_cpu_only(monkeypatch):
+    assert pallas_attention._interpret() is True  # the CPU test backend
+    monkeypatch.setattr(jax, "devices", lambda: [_Device("tpu")])
+    assert pallas_attention.on_tpu_backend()
+    assert pallas_attention._interpret() is False
+    assert pallas_attention.tpu_compiler_params("parallel") is not None
+    monkeypatch.setattr(jax, "devices", lambda: [_Device("gpu")])
+    with pytest.raises(RuntimeError, match="neither"):
+        pallas_attention._interpret()
+
+
+@pytest.mark.parametrize(
+    "kind,peak",
+    [("TPU v5 lite", 197e12), ("cpu", None), ("TPU v5 lite pod", ValueError),
+     ("TPU v9", ValueError)],
+)
+def test_peak_flops_keys_on_exact_device_kind(kind, peak):
+    from tpukit.obs import peak_flops_per_chip
+
+    if peak is ValueError:
+        with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+            peak_flops_per_chip(kind)
+    else:
+        assert peak_flops_per_chip(kind) == peak
+
+
+def test_tpu_module_text_parses():
+    """A TPU module prints tiled layouts; the HLO IR and the kernel census
+    must read through them (both found nothing before)."""
+    text = """\
+ENTRY %main.1 (p: f32[512,512]) -> f32[512,512] {
+  %p = f32[512,512]{1,0:T(8,128)} parameter(0)
+  %jvp_flash_fwd_.3 = (bf16[24,1024,64]{2,1,0:T(8,128)(2,1)S(1)}, f32[24,1,1024]{2,1,0:T(1,128)}) custom-call(%p), custom_call_target="tpu_custom_call", backend_config={"x":1}
+  %head_ce_bwd = f32[8,8]{1,0:T(8,128)} custom-call(%p), custom_call_target="tpu_custom_call"
+  ROOT %all-reduce = f32[512,512]{1,0:T(8,128)} all-reduce(%p), channel_id=1, replica_groups=[1,4]<=[4], to_apply=%add
+}
+"""
+    assert kernel_calls(text) == {"flash_fwd": 1, "head_ce_bwd": 1}
+    assert collective_bytes(text) == {
+        "all-reduce": {"count": 1, "bytes": 512 * 512 * 4}
+    }
+
+
+@pytest.fixture()
+def cache_config():
+    """Hand the suite back the cache configuration it came with."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    if jax.config.jax_compilation_cache_dir != prev:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        cc.reset_cache()
+
+
+@pytest.mark.parametrize("env_dir", ["/somewhere/outside", None],
+                         ids=["env_set", "env_unset"])
+def test_compile_cache_is_placed_from_outside(
+    monkeypatch, tmp_path, cache_config, env_dir
+):
+    from tpukit import cache
+
+    assigned = []
+    update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, val: (assigned.append(name), update(name, val)),
+    )
+    monkeypatch.chdir(tmp_path)  # the rule must not look at the cwd
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        stats = cache.enable_compilation_cache()
+        # jax read the variable itself: tpukit assigns no directory
+        assert "jax_compilation_cache_dir" not in assigned
+        assert stats.cache_dir == env_dir
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        stats = cache.enable_compilation_cache()
+        assert stats.cache_dir == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == stats.cache_dir
+    assert "jax_persistent_cache_min_compile_time_secs" in assigned
+    assert not (tmp_path / ".jax_cache").exists()
+
+
+def test_fleet_workers_are_bound_one_per_chip():
+    from tpukit.serve import worker_chip_env
+
+    envs = [worker_chip_env(i, 4, 4) for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    assert all(e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    assert worker_chip_env(1, 2, 0) == {}  # no TPU on the host: nothing to bind
+    with pytest.raises(ValueError, match="needs 2 chips, this host has 1"):
+        worker_chip_env(0, 2, 1)
+
+
+def test_bench_reports_recorded_probe_errors():
+    import bench
+
+    clean = {"value": 1.0, "moe_error": None, "ladder": [{"shape": "a", "mfu": 0.4}]}
+    assert bench._recorded_errors(clean) == []
+    broken = {"long_context_error": "boom", "serving": {"error": "x"},
+              "ladder": [{"shape": "a"}, {"shape": "b", "error": "oom"}]}
+    assert bench._recorded_errors(broken) == [
+        "result.long_context_error", "result.serving.error",
+        "result.ladder[1].error",
+    ]

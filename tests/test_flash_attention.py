@@ -173,7 +173,7 @@ def test_multiblock_fused_and_split_backward(monkeypatch):
 def test_auto_dispatch_gspmd_safe():
     """Under GSPMD-sharded jit on a multi-device mesh, impl='auto' is
     sharded-correct (on the CPU test backend it picks the XLA path; on TPU
-    it picks the flash kernel, whose custom_partitioning rules the
+    it picks the flash kernel, whose per-shard call the
     test_flash_under_dp_mesh tests below exercise explicitly)."""
     import jax.sharding as jsh
 
@@ -210,7 +210,7 @@ def _dp_mesh():
 
 def test_flash_under_dp_mesh(qkv, pad_mask):
     """VERDICT r1 #2: the kernel must keep working when its operands are
-    GSPMD-sharded over a data mesh — the custom_partitioning rules run it
+    GSPMD-sharded over a data mesh — given the mesh axes (`shard`) it runs
     per-shard with no collectives and no all-gather."""
     import jax.sharding as jsh
 
@@ -223,7 +223,9 @@ def test_flash_under_dp_mesh(qkv, pad_mask):
 
     sh = jsh.NamedSharding(mesh, jsh.PartitionSpec("data"))
     fn = jax.jit(
-        lambda q, k, v, m: flash_causal_attention(q, k, v, scale=SCALE, pad_mask=m),
+        lambda q, k, v, m: flash_causal_attention(
+            q, k, v, scale=SCALE, pad_mask=m, shard=(mesh, "data", None)
+        ),
         in_shardings=(sh, sh, sh, sh),
     )
     out = fn(q, k, v, mask)
@@ -248,12 +250,15 @@ def test_flash_grads_under_dp_mesh(qkv):
     rng = np.random.RandomState(4)
     q, k, v = (jnp.asarray(rng.randn(8, H, S, D), jnp.float32) for _ in range(3))
 
-    def loss(q, k, v):
-        return jnp.sum(flash_causal_attention(q, k, v, scale=SCALE) ** 2)
+    def loss(q, k, v, shard=None):
+        return jnp.sum(
+            flash_causal_attention(q, k, v, scale=SCALE, shard=shard) ** 2
+        )
 
     g_ref = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     sh = jsh.NamedSharding(mesh, jsh.PartitionSpec("data"))
-    g_dp = jax.jit(jax.grad(loss, argnums=(0, 1, 2)), in_shardings=(sh, sh, sh))(q, k, v)
+    sharded = lambda q, k, v: loss(q, k, v, shard=(mesh, "data", None))  # noqa: E731
+    g_dp = jax.jit(jax.grad(sharded, argnums=(0, 1, 2)), in_shardings=(sh, sh, sh))(q, k, v)
     for a, b in zip(g_ref, g_dp):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5, rtol=1e-4)
 
@@ -262,7 +267,7 @@ def test_flash_inside_shard_map():
     """The pipeline recipes call attention inside a Manual shard_map region;
     the kernel must compose there as well."""
     import jax.sharding as jsh
-    from tpukit.compat import shard_map
+    from jax import shard_map
 
     mesh = _dp_mesh()
     rng = np.random.RandomState(5)

@@ -124,9 +124,9 @@ def test_argmax_tie_break_first_index():
 
 
 def test_token_sharded_grads_match_unsharded(setup):
-    """The custom_partitioning rules: with h/targets sharded over an
-    8-device data axis (and w replicated), loss and both grads equal the
-    unsharded result — the backward's dw psums local token partials."""
+    """The sharded call: with h/targets sharded over an 8-device data axis
+    (and w replicated), loss and both grads equal the unsharded result —
+    the backward's dw psums local token partials."""
     import tpukit.mesh as mesh_lib
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -137,15 +137,16 @@ def test_token_sharded_grads_match_unsharded(setup):
     h8, tgt8 = h[:n8], tgt[:n8]
     mesh = mesh_lib.create_mesh({"data": 8})
 
-    def loss(h, w, t):
-        s, c, _ = fused_head_ce(h, w, t, VOCAB)
+    def loss(h, w, t, shard=None):
+        s, c, _ = fused_head_ce(h, w, t, VOCAB, shard=shard)
         return s / jnp.maximum(c, 1.0)
 
     ref_l, ref_g = jax.value_and_grad(loss, argnums=(0, 1))(h8, w, tgt8)
     hs = jax.device_put(h8, NamedSharding(mesh, P("data", None)))
     ws = jax.device_put(w, NamedSharding(mesh, P(None, None)))
     ts = jax.device_put(tgt8, NamedSharding(mesh, P("data")))
-    sh_l, sh_g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(hs, ws, ts)
+    sharded = lambda h, w, t: loss(h, w, t, shard=(mesh, "data"))  # noqa: E731
+    sh_l, sh_g = jax.jit(jax.value_and_grad(sharded, argnums=(0, 1)))(hs, ws, ts)
     np.testing.assert_allclose(float(sh_l), float(ref_l), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(sh_g[0]), np.asarray(ref_g[0]), atol=1e-6)
     np.testing.assert_allclose(np.asarray(sh_g[1]), np.asarray(ref_g[1]), atol=1e-6)

@@ -25,13 +25,25 @@ def fresh_runtime(monkeypatch):
     mesh_lib._initialized = True  # never re-run real init in later tests
 
 
-def test_initialize_runtime_raises_on_explicit_coordinator(monkeypatch, fresh_runtime):
-    monkeypatch.setenv("JAX_COORDINATOR_ADDRESS", "10.0.0.1:1234")
+@pytest.mark.parametrize(
+    "var,value,match",
+    [
+        ("JAX_COORDINATOR_ADDRESS", "10.0.0.1:1234", "JAX_COORDINATOR_ADDRESS"),
+        # a DETECTED multi-host world (pod slice) must fail just as loudly
+        ("TPU_WORKER_HOSTNAMES", "host-0,host-1", "multi-host environment"),
+    ],
+    ids=["explicit_coordinator", "detected_pod"],
+)
+def test_initialize_runtime_raises_on_failed_rendezvous(
+    monkeypatch, fresh_runtime, var, value, match
+):
+    monkeypatch.delenv("JAX_COORDINATOR_ADDRESS", raising=False)
+    monkeypatch.setenv(var, value)
     monkeypatch.setattr(
         jax.distributed, "initialize",
         lambda *a, **k: (_ for _ in ()).throw(RuntimeError("connection refused")),
     )
-    with pytest.raises(RuntimeError, match="JAX_COORDINATOR_ADDRESS"):
+    with pytest.raises(RuntimeError, match=match):
         mesh_lib.initialize_runtime()
 
 
@@ -106,27 +118,23 @@ def test_tokenizer_padded_output_type_stable():
 
 
 # ---------------------------------------------------------------------------
-# Flash kernel warns when a sharding would force a sequence all-gather
-# (ADVICE r2 low #5)
+# Strategies name the mesh axes their GSPMD jit shards batch and heads over,
+# for the Pallas kernels' per-shard calls (no sharding is inferred: libtpu
+# cannot compile custom_partitioning)
 # ---------------------------------------------------------------------------
 
 
-def test_flash_batch_head_spec_warns_on_seq_sharding():
-    from tpukit.ops.pallas_attention import _batch_head_spec
+def test_strategy_kernel_shard_names_batch_and_head_axes(tiny_config):
+    from tpukit.shardings import DataParallel, SingleDevice, TensorParallel
 
-    mesh = mesh_lib.create_mesh({"seq": 8})
-    seq_sharded = NamedSharding(mesh, P(None, None, "seq", None))
-    with pytest.warns(UserWarning, match="ring"):
-        spec = _batch_head_spec(seq_sharded, 4)
-    assert spec == P(None, None, None, None)
-
-    batch_sharded = NamedSharding(mesh, P("seq", None, None, None))
-    import warnings
-
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        spec = _batch_head_spec(batch_sharded, 4)
-    assert spec == P("seq", None, None, None)
+    assert SingleDevice().kernel_shard(tiny_config) is None
+    dp = DataParallel(mesh_lib.create_mesh({"data": 8}))
+    assert dp.kernel_shard(tiny_config) == (dp.mesh, "data", None)
+    tp = TensorParallel(mesh_lib.create_mesh({"data": 2, "model": 4}))
+    assert tp.kernel_shard(tiny_config) == (tp.mesh, "data", "model")
+    # 4 heads do not divide over model=8: heads stay whole per device
+    tp8 = TensorParallel(mesh_lib.create_mesh({"model": 8}))
+    assert tp8.kernel_shard(tiny_config) == (tp8.mesh, None, None)
 
 
 # ---------------------------------------------------------------------------

@@ -662,13 +662,17 @@ def test_compile_cache_misses_then_hits(tmp_path):
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
     try:
-        stats = enable_compilation_cache(str(tmp_path / "cc"))
+        stats = enable_compilation_cache(
+            str(tmp_path / "cc"), min_compile_time_secs=0.0
+        )
         jax.jit(lambda x: x @ x + 5)(np.ones((32, 32), np.float32)).block_until_ready()
         s1 = stats.stats()
         assert s1["requests"] >= 1 and s1["misses"] >= 1
         assert s1["new_entries"] >= 1  # the executable landed on disk
 
-        stats2 = enable_compilation_cache(str(tmp_path / "cc"))
+        stats2 = enable_compilation_cache(
+            str(tmp_path / "cc"), min_compile_time_secs=0.0
+        )
         jax.jit(lambda x: x @ x + 5)(np.ones((32, 32), np.float32)).block_until_ready()
         s2 = stats2.stats()
         assert s2["hits"] >= 1 and s2["misses"] == 0

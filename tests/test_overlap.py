@@ -209,7 +209,7 @@ def test_bucket_all_reduce_partition_invariant():
     BIT-identical results (the parity bar's mechanism, unit-scale)."""
     from jax.sharding import PartitionSpec as P
 
-    from tpukit.compat import shard_map
+    from jax import shard_map
 
     mesh = create_mesh({"data": 8})
     rng = np.random.RandomState(3)

@@ -89,25 +89,7 @@ def _serial(params, cfg, ids, max_new=MAX_NEW, eos_id=None, temperature=0.0,
     return np.asarray(out)[0, : int(length)]
 
 
-def _ref_attend(pool_k, pool_v, scale_k, scale_v, bt, start, q, kn, vn):
-    """The unfused spelling of the kernel's contract: gather_view, insert
-    the fresh K/V at the cursor with the ring path's dynamic-update-slice,
-    then `_attend_over_cache`'s math verbatim (pre-projection)."""
-    cdt = q.dtype
-    view_k = paged_lib.gather_view(pool_k, scale_k, bt, cdt)
-    view_v = paged_lib.gather_view(pool_v, scale_v, bt, cdt)
-    upd = lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (0, s, 0))
-    view_k = jax.vmap(upd)(view_k, kn[:, :, None, :], start)
-    view_v = jax.vmap(upd)(view_v, vn[:, :, None, :], start)
-    d = q.shape[-1]
-    scores = jnp.einsum(
-        "bhqd,bhkd->bhqk", q[:, :, None, :], view_k
-    ) * (1.0 / d**0.5)
-    q_pos = (start[:, None] + jnp.arange(1))[:, None, :, None]
-    key_pos = jnp.arange(view_k.shape[2])[None, None, None, :]
-    scores = jnp.where(key_pos <= q_pos, scores, jnp.asarray(-1e9, scores.dtype))
-    probs = jax.nn.softmax(scores.astype(jnp.float32), -1).astype(view_v.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, view_v)[:, :, 0, :]
+_ref_attend = pa.paged_attend_reference  # the kernel's contract, unfused
 
 
 def _rand_kernel_operands(dtype=jnp.float32, h=4, p=8, d=8, mp=3, n=4,

@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from tpukit.compat import shard_map
+from jax import shard_map
 from tpukit.mesh import create_mesh
 from tpukit.model import GPTConfig
 from tpukit.obs.xla import (

@@ -538,36 +538,6 @@ TINY = dict(
 # 200 rows at global batch 8 = 25 steps; resize@6:4 preempt-saves at step 6.
 
 
-@pytest.fixture(scope="module", autouse=True)
-def _no_persistent_compile_cache():
-    """Container jaxlib 0.4.37 workaround: deserializing persistent-cache
-    executables for a SECOND mesh size in one process corrupts the heap —
-    the next MLIR lowering segfaults. Reproduced WITHOUT any elastic code
-    (a plain mesh-8 fit followed by a mesh-4 `--resume latest` fit, cache
-    on: crash 3/3; cache off: clean 3/3), so this is the runtime, not the
-    reshard pass. Real elastic relaunches are separate processes (the CI
-    elastic-resize lane drives the recipe CLI twice, each with its own
-    cache, and is unaffected) — only this in-process test harness ever
-    runs two mesh sizes under one warm cache. Disable the cache for the
-    module; restore the conftest setting after."""
-    prev = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()  # drop the once-per-process "cache used" latch
-    except Exception:
-        pass
-    yield
-    jax.config.update("jax_enable_compilation_cache", prev)
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:
-        pass
-
-
 def _run_fit(tmp, log_name, strategy_fn, **overrides):
     from tpukit.flags import TrainFlags
     from tpukit.train import fit

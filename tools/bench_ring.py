@@ -26,7 +26,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from tpukit.compat import axis_size as compat_axis_size, shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from tpukit.mesh import create_mesh
@@ -37,7 +37,7 @@ from tpukit.ring_attention import ring_causal_attention, zigzag_order
 def naive_ring_attention(q, k, v, *, scale, axis_name, pad_mask=None):
     """The round-3 schedule: full f32 dense einsum on EVERY hop (including
     the entirely-masked ones), kept verbatim as the comparison baseline."""
-    ring = compat_axis_size(axis_name)
+    ring = jax.lax.axis_size(axis_name)
     my_index = jax.lax.axis_index(axis_name)
     batch, _, s_local, _ = q.shape
     if pad_mask is None:

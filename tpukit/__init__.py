@@ -21,25 +21,12 @@ import os as _os
 # Distributed-without-a-cluster: TPUKIT_CPU_DEVICES=N forces the CPU platform
 # with N virtual devices so every mesh strategy (DP/FSDP/pipeline/2-D) can be
 # driven from the recipe CLIs on one machine. Must happen before the first
-# jax backend use; plain JAX_PLATFORMS env vars are not reliable on platforms
-# whose PJRT plugin pins its own value, so set the config flags directly.
+# jax backend use.
 _cpu_devices = _os.environ.get("TPUKIT_CPU_DEVICES")
 if _cpu_devices:
-    # Belt and braces for jax versions without the jax_num_cpu_devices
-    # config option (< 0.5): the XLA flag must be in the environment before
-    # the backend initializes, and it is harmless alongside the config path.
-    _flags = _os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in _flags:
-        _os.environ["XLA_FLAGS"] = (
-            _flags + f" --xla_force_host_platform_device_count={int(_cpu_devices)}"
-        ).strip()
-
     import jax as _jax
 
     _jax.config.update("jax_platforms", "cpu")
-    try:
-        _jax.config.update("jax_num_cpu_devices", int(_cpu_devices))
-    except AttributeError:
-        pass  # covered by the XLA_FLAGS fallback above
+    _jax.config.update("jax_num_cpu_devices", int(_cpu_devices))
 
 from tpukit.model import GPTConfig, TransformerDecoderLM  # noqa: F401

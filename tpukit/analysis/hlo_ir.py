@@ -340,6 +340,12 @@ _INSTR_RE = re.compile(
 
 _OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
 
+# A TPU module prints tiled layouts after every shape —
+# `f32[512,512]{1,0:T(8,128)}`, `bf16[8,64]{1,0:T(8,128)(2,1)S(1)}` — whose
+# parentheses the shape group of _INSTR_RE cannot cross. They carry nothing
+# this IR reads, so they are dropped before matching (a plain `{1,0}` stays).
+_TILED_LAYOUT_RE = re.compile(r"\]\{[^{}]*:[^{}]*\}")
+
 _ALIAS_ENTRY_RE = re.compile(
     r"\{([\d,\s]*)\}:\s*\(\s*(\d+)\s*,\s*\{([\d,\s]*)\}\s*(?:,\s*([\w-]+))?\)"
 )
@@ -428,6 +434,7 @@ def parse_hlo(text: str) -> HloModule:
         elif line.startswith("}"):
             current = None
             continue
+        line = _TILED_LAYOUT_RE.sub("]", line)
         im = _INSTR_RE.match(line)
         if not im:
             continue  # comments/continuations: opaque, never fatal

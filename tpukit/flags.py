@@ -74,9 +74,10 @@ class TrainFlags:
     # assembly this many batches ahead, overlapping the in-flight compiled
     # step. 0 = the synchronous reference path (bit-identical losses).
     prefetch: int = 2
-    # If set, JAX's persistent compilation cache lives here: repeat runs of
-    # the same program skip XLA recompiles, and fit logs a
-    # kind="compile_cache" hit/miss record.
+    # Explicit override of where JAX's persistent compilation cache lives.
+    # Empty = tpukit/cache.py's rule: $JAX_COMPILATION_CACHE_DIR if set,
+    # else <checkout>/.jax_cache. Repeat runs of the same program skip XLA
+    # recompiles, and fit logs a kind="compile_cache" hit/miss record.
     compilation_cache_dir: str = ""
     profile_dir: str = ""  # if set, jax.profiler traces land here
     metrics_log: str = ""  # if set, JSONL step metrics land here

@@ -83,24 +83,22 @@ def initialize_runtime() -> None:
                 or _distributed_client_active()
             ):
                 pass  # initialized by the launcher/runtime — fine
-            elif explicit:
-                # The operator asked for a multi-host run. Silently falling
-                # back would train N independent single-host copies — the
-                # worst possible failure mode on a pod. Fail loudly instead.
-                raise RuntimeError(
-                    "JAX_COORDINATOR_ADDRESS is set but "
-                    "jax.distributed.initialize() failed; refusing to "
-                    "silently degrade to independent single-host training. "
-                    f"Original error: {exc}"
-                ) from exc
             else:
-                import warnings
-
-                warnings.warn(
-                    f"jax.distributed.initialize() failed ({exc}); "
-                    "continuing single-host",
-                    stacklevel=2,
+                # The operator (explicit coordinator) or the environment (a
+                # detected pod / multislice / SLURM / OMPI world) says this is
+                # a multi-host run. Silently falling back would train N
+                # independent single-host copies — the worst possible
+                # failure mode on a pod. Fail loudly instead.
+                why = (
+                    "JAX_COORDINATOR_ADDRESS is set"
+                    if explicit
+                    else "a multi-host environment was detected"
                 )
+                raise RuntimeError(
+                    f"{why} but jax.distributed.initialize() failed; "
+                    "refusing to silently degrade to independent "
+                    f"single-host training. Original error: {exc}"
+                ) from exc
     _initialized = True
 
 

@@ -149,6 +149,11 @@ class GPTConfig:
     # time; plain model calls see only what the caller configured.
     moe_dispatch: str = "xla"  # "xla" | "a2a" | "pallas"
     moe_mesh: Any = None  # jax Mesh with an 'expert' axis (a2a/pallas under EP)
+    # `(mesh, batch_axes, head_axes)`: how the strategy's GSPMD jit shards
+    # batch and heads, for the flash kernel's per-shard call
+    # (pallas_attention.per_shard). Injected at loss time like `moe_mesh`
+    # (Strategy.kernel_shard); None on one device and in plain model calls.
+    kernel_shard: Any = None
     # Explicit per-row expert capacity. 0 (default) keeps the derived
     # capacity (ceil(max_position * top_k * capacity_factor / E)) for the
     # buffer dispatches and makes the "pallas" dispatch DROPLESS; > 0
@@ -455,6 +460,7 @@ def _apply_attention(layer, cfg: GPTConfig, x, pad_mask, rng, deterministic):
         impl=cfg.attention_impl,
         ring_axis=cfg.ring_axis,
         ring_layout=cfg.ring_layout,
+        shard=cfg.kernel_shard,
     )
     out = out.transpose(0, 2, 1, 3).reshape(batch, seq_len, cfg.inner_dim)
     out = linear(out, layer["attn"]["out"], cfg.compute_dtype)

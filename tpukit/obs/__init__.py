@@ -1,7 +1,6 @@
 """tpukit.obs — the telemetry subsystem.
 
-Supersedes the old flat `tpukit/profiling.py` (now a compat shim). The
-pillars, one per module:
+The pillars, one per module:
 
   - `meter`      — MFUMeter (tokens/sec, MFU), `profiler_trace`, JSONL
                    `StepLogger`.

@@ -1,7 +1,6 @@
 """Throughput/MFU metering, profiler tracing, and the JSONL step log.
 
-Absorbed from the pre-obs `tpukit/profiling.py` (which is now a compat
-shim). The reference has no profiling at all — its only throughput signal
+The reference has no profiling at all — its only throughput signal
 is tqdm's implicit it/s counter (reference main-single.py:81; SURVEY §5).
 Since the driver-defined baseline metric is tokens/sec/chip and MFU
 (BASELINE.md), the meter is built into the trainer rather than bolted on:
@@ -30,7 +29,10 @@ import jax
 
 from tpukit.model.gpt import GPTConfig
 
-# Peak dense bf16 FLOPs/s per chip.
+# Peak dense bf16 FLOP/s per chip, keyed by the EXACT `device_kind` string
+# jax reports (a v5e chip reports "TPU v5 lite"). Source: Google Cloud TPU
+# documentation, the system-architecture page of each generation ("TPU v5e":
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s).
 _PEAK_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
@@ -43,11 +45,19 @@ _PEAK_FLOPS = {
 
 
 def peak_flops_per_chip(device_kind: str | None = None) -> float | None:
+    """Peak bf16 FLOP/s of one chip of `device_kind` (default: the first
+    device). None off-TPU — MFU is undefined on the CPU test backend. A TPU
+    kind missing from the table raises: a guessed peak is a wrong MFU."""
     kind = device_kind or jax.devices()[0].device_kind
-    for key, val in sorted(_PEAK_FLOPS.items(), key=lambda kv: -len(kv[0])):
-        if kind.lower().startswith(key.lower()):
-            return val
-    return None  # CPU or unknown: MFU undefined
+    if kind in _PEAK_FLOPS:
+        return _PEAK_FLOPS[kind]
+    if kind.startswith("TPU"):
+        raise ValueError(
+            f"no peak FLOP/s on record for TPU device_kind {kind!r}; add it "
+            f"to tpukit/obs/meter.py:_PEAK_FLOPS with its source (known: "
+            f"{sorted(_PEAK_FLOPS)})"
+        )
+    return None
 
 
 def matmul_param_count(cfg: GPTConfig) -> int:
@@ -140,19 +150,13 @@ class MFUMeter:
 @contextlib.contextmanager
 def profiler_trace(profile_dir: str = ""):
     """jax.profiler trace hook (SURVEY §5 tracing plan). No-op when unset.
-
-    Renamed from `trace` in round 20: `tpukit.obs.trace` is now the
-    request-scoped serving-trace MODULE, so the profiler hook carries an
-    unambiguous name. The old spelling survives below for the
-    `tpukit.profiling` compat shim."""
+    (`tpukit.obs.trace` is the request-scoped serving-trace MODULE; the
+    profiler hook carries this unambiguous name.)"""
     if profile_dir:
         with jax.profiler.trace(profile_dir):
             yield
     else:
         yield
-
-
-trace = profiler_trace  # legacy alias (tpukit/profiling.py shim)
 
 
 class StepLogger:

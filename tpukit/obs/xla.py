@@ -6,10 +6,10 @@ is a first-order cost worth metering). Three captures:
 
   - `compiled_stats(jitted_fn, *avals)`: AOT `lower().compile()` at the
     given avals and pull XLA's `cost_analysis()` (FLOPs, bytes accessed)
-    and `memory_analysis()` (argument/output/temp/peak bytes). jit and the
-    AOT path share the lowering/compilation caches, so when the trainer has
-    already compiled the step this records the SAME executable rather than
-    forcing a second compile.
+    and `memory_analysis()` (argument/output/temp/peak bytes). The AOT path
+    does NOT share a compile with a later jit call of the same function, so
+    fit() runs the executable this hands back (`hlo_out["executable"]`):
+    one compile serves the analysis and the step.
   - `collective_bytes(hlo_text)`: per-collective-kind op counts and payload
     bytes parsed from the optimized HLO — the DP grad psum, FSDP
     all-gather/reduce-scatter, pipeline/ring ppermute, and MoE all_to_all
@@ -96,6 +96,25 @@ def collective_bytes(hlo_text: str) -> dict[str, dict[str, int]]:
     is the body's, not a text offset — so rule-engine callers and this
     summary read the same parse."""
     return _ir.collective_summary(_ir.parse_hlo(hlo_text))
+
+
+_KERNEL_CALL = re.compile(
+    r"%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\""
+)
+
+
+def kernel_calls(hlo_text: str) -> dict[str, int]:
+    """{kernel name: call sites} of the Pallas kernels COMPILED into a
+    module (`tpu_custom_call` custom calls; an interpreted kernel lowers to
+    plain HLO and is absent). The name is the `name=` every tpukit
+    `pallas_call` carries — flash_fwd, head_ce_bwd, paged_attend, ... — so
+    a run can state which kernels were on its path."""
+    out: dict[str, int] = {}
+    for name in _KERNEL_CALL.findall(hlo_text):
+        # autodiff and scan wrap the name: jvp_flash_fwd_, transpose_jvp_...
+        name = re.sub(r"^(?:transpose_|jvp_)+", "", name).rstrip("_")
+        out[name] = out.get(name, 0) + 1
+    return out
 
 
 def wire_bytes(collectives: dict[str, dict[str, int]], world: int) -> int:
@@ -197,19 +216,26 @@ def compiled_stats(jitted_fn, *args, hlo_out: dict | None = None,
 
     Record fields: `flops`, `bytes_accessed`, `transcendentals` (per
     executed step, from cost_analysis), `memory` (memory_analysis sizes),
-    `collectives` ({op: {count, bytes}} from the optimized HLO).
+    `collectives` ({op: {count, bytes}} from the optimized HLO), `kernels`
+    ({name: call sites} of compiled Pallas kernels, `kernel_calls`).
 
     `hlo_out`: optional dict that receives the optimized module text under
     "text" — fit()'s rule-engine pass (analysis/rules.py) reads it so the
     hlolint verdicts ride the same AOT compile as the stats instead of
-    paying a second lower().
+    paying a second lower() — and the executable itself under
+    "executable": a second lowering of the same function numbers its
+    private functions differently, so a later `jitted_fn(...)` call would
+    miss both the jit and the persistent cache and compile the whole step
+    again. The caller runs this executable instead.
     """
     try:
         compiled = jitted_fn.lower(*args, **kwargs).compile()
     except Exception:
         return None
+    if hlo_out is not None:
+        hlo_out["executable"] = compiled
     out: dict = {"flops": None, "bytes_accessed": None, "memory": None,
-                 "collectives": None}
+                 "collectives": None, "kernels": None}
     ca = _cost_analysis_dict(compiled)
     if ca:
         out["flops"] = ca.get("flops")
@@ -222,6 +248,7 @@ def compiled_stats(jitted_fn, *args, hlo_out: dict | None = None,
         if hlo_out is not None:
             hlo_out["text"] = text
         out["collectives"] = collective_bytes(text)
+        out["kernels"] = kernel_calls(text)
     except Exception:
         pass
     return out

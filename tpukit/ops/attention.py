@@ -38,6 +38,7 @@ def causal_attention(
     impl: str = "xla",
     ring_axis: str = "seq",
     ring_layout: str = "contiguous",
+    shard=None,
 ) -> jax.Array:
     """Scaled dot-product causal attention.
 
@@ -46,6 +47,8 @@ def causal_attention(
       scale: `1 / sqrt(head_dim)` (reference models/gpt.py:66).
       pad_mask: optional `[B, S]` bool, True = position is padding (masked).
       impl: "xla" (fused by the compiler) or "flash" (Pallas kernel on TPU).
+      shard: `(mesh, batch_axes, head_axes)` under a multi-device GSPMD jit
+        (`Strategy.kernel_shard`), for the flash kernel's per-shard call.
 
     Returns `[B, heads, S, head_dim]` in the dtype of `v`.
     """
@@ -55,17 +58,20 @@ def causal_attention(
         # from 512 up (+68% at S=1024, +130% at S=2048) and is the only
         # option at S >= 8k, where the materialized S x S no longer compiles.
         #
-        # The kernel is safe in every sharded context: custom_partitioning
-        # rules (tpukit/ops/pallas_attention.py) keep batch/head shardings
-        # under GSPMD jit (DP/FSDP/TP), and pallas_call composes directly
-        # with shard_map Manual regions (pipeline recipes).
+        # The kernel is safe in every sharded context: under GSPMD jit
+        # (DP/FSDP/TP) the strategy names its mesh axes in `shard` and the
+        # kernel runs per shard (pallas_attention.per_shard), and
+        # pallas_call composes directly with shard_map Manual regions
+        # (pipeline recipes).
         from tpukit.ops.pallas_attention import on_tpu_backend
 
         impl = "flash" if (on_tpu_backend() and q.shape[2] >= 512) else "xla"
     if impl == "flash":
         from tpukit.ops.pallas_attention import flash_causal_attention
 
-        return flash_causal_attention(q, k, v, scale=scale, pad_mask=pad_mask)
+        return flash_causal_attention(
+            q, k, v, scale=scale, pad_mask=pad_mask, shard=shard
+        )
     if impl == "ring":
         from tpukit.ring_attention import ring_causal_attention
 

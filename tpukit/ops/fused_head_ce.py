@@ -19,7 +19,7 @@ No reference counterpart: the reference computes full logits and calls
 F.cross_entropy (models/gpt.py:229-231, main-single.py:95-96) — viable at
 S=256, not at the long-context shapes this framework targets.
 
-On non-TPU backends the kernels run in Pallas interpreter mode (the CPU
+On the CPU backend the kernels run in Pallas interpreter mode (the CPU
 test mesh exercises the exact kernel code path).
 """
 
@@ -32,12 +32,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.custom_partitioning import custom_partitioning
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from tpukit.compat import def_partition as compat_def_partition
 from tpukit.ops.layers import IGNORE_INDEX  # one sentinel for every loss path
-from tpukit.ops.pallas_attention import _interpret, tpu_compiler_params
+from tpukit.ops.pallas_attention import (
+    _interpret,
+    per_shard,
+    tpu_compiler_params,
+)
 
 NEG_INF = -1e9  # same pad-column clamp as apply_head (model/gpt.py)
 
@@ -223,6 +226,7 @@ def _fused_fwd_arrays(h, w, targets, vocab_size, with_argmax):
         scratch_shapes=[pltpu.VMEM((t_blk, 128), jnp.float32)] * 4
         + [pltpu.VMEM((t_blk, 128), jnp.int32)],
         compiler_params=tpu_compiler_params("parallel", "arbitrary"),
+        name="head_ce_fwd",
         interpret=_interpret(),
     )(tgt_p, h_p, w_p)
     return (
@@ -264,6 +268,7 @@ def _fused_bwd_arrays(h, w, targets, lse, g_lse, g_tgt, vocab_size):
             jax.ShapeDtypeStruct((dim, v_pad2), jnp.float32),
         ],
         compiler_params=tpu_compiler_params("parallel", "arbitrary"),
+        name="head_ce_bwd",
         interpret=_interpret(),
     )(tgt_p, glse_p, gtgt_p, lse_p, h_p, w_p)
 
@@ -272,136 +277,77 @@ def _fused_bwd_arrays(h, w, targets, lse, g_lse, g_tgt, vocab_size):
 
 
 # ---------------------------------------------------------------------------
-# GSPMD partitioning (mirrors pallas_attention's treatment): the token axis
+# Sharded calls (pallas_attention.per_shard): the token axis
 # (h/targets dim 0) is freely shardable — each device runs the kernels on its
 # local tokens — while dim and vocab must be whole per device (the online
 # logsumexp sweeps all vocab tiles and the matmul contracts all of dim). The
 # forward's per-token outputs inherit the token sharding; the backward's dw
 # is a sum over tokens, so each shard contributes its local partial and the
-# lowered body psums over the token mesh axes. Without these rules a real-TPU
-# GSPMD trace would treat the tpu_custom_call as unpartitionable and
-# all-gather the whole batch onto every device (the CPU tests can't catch
-# that: interpreter mode lowers to plain HLO, which partitions fine).
+# body psums over the token mesh axes. `shard` is None (one device, or
+# already inside a shard_map) or `(mesh, token_axes)` under a multi-device
+# GSPMD jit: left to itself GSPMD would treat the tpu_custom_call as
+# unpartitionable and all-gather the whole batch onto every device, and
+# custom_partitioning does not compile on libtpu (pallas_attention.per_shard).
+# The custom_vjp sits OUTSIDE the sharded calls, same layering as
+# pallas_attention's _flash wrapper.
 # ---------------------------------------------------------------------------
 
 
-def _token_axes(sharding):
-    """Mesh axes of h's dim-0 sharding (None if unsharded). dim-1 shardings
-    are dropped (GSPMD all-gathers them) with a warning, as in
-    pallas_attention._batch_head_spec."""
-    if sharding is None or not hasattr(sharding, "spec"):
-        return None
-    spec = list(sharding.spec) + [None] * 2
-    if spec[1]:
-        import warnings
-
-        warnings.warn(
-            f"fused_head_ce: hidden states sharded over the feature dim "
-            f"({sharding.spec}); the kernel contracts the full dim per "
-            f"device, so GSPMD will all-gather it.",
-            stacklevel=2,
-        )
-    return spec[0]
+def _fwd_call(h, w, targets, vocab_size, with_argmax, shard):
+    tok = shard and shard[1]
+    return per_shard(
+        lambda h, w, t: _fused_fwd_arrays(h, w, t, vocab_size, with_argmax),
+        shard, (P(tok, None), P(None, None), P(tok)), (P(tok),) * 3,
+    )(h, w, targets)
 
 
-def _fused_shardings(mesh, tok):
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    return {
-        "h": NamedSharding(mesh, P(tok, None)),
-        "w": NamedSharding(mesh, P(None, None)),
-        "tok": NamedSharding(mesh, P(tok)),
-    }
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _fused_terms(h, w, targets, vocab_size, with_argmax, shard):
+    return _fwd_call(h, w, targets, vocab_size, with_argmax, shard)
 
 
-def _fwd_partition(vocab_size, with_argmax, mesh, arg_infos, result_infos):
-    tok = _token_axes(arg_infos[0].sharding)
-    sh = _fused_shardings(mesh, tok)
-    arg_sh = (sh["h"], sh["w"], sh["tok"])
-    out_sh = (sh["tok"],) * 3
-
-    def lower(h, w, t):
-        return _fused_fwd_arrays(h, w, t, vocab_size, with_argmax)
-
-    return mesh, lower, out_sh, arg_sh
-
-
-def _fwd_infer(vocab_size, with_argmax, mesh, arg_infos, result_infos):
-    tok = _token_axes(arg_infos[0].sharding)
-    return (_fused_shardings(mesh, tok)["tok"],) * 3
-
-
-_fwd_cp = custom_partitioning(_fused_fwd_arrays, static_argnums=(3, 4))
-compat_def_partition(_fwd_cp, 
-    partition=_fwd_partition,
-    infer_sharding_from_operands=_fwd_infer,
-    sharding_rule="n d, d v, n -> n, n, n",
-)
-
-
-def _bwd_partition(vocab_size, mesh, arg_infos, result_infos):
-    tok = _token_axes(arg_infos[0].sharding)
-    sh = _fused_shardings(mesh, tok)
-    arg_sh = (sh["h"], sh["w"], sh["tok"], sh["tok"], sh["tok"], sh["tok"])
-    out_sh = (sh["h"], sh["w"])
-    axes = (tok,) if isinstance(tok, str) else tuple(tok or ())
-
-    def lower(h, w, t, lse, gl, gt):
-        dh, dw = _fused_bwd_arrays(h, w, t, lse, gl, gt, vocab_size)
-        if axes:  # token-sharded: dw partials live per shard
-            dw = jax.lax.psum(dw, axes)
-        return dh, dw
-
-    return mesh, lower, out_sh, arg_sh
-
-
-def _bwd_infer(vocab_size, mesh, arg_infos, result_infos):
-    tok = _token_axes(arg_infos[0].sharding)
-    sh = _fused_shardings(mesh, tok)
-    return (sh["h"], sh["w"])
-
-
-_bwd_cp = custom_partitioning(_fused_bwd_arrays, static_argnums=(6,))
-compat_def_partition(_bwd_cp, 
-    partition=_bwd_partition,
-    infer_sharding_from_operands=_bwd_infer,
-    sharding_rule="n d, d v, n, n, n, n -> n d, d v",
-)
-
-
-# custom_vjp sits OUTSIDE the partitioned ops (custom_partitioning has no
-# autodiff rule — same layering as pallas_attention's _flash wrapper)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _fused_terms(h, w, targets, vocab_size, with_argmax):
-    return _fwd_cp(h, w, targets, vocab_size, with_argmax)
-
-
-def _fused_terms_fwd(h, w, targets, vocab_size, with_argmax):
-    lse, tgtl, best = _fwd_cp(h, w, targets, vocab_size, with_argmax)
+def _fused_terms_fwd(h, w, targets, vocab_size, with_argmax, shard):
+    lse, tgtl, best = _fwd_call(h, w, targets, vocab_size, with_argmax, shard)
     return (lse, tgtl, best), (h, w, targets, lse)
 
 
-def _fused_terms_bwd(vocab_size, with_argmax, residuals, g):
+def _fused_terms_bwd(vocab_size, with_argmax, shard, residuals, g):
     h, w, targets, lse = residuals
     g_lse, g_tgt = g[0], g[1]  # best (int) has no cotangent
-    dh, dw = _bwd_cp(h, w, targets, lse, g_lse, g_tgt, vocab_size)
+    tok = shard and shard[1]
+
+    def local(h, w, t, lse, g_lse, g_tgt):
+        dh, dw = _fused_bwd_arrays(h, w, t, lse, g_lse, g_tgt, vocab_size)
+        if tok:  # token-sharded: dw partials live per shard
+            dw = jax.lax.psum(dw, tok)
+        return dh, dw
+
+    dh, dw = per_shard(
+        local, shard,
+        (P(tok, None), P(None, None)) + (P(tok),) * 4,
+        (P(tok, None), P(None, None)),
+    )(h, w, targets, lse, g_lse, g_tgt)
     return dh, dw, np.zeros(targets.shape, jax.dtypes.float0)
 
 
 _fused_terms.defvjp(_fused_terms_fwd, _fused_terms_bwd)
 
 
-def fused_head_ce(h, w, targets, vocab_size, with_accuracy: bool = False):
+def fused_head_ce(h, w, targets, vocab_size, with_accuracy: bool = False,
+                  shard=None):
     """(loss_sum, count, correct) of the LM head + masked CE, computed from
     hidden states `h [N, dim]` and the (vocab-padded) head kernel
     `w [dim, V_pad]` without materializing logits. `targets [N]` uses
     IGNORE_INDEX masking; `correct` is 0 unless with_accuracy.
 
     Equivalent to `cross_entropy_sum(apply_head-logits, targets)` (+
-    masked_accuracy) — equivalence-tested against that path."""
-    lse, tgt_logit, best = _fused_terms(h, w, targets, vocab_size, with_accuracy)
+    masked_accuracy) — equivalence-tested against that path.
+
+    `shard`: `(mesh, token_axes)` under a multi-device GSPMD jit — the mesh
+    axes that shard the token dim of `h`/`targets`; None otherwise."""
+    lse, tgt_logit, best = _fused_terms(
+        h, w, targets, vocab_size, with_accuracy, shard
+    )
     valid = targets != IGNORE_INDEX
     loss_sum = jnp.sum(jnp.where(valid, lse - tgt_logit, 0.0))
     count = jnp.sum(valid).astype(jnp.float32)

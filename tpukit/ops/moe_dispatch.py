@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from tpukit.compat import shard_map
+from jax import shard_map
 from tpukit.ops import quant_comm
 
 

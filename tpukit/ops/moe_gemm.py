@@ -4,9 +4,9 @@ dispatch.
 The buffer dataflows ("xla"/"a2a", tpukit/ops/moe_dispatch.py) materialize
 an `[E, B, C, D]` capacity tensor and run EVERY expert over mostly-padding
 rows: at the bench e8 shape the dispatch/combine one-hot einsums plus the
-~25% capacity padding are why `moe_e8` sat ~100k tok/s/chip under the
-dense model (BENCH_r02..r05, ROADMAP #3). This module removes the buffer
-entirely:
+~25% capacity padding are why `moe_e8` sat under the dense model in the
+round-5 chip records (since deleted; ROADMAP S1). This module removes the
+buffer entirely:
 
   1. SORT: the `[B*S*K]` top-k expert assignments are stably argsorted by
      expert id on device, giving a permutation into expert-contiguous
@@ -50,13 +50,15 @@ there; the dropless win is the meshless/single-chip path, which is what
 the bench `moe_e8` probe measures.)
 
 VMEM budget: the whole expert bank (`[E, D, F]` + `[E, F, D]` + biases)
-stays resident in VMEM across the row walk — at the bench e8 shape ~8 MiB
-bf16, well under the 100 MiB kernel budget, but it bounds this kernel to
-banks that fit on-chip (E ~<= 32 at GPT-small widths). The static expert
-unroll likewise targets small expert counts; both limits are asserted at
-call time rather than discovered as Mosaic errors.
+stays resident in VMEM across the row walk, and in the backward so does
+its f32 gradient — at the bench e8 shape (D=256, F=1024) ~8 + 17 MiB,
+well under the 100 MiB kernel budget, but it bounds this kernel to banks
+that fit on-chip: at GPT-small widths (D=768, F=3072) that is E <= 2 per
+device. The static expert unroll likewise targets small expert counts;
+both limits are asserted at call time rather than discovered as Mosaic
+errors.
 
-On non-TPU backends the kernels run in Pallas interpreter mode (the
+On the CPU backend the kernels run in Pallas interpreter mode (the
 `pallas_attention.py` convention), so the CPU tier-1 suite exercises the
 exact kernel code path.
 """
@@ -90,6 +92,13 @@ _BLOCK_ROWS = max(8, -(-int(os.environ.get("TPUKIT_MOE_BLOCK", "512")) // 8) * 8
 # both scale with E. Fail with a named limit instead of a Mosaic OOM.
 _MAX_VMEM_EXPERTS = 32
 
+# The backward keeps the bank AND its f32 gradient VMEM-resident. 64 MiB of
+# the kernels' 100 MiB limit go to those; the rest is row tiles and the
+# [BT, F] hidden activations. Asked from the sandbox, the v5e compiler
+# accepts 57 MB (E=2, D=768, F=3072, bf16) and refuses 85 MB (E=3) with
+# "Ran out of memory in memory space vmem".
+_BANK_VMEM_BYTES = 64 * 1024 * 1024
+
 
 def _plan_rows(n_rows: int) -> tuple[int, int]:
     """(block_rows, padded_rows): sublane-aligned block edge and the row
@@ -114,7 +123,9 @@ def _fwd_kernel(offs_ref, x_ref, wu_ref, bu_ref, wd_ref, bd_ref, y_ref, *,
     # sort-padding tail) must read as exact zeros downstream
     y_ref[...] = jnp.zeros_like(y_ref)
     base = b * block_rows
-    rows = base + jax.lax.broadcasted_iota(jnp.int32, (block_rows, 1), 0)
+    # global row ids at the FULL [BT, D] tile shape: Mosaic has no relayout
+    # that broadcasts a [BT, 1] boolean column across lanes
+    rows = base + jax.lax.broadcasted_iota(jnp.int32, x_ref.shape, 0)
     x_blk = x_ref[...]
     for e in range(num_experts):
         start = offs_ref[e]
@@ -164,7 +175,7 @@ def _bwd_kernel(offs_ref, x_ref, g_ref, y_ref, wu_ref, bu_ref, wd_ref,
 
     dx_ref[...] = jnp.zeros_like(dx_ref)
     base = b * block_rows
-    rows = base + jax.lax.broadcasted_iota(jnp.int32, (block_rows, 1), 0)
+    rows = base + jax.lax.broadcasted_iota(jnp.int32, x_ref.shape, 0)
     x_blk = x_ref[...]
     for e in range(num_experts):
         start = offs_ref[e]
@@ -173,8 +184,18 @@ def _bwd_kernel(offs_ref, x_ref, g_ref, y_ref, wu_ref, bu_ref, wd_ref,
         @pl.when((start < base + block_rows) & (end > base))
         def _():
             mask = (rows >= start) & (rows < end)
+            # two selects, not `mask & (y > 0)`: the row mask is lane-
+            # replicated and Mosaic cannot relayout the other i1 operand
+            # to match it; y compares in f32 (the v5e VPU has no bf16
+            # compare)
             dz2 = jnp.where(
-                mask & (y_ref[...] > 0), g_ref[...].astype(jnp.float32), 0.0
+                mask,
+                jnp.where(
+                    y_ref[...].astype(jnp.float32) > 0,
+                    g_ref[...].astype(jnp.float32),
+                    0.0,
+                ),
+                0.0,
             )
             h = jax.lax.dot_general(
                 x_blk, wu_ref[e],
@@ -214,13 +235,26 @@ def _bwd_kernel(offs_ref, x_ref, g_ref, y_ref, wu_ref, bu_ref, wd_ref,
             ).astype(dx_ref.dtype)
 
 
-def _check_bank(num_experts: int) -> None:
+def _check_bank(num_experts: int, d: int, f: int, bytes_per_param: int) -> None:
+    """`bytes_per_param`: the bank's itemsize in the forward, plus 4 in the
+    backward (its f32 gradient is resident too)."""
     if num_experts > _MAX_VMEM_EXPERTS:
         raise ValueError(
             f"moe_dispatch='pallas' keeps the whole expert bank VMEM-"
             f"resident and unrolls over it: num_experts={num_experts} "
             f"exceeds the supported {_MAX_VMEM_EXPERTS} (shard experts "
             f"over an ExpertParallel mesh, or use the buffer dispatches)"
+        )
+    need = num_experts * (2 * d * f + d + f) * bytes_per_param
+    if need > _BANK_VMEM_BYTES:
+        raise ValueError(
+            f"moe_dispatch='pallas' keeps the expert bank (and, in the "
+            f"backward, its f32 gradient) VMEM-resident: {num_experts} "
+            f"experts x (D={d}, F={f}) need {need // (1024 * 1024)} MiB, "
+            f"over the {_BANK_VMEM_BYTES // (1024 * 1024)} MiB budget (the "
+            f"TPU compiler refuses such a bank with 'Ran out of memory in "
+            f"memory space vmem') — shard experts over an ExpertParallel "
+            f"mesh, or use the buffer dispatches"
         )
 
 
@@ -241,7 +275,7 @@ def _row_spec(bt, d):
 def _grouped_ffn_fwd_call(xs, wu, bu, wd, bd, offsets):
     m, d = xs.shape
     e, _, f = wu.shape
-    _check_bank(e)
+    _check_bank(e, d, f, wu.dtype.itemsize)
     bt, m_pad = _plan_rows(m)
     assert m_pad == m, "caller pads the sorted rows to a block multiple"
     kernel = functools.partial(_fwd_kernel, block_rows=bt, num_experts=e)
@@ -255,6 +289,7 @@ def _grouped_ffn_fwd_call(xs, wu, bu, wd, bd, offsets):
         out_specs=_row_spec(bt, d),
         out_shape=jax.ShapeDtypeStruct((m, d), xs.dtype),
         compiler_params=tpu_compiler_params("arbitrary"),
+        name="moe_ffn_fwd",
         interpret=_interpret(),
     )(offsets, xs, wu, bu, wd, bd)
 
@@ -262,6 +297,7 @@ def _grouped_ffn_fwd_call(xs, wu, bu, wd, bd, offsets):
 def _grouped_ffn_bwd_call(xs, g, ys, wu, bu, wd, offsets):
     m, d = xs.shape
     e, _, f = wu.shape
+    _check_bank(e, d, f, wu.dtype.itemsize + 4)
     bt, _ = _plan_rows(m)
     kernel = functools.partial(_bwd_kernel, block_rows=bt, num_experts=e)
     return pl.pallas_call(
@@ -285,6 +321,7 @@ def _grouped_ffn_bwd_call(xs, g, ys, wu, bu, wd, offsets):
             jax.ShapeDtypeStruct((e, d), jnp.float32),
         ],
         compiler_params=tpu_compiler_params("arbitrary"),
+        name="moe_ffn_bwd",
         interpret=_interpret(),
     )(offsets, xs, g, ys, wu, bu, wd)
 
@@ -422,7 +459,6 @@ def moe_ffn_pallas(layer, cfg, x, pad_mask=None):
         return _moe_ffn_exchange(
             layer, cfg, x, pad_mask, _grouped_expert_ffn, "pallas"
         )
-    _check_bank(cfg.num_experts)
     experts = layer["ffn"]["experts"]
     xc, top_idx, top_vals, probs, assign = _route_topk(
         x, layer["ffn"]["router"]["kernel"], cfg

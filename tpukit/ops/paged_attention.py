@@ -51,10 +51,16 @@ the existing >=90% token-agreement gate transfers unchanged.
 Grid is `(H, N)` with slots innermost: the per-head pool slab
 `[NP, 1, P, D]` stays VMEM-resident while every slot's window is
 assembled against it — the pool is fetched H times total, not N*H.
-Every test runs on this container via `interpret=_interpret()` (the
-pallas_attention convention); the VMEM footprint of the head slab is
-asserted with a named error (TPUKIT_PAGED_VMEM_MB) instead of a Mosaic
-OOM.
+The CPU tests run it via `interpret=_interpret()` (the pallas_attention
+convention), which cannot see what Mosaic refuses: tests/test_chip_compile.py
+compiles it for a described v5e and chip_smoke.py runs it compiled. What
+the chip's compiler dictated: every block whole in its last two dims (the
+per-slot vectors ride `[N, H, 1, D]`, the scale sidecars head-major), f32
+matmul accumulators rounded once to the compute dtype, the fresh token
+entered by a row select (no dynamic one-row store into a packed tile), the
+dequant scale selected at tile shape (no `[nb, 256]` reshape). The VMEM
+footprint of the head slab is asserted with a named error
+(TPUKIT_PAGED_VMEM_MB) instead of a Mosaic OOM.
 """
 
 from __future__ import annotations
@@ -64,6 +70,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -118,17 +125,29 @@ def _paged_kernel(bt_ref, start_ref, *refs, page, mp, head_dim, quant,
     w = mp * page
     st = start_ref[n]
 
+    if quant:
+        nb = (page * head_dim) // quant_comm.DEFAULT_BLOCK
+        # which quant block each element of a (P, D) page tile belongs to,
+        # in the tile's row-major order (the sidecar's flattening)
+        blk = (
+            jax.lax.broadcasted_iota(jnp.int32, (page, head_dim), 0) * head_dim
+            + jax.lax.broadcasted_iota(jnp.int32, (page, head_dim), 1)
+        ) // quant_comm.DEFAULT_BLOCK
+
     def load_tile(pool_ref, scale_ref, pid):
-        tile = pool_ref[pl.ds(pid, 1), 0]  # (1, P, D), pool storage dtype
+        tile = pool_ref[pid, 0]  # (P, D), pool storage dtype
         if quant:
-            nb = (page * head_dim) // quant_comm.DEFAULT_BLOCK
-            srow = scale_ref[pl.ds(pid, 1), 0]  # (1, nb) f32
-            # dequantize_blocks verbatim per (page, head) row: f32 cast,
-            # per-256-element-block scale multiply — elementwise-identical
-            # to the gathered view's dequant
-            xb = tile.astype(jnp.float32).reshape(nb, quant_comm.DEFAULT_BLOCK)
-            tile = (xb * srow.reshape(nb, 1)).reshape(1, page, head_dim)
-        return tile.reshape(page, head_dim).astype(k_win.dtype)
+            srow = scale_ref[0, pl.ds(pid, 1), :]  # (1, nb) f32
+            # dequantize_blocks' arithmetic per (page, head) row: f32 cast,
+            # one multiply by the element's block scale — elementwise-
+            # identical to the gathered view's dequant. The scale tile is
+            # selected at the full (P, D) shape: Mosaic has no relayout for
+            # the [nb, 256] reshape the XLA spelling uses.
+            sc = jnp.zeros((page, head_dim), jnp.float32)
+            for j in range(nb):
+                sc = jnp.where(blk == j, srow[0, j], sc)
+            tile = tile.astype(jnp.float32) * sc
+        return tile.astype(k_win.dtype)
 
     for j in range(mp):  # MP is static and small: unrolled page walk
         pid = bt_ref[n, j]
@@ -141,18 +160,24 @@ def _paged_kernel(bt_ref, start_ref, *refs, page, mp, head_dim, quant,
 
     # fresh-token insert at the cursor — the same clamp semantics as the
     # unfused path's dynamic_update_slice (start is < W for every lane
-    # the engine dispatches; the clamp only guards degenerate inputs)
-    idx = jnp.minimum(st, w - 1)
-    k_win[pl.ds(idx, 1), :] = kn_ref[0]
-    v_win[pl.ds(idx, 1), :] = vn_ref[0]
+    # the engine dispatches; the clamp only guards degenerate inputs).
+    # A row select over the window, not a one-row store: Mosaic cannot
+    # store a single row of a packed (bf16) tile at a dynamic offset.
+    at_cursor = jax.lax.broadcasted_iota(
+        jnp.int32, (w, head_dim), 0
+    ) == jnp.minimum(st, w - 1)
+    k_all = jnp.where(at_cursor, kn_ref[0, 0], k_win[...])
+    v_all = jnp.where(at_cursor, vn_ref[0, 0], v_win[...])
 
-    # scores in the COMPUTE dtype (no preferred_element_type — the
-    # reference einsum's accumulation), scale + causal mask applied in
-    # the same dtype/order as _attend_over_cache, THEN the f32 cast
+    # scores rounded to the COMPUTE dtype straight out of the dot (the
+    # reference einsum's result dtype; Mosaic's MXU accumulates in f32, as
+    # XLA's does), scale + causal mask applied in the same dtype/order as
+    # _attend_over_cache, THEN the f32 cast
     s = jax.lax.dot_general(
-        q_ref[0], k_win[...],
+        q_ref[0, 0], k_all,
         dimension_numbers=(((1,), (1,)), ((), ())),
-    ) * scale  # (1, W)
+        preferred_element_type=jnp.float32,
+    ).astype(k_all.dtype) * scale  # (1, W)
     key_pos = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
     s = jnp.where(key_pos <= st, s, jnp.asarray(NEG_INF, s.dtype))
     s32 = s.astype(jnp.float32)
@@ -163,12 +188,13 @@ def _paged_kernel(bt_ref, start_ref, *refs, page, mp, head_dim, quant,
     m0 = jnp.full((1, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((1, 1), jnp.float32)
     _, l, _, p = online_softmax_update(m0, l0, s32)
-    probs = (p / l).astype(v_win.dtype)
+    probs = (p / l).astype(v_all.dtype)
     o = jax.lax.dot_general(
-        probs, v_win[...],
+        probs, v_all,
         dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
     )
-    o_ref[0] = o.astype(o_ref.dtype)
+    o_ref[0, 0] = o.astype(o_ref.dtype)
 
 
 def paged_attend(pool_k, pool_v, scale_k, scale_v, bt, start, q, k_new,
@@ -206,34 +232,67 @@ def paged_attend(pool_k, pool_v, scale_k, scale_v, bt, start, q, k_new,
     # into VMEM once per head and reused for every slot's window
     slab = pl.BlockSpec((num_pages, 1, page, head_dim),
                         lambda h, n, *_: (0, h, 0, 0))
-    vec = pl.BlockSpec((1, 1, head_dim), lambda h, n, *_: (n, h, 0))
+    # Mosaic wants a block's last two dims (8, 128)-divisible or whole, so
+    # the per-slot vectors ride as [N, H, 1, D] (unit sublane axis) and the
+    # scale sidecars head-major [H, NP, nb]: every block below is whole in
+    # its last two dims
+    vec = pl.BlockSpec((1, 1, 1, head_dim), lambda h, n, *_: (n, h, 0, 0))
     in_specs = [slab, slab]
     operands = [pool_k, pool_v]
     if quant:
         nb = (page * head_dim) // quant_comm.DEFAULT_BLOCK
-        srow = pl.BlockSpec((num_pages, 1, nb), lambda h, n, *_: (0, h, 0))
+        srow = pl.BlockSpec((1, num_pages, nb), lambda h, n, *_: (h, 0, 0))
         in_specs += [srow, srow]
-        operands += [scale_k, scale_v]
+        operands += [scale_k.transpose(1, 0, 2), scale_v.transpose(1, 0, 2)]
     in_specs += [vec, vec, vec]
-    operands += [q, k_new, v_new]
+    operands += [x[:, :, None, :] for x in (q, k_new, v_new)]
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # bt + start ride SMEM, read per slot
             grid=(heads, n),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, head_dim),
-                                   lambda h, n, *_: (n, h, 0)),
+            out_specs=vec,
             scratch_shapes=[
                 pltpu.VMEM((w, head_dim), cdt),
                 pltpu.VMEM((w, head_dim), cdt),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((n, heads, head_dim), cdt),
+        out_shape=jax.ShapeDtypeStruct((n, heads, 1, head_dim), cdt),
         compiler_params=tpu_compiler_params("parallel", "arbitrary"),
+        name="paged_attend",
         interpret=_interpret(),
     )(bt, start, *operands)
+    return out[:, :, 0, :]
+
+
+def paged_attend_reference(pool_k, pool_v, scale_k, scale_v, bt, start, q,
+                           k_new, v_new):
+    """The unfused spelling of `paged_attend`'s contract, in plain XLA:
+    gather_view, insert the fresh K/V at the cursor with the ring path's
+    dynamic-update-slice, then `_attend_over_cache`'s math verbatim
+    (pre-projection). What the kernel is held to — by the CPU tests in
+    interpret mode and by chip_smoke.py compiled."""
+    from tpukit.serve import paged as paged_lib  # lazy: serve imports ops
+
+    cdt = q.dtype
+    view_k = paged_lib.gather_view(pool_k, scale_k, bt, cdt)
+    view_v = paged_lib.gather_view(pool_v, scale_v, bt, cdt)
+    upd = lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (0, s, 0))
+    view_k = jax.vmap(upd)(view_k, k_new[:, :, None, :], start)
+    view_v = jax.vmap(upd)(view_v, v_new[:, :, None, :], start)
+    d = q.shape[-1]
+    scores = jnp.einsum(
+        "bhqd,bhkd->bhqk", q[:, :, None, :], view_k
+    ) * (1.0 / d**0.5)
+    q_pos = (start[:, None] + jnp.arange(1))[:, None, :, None]
+    key_pos = jnp.arange(view_k.shape[2])[None, None, None, :]
+    scores = jnp.where(
+        key_pos <= q_pos, scores, jnp.asarray(NEG_INF, scores.dtype)
+    )
+    probs = jax.nn.softmax(scores.astype(jnp.float32), -1).astype(view_v.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, view_v)[:, :, 0, :]
 
 
 def fused_paged_attention(pool_k, pool_v, scale_k, scale_v, bt, start, q,
@@ -257,8 +316,6 @@ def fused_paged_attention(pool_k, pool_v, scale_k, scale_v, bt, start, q,
             f"heads={heads} must divide model={m} (the paged serving grid "
             f"picker guarantees this)"
         )
-    from tpukit.compat import shard_map
-
     pool_spec = P(None, "model", None, None)
     head_spec = P(None, "model", None)
     if scale_k is None:
@@ -269,12 +326,12 @@ def fused_paged_attention(pool_k, pool_v, scale_k, scale_v, bt, start, q,
             fn, mesh=mesh,
             in_specs=(pool_spec, pool_spec, P(), P(), head_spec,
                       head_spec, head_spec),
-            out_specs=head_spec, check_rep=False,
+            out_specs=head_spec, check_vma=False,
         )(pool_k, pool_v, bt, start, q, k_new, v_new)
     scale_spec = P(None, "model", None)
     return shard_map(
         paged_attend, mesh=mesh,
         in_specs=(pool_spec, pool_spec, scale_spec, scale_spec, P(), P(),
                   head_spec, head_spec, head_spec),
-        out_specs=head_spec, check_rep=False,
+        out_specs=head_spec, check_vma=False,
     )(pool_k, pool_v, scale_k, scale_v, bt, start, q, k_new, v_new)

@@ -61,7 +61,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from tpukit.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tpukit import mesh as mesh_lib

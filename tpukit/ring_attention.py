@@ -49,7 +49,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpukit.compat import axis_size as compat_axis_size
 from tpukit.ops.attention import NEG_INF
 
 
@@ -99,7 +98,7 @@ def ulysses_attention(
     computation is the standard causal attention over the full sequence —
     no online-state stitching at all.
     """
-    ring = compat_axis_size(axis_name)
+    ring = jax.lax.axis_size(axis_name)
     heads = q.shape[1]
     if heads % ring:
         raise ValueError(
@@ -163,7 +162,7 @@ def ring_causal_attention(
         return _zigzag_ring(q, k, v, scale=scale, axis_name=axis_name, pad_mask=pad_mask)
     if layout != "contiguous":
         raise ValueError(f"unknown ring layout {layout!r}")
-    ring = compat_axis_size(axis_name)
+    ring = jax.lax.axis_size(axis_name)
     my_index = jax.lax.axis_index(axis_name)
     batch, _, s_local, _ = q.shape
     if pad_mask is None:
@@ -243,7 +242,7 @@ def _zigzag_ring(q, k, v, *, scale, axis_name, pad_mask):
     Matmuls stay in the input dtype (MXU) with f32 accumulation; softmax
     state is f32; the ppermutes issue before the hop compute for overlap.
     """
-    ring = compat_axis_size(axis_name)
+    ring = jax.lax.axis_size(axis_name)
     my_index = jax.lax.axis_index(axis_name)
     batch, _, s_local, _ = q.shape
     if s_local % 2:

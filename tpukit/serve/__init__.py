@@ -42,5 +42,7 @@ from tpukit.serve.fleet import (  # noqa: F401
 from tpukit.serve.ledger import (  # noqa: F401
     ProcessFleet,
     RequestLedger,
+    local_tpu_chips,
     serve_from_ledger,
+    worker_chip_env,
 )

@@ -142,14 +142,12 @@ def _advance(params, cfg, buf, cache, cursors, active, limits, keys,
     return buf, cache, cursors, active
 
 
-# NOTE (container jaxlib 0.4.37): buffer donation is deliberately OMITTED
-# on the serve programs. Donated executables DESERIALIZED from the
-# persistent compilation cache mis-alias their inputs on this jaxlib —
-# reproduced deterministically: a fresh process with a warm cache decodes
-# garbage (slots with 0 or limit-overrunning generated counts) while the
-# compiling process is correct, and stripping donate_argnames fixes the
-# round-trip with no other change. The KV ring at test/bench scale copies
-# cheaply; re-add donation when the container jaxlib moves past the bug.
+# NOTE: buffer donation is deliberately OMITTED on the serve programs. An
+# earlier jaxlib mis-aliased the inputs of donated executables DESERIALIZED
+# from the persistent compilation cache (a fresh process with a warm cache
+# decoded garbage while the compiling process was correct). Not re-tested
+# on 0.9.0; see ROADMAP S6a — restoring donation moves a metric and is a
+# perf PR of its own.
 @partial(
     jax.jit,
     static_argnames=("cfg", "eos_id", "temperature", "top_k", "mesh", "steps"),
@@ -189,8 +187,7 @@ def decode_step(params, cfg: gpt.GPTConfig, buf, cache, cursors, active,
     return jax.lax.fori_loop(0, steps, tick, (buf, cache, cursors, active))
 
 
-# No donation here either — see the decode_step note (persistent-cache
-# deserialization of donated executables mis-aliases on this jaxlib).
+# No donation here either — see the decode_step note.
 @partial(
     jax.jit,
     static_argnames=("cfg",),
@@ -237,8 +234,7 @@ def prefill_slots(params, cfg: gpt.GPTConfig, buf, cache, cursors, active,
     return buf, cache, cursors, active, limits, keys
 
 
-# No donation — see the decode_step note (persistent-cache deserialization
-# of donated executables mis-aliases on this jaxlib).
+# No donation — see the decode_step note.
 @partial(jax.jit, static_argnames=("cfg",))
 def prefill_chunk_paged(params, cfg: gpt.GPTConfig, buf, cache, cursors,
                         active, limits, keys, slots, rows, starts, is_last,
@@ -280,8 +276,7 @@ def prefill_chunk_paged(params, cfg: gpt.GPTConfig, buf, cache, cursors,
     return buf, cache, cursors, active, limits, keys
 
 
-# No donation — see the decode_step note (persistent-cache deserialization
-# of donated executables mis-aliases on this jaxlib).
+# No donation — see the decode_step note.
 @jax.jit
 def adopt_slot(buf, cursors, active, limits, keys, slot, row, prompt_len,
                new_limit, new_key):
@@ -349,8 +344,7 @@ def decode_loop(params, cfg: gpt.GPTConfig, buf, prompt_lens,
     return buf, cursors
 
 
-# No donation — see the decode_step note (persistent-cache deserialization
-# of donated executables mis-aliases on this jaxlib).
+# No donation — see the decode_step note.
 @partial(
     jax.jit,
     static_argnames=("cfg", "eos_id", "temperature", "top_k", "mesh"),

@@ -229,8 +229,8 @@ def _verify_body(params, cfg: gpt.GPTConfig, buf, cache, cursors, active,
     return buf, cache, new_cursors, new_active, accepted, n_app
 
 
-# No donation — the serve-path rule (decode.decode_step note: persistent-
-# cache deserialization of donated executables mis-aliases on this jaxlib).
+# No donation — the serve-path rule (decode.decode_step note: not
+# re-tested on 0.9.0; see ROADMAP S6a).
 @partial(
     jax.jit,
     static_argnames=("cfg", "k", "eos_id", "temperature", "top_k",

@@ -57,3 +57,19 @@ def tiny_params(tiny_config):
 @pytest.fixture()
 def rng():
     return np.random.RandomState(1234)
+
+
+@pytest.fixture()
+def fresh_compiles():
+    """The persistent compile cache off for one test. Its key leaves the
+    metadata out, so a hit hands back the executable of whichever compile
+    wrote the entry, with THAT compile's `op_name`s: a test that reads names
+    out of a compiled module's text has to compile it itself."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()

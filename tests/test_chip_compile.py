@@ -164,6 +164,79 @@ def test_v5e_compiles(v5e, monkeypatch, name):
     assert set(expect) <= set(kernels), kernels
 
 
+def _small_cfg(**kw):
+    from tpukit.model import gpt
+
+    return gpt.GPTConfig(dim=DIM, heads=HEADS, head_dim=HEAD_DIM, num_layers=2,
+                         vocab_size=VOCAB, max_position_embeddings=1024,
+                         compute_dtype=BF16, vocab_pad_multiple=128, **kw)
+
+
+def _scoped_train_step(devices):
+    """The trainer's own step (two GPT-small layers, flash + fused head+CE)
+    lowered for one described chip: every kernel sits under the `loss` scope
+    and a model scope."""
+    from tpukit import shardings
+    from tpukit.mesh import create_mesh
+    from tpukit.train import create_train_state, make_optimizer, make_step_fns
+
+    cfg = _small_cfg(attention_impl="flash")
+    strategy = shardings.SingleDevice(create_mesh(None, devices=devices[:1]))
+    opt = make_optimizer(3e-4)
+    shapes = jax.eval_shape(lambda: create_train_state(jax.random.PRNGKey(0), cfg, opt, strategy))
+    step, _, state_sh = make_step_fns(cfg, opt, strategy, shapes)
+    bsh = strategy.batch_sharding()
+    arr = lambda dt: jax.ShapeDtypeStruct((2, 1023), dt, sharding=bsh)  # noqa: E731
+    state = jax.tree.map(lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), shapes, state_sh)
+    batch = {"input_ids": arr(I32), "position_ids": arr(I32), "mask": arr(jnp.bool_)}
+    return step.lower(state, batch, arr(I32))
+
+
+def _scoped_fused_decode(devices):
+    """The serve engine's decode quantum with the fused paged kernel on, for
+    one described chip: `paged_attend` sits under decode/attn."""
+    from tpukit.model import gpt
+    from tpukit.serve import decode, paged
+
+    cfg = _small_cfg(fused_decode=True)
+    one = SingleDeviceSharding(devices[0])
+    on = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    n, page, per_slot = 8, 16, 16
+    params = on(jax.eval_shape(lambda: gpt.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on(jax.eval_shape(lambda: paged.init_paged_cache(cfg, n * per_slot + 1, page, per_slot, n, "bf16")))
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    return decode.decode_step.lower(
+        params, cfg, sds((n, page * per_slot), I32), cache, sds((n,), I32), sds((n,), jnp.bool_),
+        sds((n,), I32), sds((n, 2), jnp.uint32), 0, 0.0, 0, None, steps=2)
+
+
+@pytest.mark.parametrize("name,lower,expect,scope_of", [
+    ("train_step", _scoped_train_step,
+     {"flash_fwd": 2, "flash_bwd": 2, "head_ce_fwd": 1, "head_ce_bwd": 1},
+     {"flash_fwd": "loss/attn", "flash_bwd": "loss/attn", "head_ce_fwd": "loss", "head_ce_bwd": "loss"}),
+    ("fused_decode_step", _scoped_fused_decode, {"paged_attend": 2},
+     {"paged_attend": "decode/attn"}),
+])
+def test_v5e_named_scopes_leave_kernel_names_alone(v5e, monkeypatch, name, lower, expect, scope_of):
+    """The benchmark finds kernels in the trace and the HLO by instruction
+    name. With the model's `jax.named_scope`s on, the kernels compiled for
+    the described v5e keep their names and counts, and `instruction_scopes`
+    places each call under the program's own names."""
+    from tpukit.obs.xla import instruction_scopes
+
+    monkeypatch.setattr(pallas_attention, "on_tpu_backend", lambda: True)
+    text = lower(v5e).compile().as_text()
+    assert kernel_calls(text) == expect
+    scopes = instruction_scopes(text)
+    for kernel, scope in scope_of.items():
+        calls = {k: v for k, v in scopes.items() if kernel_calls(
+            f'%{k} = custom_call_target="tpu_custom_call"') == {kernel: 1}}
+        assert len(calls) == expect[kernel] and set(calls.values()) == {scope}, calls
+    if name == "train_step":
+        assert {"optimizer", "loss/embed", "loss/ln", "loss/ffn"} <= set(scopes.values())
+
+
 # ---------------------------------------------------------------------------
 # Nothing hides the device
 # ---------------------------------------------------------------------------

@@ -80,6 +80,103 @@ def test_epoch_breakdown_spans_windows():
     assert "goodput" in format_breakdown(ep)
 
 
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: records enter/exit order."""
+
+    log: list = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs = name, kwargs
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.kwargs))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, self.kwargs))
+
+
+def test_span_enters_the_annotation_it_was_given_and_sums_are_unchanged():
+    """One `with`: phase sums (outermost only), the handed-over annotation
+    under `tpukit:<name>` for outer AND nested spans, and the span's own two
+    readings of the run clock."""
+    import functools
+
+    _FakeAnnotation.log = []
+    plain = SpanTimeline()
+    tl = SpanTimeline(annotation=_FakeAnnotation,
+                      step_annotation=functools.partial(_FakeAnnotation, "train"))
+    t_run0 = time.perf_counter()
+    tl.set_epoch(t_run0)
+    for timeline in (plain, tl):
+        with timeline.span("eval") as outer:
+            with timeline.span("telemetry") as inner:
+                time.sleep(0.01)
+        with timeline.span("step", step_num=7):
+            time.sleep(0.005)
+    names = [(kind, name) for kind, name, _ in _FakeAnnotation.log]
+    assert names == [
+        ("enter", "tpukit:eval"), ("enter", "tpukit:telemetry"),
+        ("exit", "tpukit:telemetry"), ("exit", "tpukit:eval"),
+        ("enter", "tpukit:step"), ("enter", "train"),
+        ("exit", "train"), ("exit", "tpukit:step"),
+    ]
+    assert _FakeAnnotation.log[5][2] == {"step_num": 7}
+    # the readings are on the run clock, nested inside the outer span's
+    assert 0.0 <= outer.t0 <= inner.t0 <= inner.t1 <= outer.t1 <= time.perf_counter() - t_run0
+    assert inner.t1 - inner.t0 >= 0.009
+    # the sums are what a timeline without an annotation keeps
+    win, ref = tl.window(), plain.window()
+    assert set(win["seconds"]) == set(ref["seconds"]) == {"eval", "step", "other"}
+    assert win["seconds"]["eval"] == pytest.approx(outer.t1 - outer.t0, abs=1e-9)
+    assert abs(sum(win["seconds"].values()) - win["total_s"]) < 1e-6
+
+
+def test_annotate_names_another_threads_work_outside_the_sums():
+    _FakeAnnotation.log = []
+    tl = SpanTimeline(annotation=_FakeAnnotation)
+    with tl.annotate("prefetch.produce"):
+        time.sleep(0.002)
+    assert [n for _, n, _ in _FakeAnnotation.log] == ["tpukit:prefetch.produce"] * 2
+    assert set(tl.window()["seconds"]) == {"other"}
+    with SpanTimeline().annotate("x"):  # no annotation handed over: a no-op
+        pass
+
+
+def test_lap_returns_the_walls_since_the_last_lap():
+    tl = SpanTimeline()
+    with tl.span("admit"):
+        time.sleep(0.002)
+    with tl.span("decode"):
+        pass
+    first = tl.lap()
+    assert set(first) == {"admit", "decode"} and first["admit"] >= 0.002
+    assert tl.lap() == {}
+    with tl.span("admit"):
+        pass
+    tl.epoch()  # a new run starts every account afresh
+    assert tl.lap() == {}
+    assert "admit" not in tl.window()["seconds"]
+
+
+def test_prefetcher_worker_runs_process_inside_the_span_it_was_given():
+    from tpukit.prefetch import HostPrefetcher
+
+    _FakeAnnotation.log = []
+    tl = SpanTimeline(annotation=_FakeAnnotation)
+    seen = []
+
+    def process(x):
+        seen.append(len(_FakeAnnotation.log))  # 1, 3, 5: entered, not yet left
+        return x * 2
+
+    pf = HostPrefetcher(range(3), process, depth=2, span=tl.annotate)
+    assert list(pf) == [0, 2, 4]
+    assert [n for _, n, _ in _FakeAnnotation.log] == ["tpukit:prefetch.produce"] * 6
+    assert seen == [1, 3, 5]
+    assert list(HostPrefetcher(range(3), lambda x: x, depth=1)) == [0, 1, 2]  # no span given
+
+
 # ---------------------------------------------------------------------------
 # XLA static analysis
 # ---------------------------------------------------------------------------
@@ -158,6 +255,77 @@ def test_compiled_stats_on_cpu_mesh(tiny_config):
     assert mem is not None
     assert mem["temp_size_in_bytes"] >= 0
     assert mem["peak_bytes_estimate"] > 0
+
+
+def test_train_step_names_every_training_scope(tiny_config, fresh_compiles):
+    """The compiled text of a tiny train step carries the model's named
+    scopes in `op_name`, and `instruction_scopes` maps instructions to them
+    through the autodiff wrappers."""
+    from tpukit.obs import SCOPES, instruction_scopes
+    from tpukit.shardings import SingleDevice
+    from tpukit.train import create_train_state, make_optimizer, make_step_fns
+
+    opt = make_optimizer(1e-3)
+    shapes = jax.eval_shape(
+        lambda: create_train_state(jax.random.PRNGKey(0), tiny_config, opt)
+    )
+    step, evaluate, _ = make_step_fns(tiny_config, opt, SingleDevice(), shapes)
+    batch, targets = _batch_structs(4, 16)
+    text = step.lower(shapes, batch, targets).compile().as_text()
+    scopes = instruction_scopes(text)
+    paths = set(scopes.values())
+    leaves = {p.split("/")[-1] for p in paths}
+    assert {"embed", "attn", "ffn", "ln", "loss", "optimizer"} <= leaves, leaves
+    assert all(set(p.split("/")) <= set(SCOPES) for p in paths)
+    # the backward of attention is still attention's: jvp(...) and
+    # transpose(jvp(...)) are read through
+    assert "transpose(jvp(attn))" in text and "loss/attn" in paths
+    # the optimizer's update is outside the loss
+    assert "optimizer" in paths and not any(p.startswith("loss/optimizer") for p in paths)
+    # the eval step's loss is under the same name; a cond branch traced apart
+    # repeats the stack from its root, which is read as one path
+    eval_paths = set(instruction_scopes(
+        evaluate.lower(shapes, batch, targets).compile().as_text()).values())
+    assert {"loss", "loss/attn", "loss/ffn"} <= eval_paths and "loss/loss" not in eval_paths
+    # the routed FFN is `moe`, never `ffn`
+    moe_cfg = tiny_config.replace(num_experts=2)
+    moe_shapes = jax.eval_shape(
+        lambda: create_train_state(jax.random.PRNGKey(0), moe_cfg, opt)
+    )
+    moe_step, _, _ = make_step_fns(moe_cfg, opt, SingleDevice(), moe_shapes)
+    moe_paths = set(instruction_scopes(
+        moe_step.lower(moe_shapes, batch, targets).compile().as_text()).values())
+    assert "loss/moe" in moe_paths and "loss/ffn" not in moe_paths
+
+
+def test_instruction_scopes_maps_a_known_fusion():
+    from tpukit.obs import instruction_scopes
+
+    text = """\
+HloModule jit_train_step
+%fused_computation.3 (p: bf16[8,64]) -> bf16[8,64] {
+  %p = bf16[8,64]{1,0} parameter(0)
+  ROOT %max.1 = bf16[8,64]{1,0} maximum(%p, %p), metadata={op_name="jit(train_step)/loss/jvp(ffn)/jit(relu)/max" stack_frame_id=9}
+}
+ENTRY %main.1 (a: bf16[8,64]) -> bf16[8,64] {
+  %a = bf16[8,64]{1,0:T(8,128)(2,1)} parameter(0), metadata={op_name="state.params['layers']"}
+  %fusion.3 = bf16[8,64]{1,0:T(8,128)(2,1)} fusion(%a), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(train_step)/loss/jvp(ffn)/jit(relu)/max" stack_frame_id=9}
+  %flash_bwd.1 = (f32[24,1,1024,64]{3,2,1,0}, bf16[24,1024,64]{2,1,0}) custom-call(%fusion.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/loss/transpose(jvp(attn))/flash_bwd/pallas_call" stack_frame_id=4}
+  %jvp_head_ce_bwd_.1 = f32[8,8]{1,0} custom-call(%fusion.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/loss/transpose(loss)/jvp(head_ce_bwd)/pallas_call"}
+  %bitcast.7 = bf16[8,64]{1,0} bitcast(%fusion.3), metadata={op_name="decode/attn/squeeze;decode/attn/attend/transpose"}
+  %copy.2 = bf16[8,64]{1,0} copy(%bitcast.7), metadata={op_name="jit(decode_step)/while/body/closed_call/decode/attn/kv_gather/gather"}
+  ROOT %add.9 = bf16[8,64]{1,0} add(%copy.2, %copy.2)
+}
+"""
+    assert instruction_scopes(text) == {
+        "max.1": "loss/ffn",
+        "fusion.3": "loss/ffn",
+        "flash_bwd.1": "loss/attn",  # the kernel's own name is kernel_calls' business
+        "jvp_head_ce_bwd_.1": "loss",  # transpose(loss) restates the scope it was taken under
+        "bitcast.7": "decode/attn",  # merged op_names: the first one
+        "copy.2": "decode/attn/kv_gather",
+    }
+    assert instruction_scopes(text, scopes=("attn",))["copy.2"] == "attn"
 
 
 def test_compiled_stats_is_none_on_lowering_failure():

@@ -450,3 +450,42 @@ def test_serve_jsonl_windows_and_report(tok, cfg, params, tmp_path):
     text = report.summarize(recs)
     assert "== serving ==" in text
     assert "tokens/s" in text and "occupancy" in text
+
+
+# ---------------------------------------------------------------------------
+# Device-side names: the serve programs' scopes reach the compiled module.
+# ---------------------------------------------------------------------------
+
+
+def test_paged_serve_programs_name_every_serving_scope(cfg, params, fresh_compiles):
+    """The compiled text of a tiny paged decode quantum and of a chunked
+    prefill names the serving scopes in `op_name`: together with the train
+    step's (tests/test_obs.py) that is every name of `obs.SCOPES`."""
+    from tpukit.obs import SCOPES, instruction_scopes
+    from tpukit.serve import paged
+    from tpukit.serve.decode import prefill_chunk_paged
+
+    n, page, per_slot = 4, 8, 4
+    cache = paged.init_paged_cache(cfg, n * per_slot + 1, page, per_slot, n, "f32")
+    buf = jnp.zeros((n, page * per_slot), jnp.int32)
+    cur, lim = jnp.ones((n,), jnp.int32), jnp.full((n,), 24, jnp.int32)
+    act, keys = jnp.ones((n,), bool), jnp.zeros((n, 2), jnp.uint32)
+    decode = decode_step.lower(params, cfg, buf, cache, cur, act, lim, keys,
+                               0, 0.0, 0, None, steps=2).compile().as_text()
+    paths = set(instruction_scopes(decode).values())
+    assert {"decode/embed", "decode/ln", "decode/attn", "decode/attn/kv_gather",
+            "decode/attn/kv_write", "decode/attn/attend", "decode/ffn",
+            "decode/kv_write",  # forward_cached's restack, outside any one layer
+            "decode/head", "decode/head/ln", "decode/sample"} <= paths, paths
+    a = 2
+    z = lambda shape, dt: jnp.zeros(shape, dt)  # noqa: E731
+    prefill = prefill_chunk_paged.lower(
+        params, cfg, buf, cache, cur, act, lim, keys, z((a,), jnp.int32),
+        z((a, page), jnp.int32), z((a,), jnp.int32), z((a,), bool),
+        z((a,), jnp.int32), z((a,), jnp.int32), z((a, 2), jnp.uint32),
+    ).compile().as_text()
+    prefill_paths = set(instruction_scopes(prefill).values())
+    assert {"prefill/attn/kv_gather", "prefill/attn/kv_write", "prefill/attn/attend",
+            "prefill/ffn"} <= prefill_paths, prefill_paths
+    serving = {c for p in paths | prefill_paths for c in p.split("/")}
+    assert serving == set(SCOPES) - {"loss", "optimizer", "moe"}

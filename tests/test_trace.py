@@ -139,6 +139,127 @@ def test_window_records_carry_attribution(tok, cfg, params, tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# The quantum's host account, slot counters and token-delivery stamps.
+# ---------------------------------------------------------------------------
+
+HOST_SPANS = {"admit", "place", "prefill", "decode", "retire", "window", "idle", "other"}
+PAGED = dict(page_size=8, prefill_chunk=8)
+
+
+@pytest.mark.parametrize("serve_kw,qps", [(PAGED, 0.0), ({}, 0.0), (PAGED, 40.0)],
+                         ids=["paged", "ring", "paged_open_loop"])
+def test_quantum_host_walls_counters_and_deliveries(tok, cfg, params, serve_kw, qps):
+    serve = ServeConfig(slots=2, buckets=(8, 16), max_new_tokens=MAX_NEW,
+                        window_steps=4, **serve_kw)
+    reqs = synthetic_request_stream(tok, 8, seed=3, max_new_tokens=MAX_NEW,
+                                    buckets=(8, 16), qps=qps)
+    tracer = TraceRecorder()
+    eng = ServeEngine(params, cfg, serve, eos_id=int(tok.eos_token_id), tracer=tracer)
+    comps = eng.run(list(reqs), max_wall_s=300)
+    quanta = [e for e in tracer.snapshot() if e["ev"] == "quantum"]
+    assert len(quanta) >= 4 and len(comps) == 8
+
+    s1_prev = 0.0  # the first quantum's account starts with the run
+    for q in quanta:
+        assert set(q["host"]) <= HOST_SPANS and "other" in q["host"] and "sync" not in q["host"]
+        assert all(v >= 0.0 for v in q["host"].values())
+        assert sum(q["host"].values()) == pytest.approx(q["s0"] - s1_prev, abs=1e-6)
+        # the dispatch wall is one of the host walls, read from the same span
+        assert q["host"]["decode"] == pytest.approx(q["t1"] - q["t0"], abs=1e-9)
+        assert s1_prev <= q["t0"] <= q["t1"] <= q["s0"] <= q["s1"]
+        s1_prev = q["s1"]
+        # counters read at the dispatch
+        assert q["decoding"] == len(q["lanes"]) >= 1
+        assert 0 <= q["prefilling"] <= serve.slots - q["decoding"]
+        assert q["pending"] >= 0
+        assert (q["free_pages"] is None) == (not serve.paged)
+        assert 0 <= q["delivered"] <= q["steps"] * q["decoding"]
+        assert 0 <= q["finished"] <= q["decoding"]
+    assert sum(q["delivered"] for q in quanta) == eng.generated_tokens
+    assert sum(q["finished"] for q in quanta) == len(comps)
+    if serve.paged:  # admission, the block-table push and the window record have names now
+        assert any(q["host"].get("admit", 0) > 0 for q in quanta)
+        assert any(q["host"].get("place", 0) > 0 for q in quanta)
+        assert any(q["host"].get("prefill", 0) > 0 for q in quanta)
+    assert any(q["host"].get("retire", 0) > 0 for q in quanta)
+    assert any(q["host"].get("window", 0) > 0 for q in quanta)
+    if qps:  # an open loop's sleeping is named, so it cannot read as host work
+        assert any(q["host"].get("idle", 0) > 0 for q in quanta)
+
+    finish_t = {e["rid"]: e["t"] for e in tracer.snapshot() if e["ev"] == "finish"}
+    sync_ends = {q["s1"] for q in quanta}
+    for c in comps:
+        assert c.reason in ("eos", "length")
+        assert sum(n for _, n in c.deliveries) == c.generated
+        times = [t for t, _ in c.deliveries]
+        assert times == sorted(times) and set(times) <= sync_ends  # stamped when a fetch returned
+        assert c.first_token_s == times[0] and c.last_token_s == times[-1]
+        assert c.first_token_s >= c.active_s >= c.admit_s >= c.arrival_s
+        assert c.last_token_s >= c.done_s
+        assert finish_t[c.rid] == c.last_token_s
+
+
+def test_untraced_engine_keeps_no_ring_but_stamps_deliveries(tok, cfg, params):
+    serve = ServeConfig(slots=2, buckets=(8, 16), max_new_tokens=MAX_NEW, **PAGED)
+    reqs = synthetic_request_stream(tok, 4, seed=3, max_new_tokens=MAX_NEW, buckets=(8, 16))
+    eng = ServeEngine(params, cfg, serve, eos_id=int(tok.eos_token_id))
+    comps = eng.run(list(reqs), max_wall_s=300)
+    assert all(sum(n for _, n in c.deliveries) == c.generated for c in comps)
+    assert eng._quantum is None and 0 < len(eng._periods) <= 64
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["untraced", "traced"])
+def test_a_slow_quantum_says_so_on_logging_not_stdout(tok, cfg, params, traced,
+                                                      monkeypatch, caplog, capsys):
+    """A forced stall (a device_get that sleeps once, well into the run)
+    yields ONE line on `logging` with the iteration's index, period and host
+    walls — traced or not — and nothing on stdout, whose last line is a
+    benchmark's result."""
+    import time
+
+    serve = ServeConfig(slots=2, buckets=(8, 16), max_new_tokens=MAX_NEW, **PAGED)
+    reqs = synthetic_request_stream(tok, 12, seed=3, max_new_tokens=MAX_NEW, buckets=(8, 16))
+    eng = ServeEngine(params, cfg, serve, eos_id=int(tok.eos_token_id),
+                      tracer=TraceRecorder() if traced else None)
+    real, fetches = jax.device_get, []
+
+    def stalling(x):
+        if isinstance(x, tuple):  # the per-quantum cursor fetch
+            fetches.append(1)
+            if len(fetches) == 14:
+                time.sleep(0.6)
+        return real(x)
+
+    # warm every program first: a compile inside the run would be the slow quantum
+    ServeEngine(params, cfg, serve, eos_id=int(tok.eos_token_id)).run(list(reqs), max_wall_s=300)
+    monkeypatch.setattr(jax, "device_get", stalling)
+    with caplog.at_level("WARNING", logger="tpukit.serve.engine"):
+        eng.run(list(reqs), max_wall_s=300)
+    assert len(fetches) > 14
+    lines = [r.getMessage() for r in caplog.records if "slow quantum" in r.getMessage()]
+    assert len(lines) == 1, lines
+    assert lines[0].startswith("slow quantum 13:") and "host ms {" in lines[0]
+    assert "sync wait 6" in lines[0]  # the stall was in the wait: 600-odd ms
+    assert "slow quantum" not in capsys.readouterr().out
+
+
+def test_trees_and_chrome_export_hold_on_the_extended_events(tok, cfg, params):
+    eng, tracer, comps = _run_traced(params, cfg, tok, **PAGED)
+    events = tracer.snapshot()
+    trees = trace_lib.build_trees(events)
+    assert trace_lib.completeness(trees) == 1.0 and len(trees) == len(comps)
+    chrome = trace_lib.to_chrome(events)
+    json.dumps(chrome)  # every field is serialisable
+    bars = [e for e in chrome["traceEvents"] if e.get("cat") == "quantum" and e["name"].startswith("dispatch")]
+    assert bars and all({"lanes", "host", "delivered", "finished"} <= set(b["args"]) for b in bars)
+    # events written before this PR (no host, no counters) still export
+    old = [{k: v for k, v in e.items() if k in ("ev", "trace", "t0", "t1", "s0", "s1", "steps", "lanes", "replica")}
+           if e["ev"] == "quantum" else e for e in events]
+    assert trace_lib.completeness(trace_lib.build_trees(old)) == 1.0
+    assert len(trace_lib.to_chrome(old)["traceEvents"]) == len(chrome["traceEvents"])
+
+
+# ---------------------------------------------------------------------------
 # Observer discipline: bit-identical tokens, bounded + cheap ring.
 # ---------------------------------------------------------------------------
 

@@ -48,7 +48,8 @@ import jax
 import jax.numpy as jnp
 
 from tpukit.ops.attention import causal_attention
-from tpukit.ops.layers import dropout, layer_norm, linear
+from tpukit.ops.layers import dropout, linear
+from tpukit.ops.layers import layer_norm as _layer_norm
 from tpukit.ops.moe_dispatch import moe_ffn_a2a, moe_ffn_xla
 
 Params = Any  # nested dict pytree of jax.Array
@@ -349,6 +350,15 @@ def param_count(params: Params) -> int:
 # --------------------------------------------------------------------------
 
 
+# Device-side names (`jax.named_scope`): metadata on the ops, no instruction
+# added. One spelling for the training and the cached/paged forwards: embed,
+# attn, ffn, moe, ln, head here; kv_gather, kv_write, attend where the cache
+# is read and written. `tpukit.obs.xla.instruction_scopes` maps a compiled
+# module's instructions back to them.
+layer_norm = jax.named_scope("ln")(_layer_norm)
+
+
+@jax.named_scope("embed")
 def apply_embeddings(params: Params, cfg: GPTConfig, input_ids, position_ids) -> jax.Array:
     """Token + position embedding sum (models/gpt.py:180-185), cast to the
     compute dtype."""
@@ -357,6 +367,7 @@ def apply_embeddings(params: Params, cfg: GPTConfig, input_ids, position_ids) ->
     return x.astype(cfg.compute_dtype)
 
 
+@jax.named_scope("ffn")
 def _apply_feed_forward(layer, cfg: GPTConfig, x, rng, deterministic):
     """FeedForward (models/gpt.py:33-41): up -> relu -> down -> relu -> drop.
     The post-down_proj activation is the reference's (unusual) behavior."""
@@ -367,6 +378,7 @@ def _apply_feed_forward(layer, cfg: GPTConfig, x, rng, deterministic):
     return dropout(h, cfg.dropout, rng, deterministic)
 
 
+@jax.named_scope("moe")
 def _apply_moe_ffn(layer, cfg: GPTConfig, x, rng, deterministic, pad_mask=None):
     """Routed mixture-of-experts FFN: Switch-style top-1 by default,
     GShard/Mixtral-style top-k via cfg.router_top_k. Returns (out, aux).
@@ -430,6 +442,7 @@ def _apply_moe_ffn(layer, cfg: GPTConfig, x, rng, deterministic, pad_mask=None):
     return dropout(out, cfg.dropout, rng, deterministic), aux
 
 
+@jax.named_scope("attn")
 def _apply_attention(layer, cfg: GPTConfig, x, pad_mask, rng, deterministic):
     """SelfAttention (models/gpt.py:68-105).
 
@@ -586,6 +599,7 @@ def init_kv_cache(cfg: GPTConfig, batch: int, max_len: int) -> dict:
     }
 
 
+@jax.named_scope("attn")
 def _apply_attention_cached(layer, cfg: GPTConfig, x, k_cache, v_cache, start):
     """Attention for decode: write this chunk's K/V into the cache at
     `start` and attend over all cached positions `<= query position`.
@@ -609,18 +623,21 @@ def _apply_attention_cached(layer, cfg: GPTConfig, x, k_cache, v_cache, start):
     s_max = k_cache.shape[2]
     if jnp.ndim(start) == 1:
         upd = lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (0, s, 0))
-        k_cache = jax.vmap(upd)(k_cache, k, start)
-        v_cache = jax.vmap(upd)(v_cache, v, start)
+        with jax.named_scope("kv_write"):
+            k_cache = jax.vmap(upd)(k_cache, k, start)
+            v_cache = jax.vmap(upd)(v_cache, v, start)
         q_pos = (start[:, None] + jnp.arange(t))[:, None, :, None]
     else:
-        k_cache = jax.lax.dynamic_update_slice(k_cache, k, (0, 0, start, 0))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, v, (0, 0, start, 0))
+        with jax.named_scope("kv_write"):
+            k_cache = jax.lax.dynamic_update_slice(k_cache, k, (0, 0, start, 0))
+            v_cache = jax.lax.dynamic_update_slice(v_cache, v, (0, 0, start, 0))
         q_pos = (start + jnp.arange(t))[None, None, :, None]
 
     out = _attend_over_cache(layer, cfg, q, k_cache, v_cache, q_pos)
     return out, k_cache, v_cache
 
 
+@jax.named_scope("attend")
 def _attend_over_cache(layer, cfg: GPTConfig, q, k_cache, v_cache, q_pos):
     """The cached-attention read: scores over every cache position, causal
     `key_pos <= q_pos` window, softmax, value mix, output projection. ONE
@@ -641,6 +658,7 @@ def _attend_over_cache(layer, cfg: GPTConfig, q, k_cache, v_cache, q_pos):
     return linear(out, layer["attn"]["out"], cfg.compute_dtype)
 
 
+@jax.named_scope("attn")
 def _apply_attention_paged(layer, cfg: GPTConfig, x, pool_k, pool_v,
                            scale_k, scale_v, bt, start, write_mask,
                            mesh=None):
@@ -775,12 +793,13 @@ def forward_cached(params: Params, cfg: GPTConfig, input_ids, position_ids,
             x = x + ffn_out
         else:
             x = x + _apply_feed_forward(layer, cfg, h, None, True)
-    cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-    if paged:
-        cache["bt"] = bt
-        if quant:
+    with jax.named_scope("kv_write"):  # the per-layer caches restacked
+        cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+        if paged and quant:
             cache["ks"] = jnp.stack(new_ks)
             cache["vs"] = jnp.stack(new_vs)
+    if paged:
+        cache["bt"] = bt
     return apply_head(params, cfg, x), cache
 
 
@@ -806,6 +825,7 @@ def forward_hidden(
     return layer_norm(x, params["norm_out"]).astype(cfg.compute_dtype)
 
 
+@jax.named_scope("head")
 def apply_head(params: Params, cfg: GPTConfig, x) -> jax.Array:
     """Final LayerNorm + untied lm_head (models/gpt.py:217-219,229-231).
 

@@ -9,6 +9,9 @@ The pillars, one per module:
                    trees with phase walls (queue_wait/prefill/handoff/
                    decode/sync_stall), the completeness invariant and
                    the Chrome-trace exporter behind `tools/traceview.py`.
+                   The engine's events are stamped by the span primitive
+                   below; a `quantum` event carries the host walls since
+                   the previous sync and the slot counters.
   - `metrics`    — mergeable fleet metrics (round 22): counters, gauges
                    and log-bucket histograms with ONE edge table
                    everywhere (merge = bucket-wise sum, exact), SLO
@@ -16,12 +19,18 @@ The pillars, one per module:
                    per-process snapshot files merged by process 0, and
                    the OpenMetrics textfile exporter behind
                    `tools/top.py`.
-  - `spans`      — `SpanTimeline`: host-phase wall-clock accounting and the
-                   goodput breakdown (fraction of time inside the compiled
-                   step vs data wait / H2D / checkpoint / eval).
+  - `spans`      — `SpanTimeline.span(name)`: THE one way the program
+                   opens a host span (trainer and serve engine). One
+                   `with` accumulates the phase sums behind the goodput
+                   breakdown and the serve windows, enters the
+                   `tpukit:<name>` profiler annotation its owner handed
+                   over, and returns `(t0, t1)` on the run clock.
   - `xla`        — static analysis of compiled steps: `cost_analysis` FLOPs
                    and bytes, `memory_analysis` peak HBM, per-collective
-                   comm bytes parsed from the optimized HLO, plus live
+                   comm bytes parsed from the optimized HLO, the Pallas
+                   kernels in a module (`kernel_calls`), the map from
+                   its instructions to the program's `jax.named_scope`
+                   names (`instruction_scopes`, `SCOPES`), plus live
                    `device.memory_stats()` gauges.
   - `sentinels`  — in-jit global grad/update/param norms and the host-side
                    loss-spike/NaN `SpikeSentinel`.
@@ -75,7 +84,6 @@ from tpukit.obs.trace import (  # noqa: F401
     completeness,
     flush_to_logger,
     phase_stats,
-    request_trace_id,
     to_chrome,
 )
 from tpukit.obs.sentinels import SpikeEvent, SpikeSentinel, global_norms  # noqa: F401
@@ -89,10 +97,13 @@ from tpukit.obs.watchdog import (  # noqa: F401
 from tpukit.obs.xla import (  # noqa: F401
     COLLECTIVE_OPS,
     INVOLUNTARY_REMAT,
+    SCOPES,
     capture_compiler_stderr,
     collective_bytes,
     compiled_stats,
     count_involuntary_remat,
+    instruction_scopes,
+    kernel_calls,
     live_memory_stats,
     wire_bytes,
 )

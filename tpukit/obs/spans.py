@@ -52,36 +52,103 @@ def _breakdown(acc: dict[str, float], total: float) -> dict:
     }
 
 
+class Span:
+    """One open span: the context manager `SpanTimeline.span` returns. After
+    the `with` block `t0` and `t1` hold its two readings of the run clock
+    (seconds since the timeline's epoch), so a caller that also records the
+    span elsewhere (the engine's `TraceRecorder` events) stamps it with the
+    SAME readings the sums were built from."""
+
+    __slots__ = ("_timeline", "_scopes", "name", "t0", "t1")
+
+    def __init__(self, timeline: "SpanTimeline", name: str, scopes: tuple):
+        self._timeline = timeline
+        self._scopes = scopes
+        self.name = name
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "Span":
+        tl = self._timeline
+        for scope in self._scopes:
+            scope.__enter__()
+        tl._depth += 1
+        self.t0 = time.perf_counter() - tl._epoch
+        return self
+
+    def __exit__(self, *exc) -> None:
+        tl = self._timeline
+        self.t1 = time.perf_counter() - tl._epoch
+        tl._depth -= 1
+        if not tl._depth:  # nested: time already attributed to the outer span
+            dt = self.t1 - self.t0
+            for acc in (tl._window_acc, tl._epoch_acc, tl._lap_acc):
+                acc[self.name] = acc.get(self.name, 0.0) + dt
+        for scope in reversed(self._scopes):
+            scope.__exit__(*exc)
+
+
 class SpanTimeline:
     """Accumulate wall clock into named phases; report per window and epoch.
 
-    `span(name)` is a context manager. Nested spans attribute their time to
-    the OUTERMOST span only (no double counting), so helpers wrapped in
-    their own spans can be called from inside a larger phase safely.
+    `span(name)` is THE way the program opens a host span. One `with`:
+
+      - accumulates the wall into the phase sums. Nested spans attribute
+        their time to the OUTERMOST span only (no double counting), so
+        helpers wrapped in their own spans can be called from inside a
+        larger phase safely;
+      - enters `annotation("tpukit:<name>")`, nested spans too, when the
+        owner handed the timeline an annotation class
+        (`jax.profiler.TraceAnnotation`): the span is then an event on the
+        host thread's line of a profiler trace, on the device planes' clock,
+        nested as the profiler nests them. Handed over rather than imported
+        so this module stays importable without jax;
+      - returns a `Span` whose `(t0, t1)` are on the run clock
+        (`set_epoch` pins its origin; construction time until then).
+
+    `step_annotation` (`jax.profiler.StepTraceAnnotation` with its name
+    bound) is entered as well by a span that passes `step_num=`.
     """
 
-    def __init__(self):
+    def __init__(self, annotation=None, step_annotation=None):
         now = time.perf_counter()
+        self._epoch = now
         self._window_start = now
         self._epoch_start = now
         self._window_acc: dict[str, float] = {}
         self._epoch_acc: dict[str, float] = {}
+        self._lap_acc: dict[str, float] = {}
         self._depth = 0
+        self._annotation = annotation
+        self._step_annotation = step_annotation
 
-    @contextlib.contextmanager
-    def span(self, name: str):
-        if self._depth:
-            yield  # nested: time already attributed to the outer span
-            return
-        self._depth += 1
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            self._depth -= 1
-            self._window_acc[name] = self._window_acc.get(name, 0.0) + dt
-            self._epoch_acc[name] = self._epoch_acc.get(name, 0.0) + dt
+    def set_epoch(self, t0: float) -> None:
+        """Pin the run clock: spans read `perf_counter() - t0`. A run loop
+        calls this with its own t0, so span times compare directly with the
+        `now` it hands its step primitives."""
+        self._epoch = t0
+
+    def span(self, name: str, step_num: int | None = None) -> Span:
+        scopes = ()
+        if self._annotation is not None:
+            scopes = (self._annotation(f"tpukit:{name}"),)
+        if step_num is not None and self._step_annotation is not None:
+            scopes += (self._step_annotation(step_num=step_num),)
+        return Span(self, name, scopes)
+
+    def annotate(self, name: str):
+        """The annotation alone, for work on ANOTHER thread (the prefetch
+        worker): named on that thread's line of the trace, never in the
+        loop's sums, which account the loop thread's wall clock only."""
+        if self._annotation is None:
+            return contextlib.nullcontext()
+        return self._annotation(f"tpukit:{name}")
+
+    def lap(self) -> dict[str, float]:
+        """Walls by phase since the previous `lap()` (or `epoch()`), then
+        reset: the per-iteration account a loop reads once a turn (the serve
+        engine's per-quantum `host` walls)."""
+        out, self._lap_acc = self._lap_acc, {}
+        return out
 
     def window(self) -> dict:
         """Close the current window: breakdown since the last `window()` (or
@@ -102,6 +169,7 @@ class SpanTimeline:
         self._epoch_start = now
         self._window_acc = {}
         self._window_start = now
+        self._lap_acc = {}
         return out
 
 

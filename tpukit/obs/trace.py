@@ -16,7 +16,9 @@ per request:
 
 Event vocabulary (each record is `{"ev", "trace", "rid", "replica", ...}`
 with `t` for points and `t0`/`t1` for spans, seconds on the run clock —
-`set_epoch` pins the perf_counter origin so every emitter shares it):
+`set_epoch` pins the perf_counter origin so every emitter shares it; the
+engine stamps its spans through `SpanTimeline.span`, pinned to the same
+origin, and builds its events from those readings):
 
     enqueue      t=arrival_s          request visible to the scheduler
     route        t, dst               router assignment (fleet only)
@@ -29,7 +31,15 @@ with `t` for points and `t0`/`t1` for spans, seconds on the run clock —
                  dispatch+sync pair; `lanes` lists the participating
                  trace ids, [t0,t1] the async-dispatch wall, [s0,s1] the
                  wall-to-sync (device) wall — the per-quantum
-                 dispatch-vs-device attribution ROADMAP #3 wants
+                 dispatch-vs-device attribution ROADMAP #3 wants. Also
+                 `host` {span name: seconds, ..., "other"}: the walls of
+                 the engine's spans between the previous quantum's s1
+                 and this s0, summing to that gap (serial host time);
+                 counters read at dispatch: `decoding`, `prefilling`
+                 (lanes in each phase), `pending` (queue depth),
+                 `free_pages` (None on the ring); and at the sync:
+                 `delivered` (tokens that reached the host), `finished`
+                 (lanes retired)
     finish       t, reason, generated  exactly-once completion
     requeue      t, from_replica       kill victim back to the queue
 
@@ -64,13 +74,6 @@ PHASES = ("queue_wait", "prefill", "handoff", "decode", "sync_stall", "other")
 # Tolerance on the completeness invariant: named phase walls may exceed
 # e2e by at most this much (float accumulation across many quanta).
 SUM_TOL_S = 1e-3
-
-
-def request_trace_id(rid: int, trace: int = -1) -> int:
-    """Effective trace id of a request: an explicit `trace` field wins,
-    else the rid — requeued attempts reuse the SAME Request object, so
-    both attempts land under one id either way."""
-    return trace if trace >= 0 else rid
 
 
 def _ev_time(ev: dict) -> float:
@@ -307,6 +310,12 @@ def flush_to_logger(tracer: TraceRecorder, logger, trees=()) -> None:
 # ---- Chrome-trace / Perfetto export --------------------------------------
 
 
+# what a quantum's dispatch bar shows when clicked: who rode it, what the
+# host did before it, and the slot counters
+_QUANTUM_ARGS = ("lanes", "host", "decoding", "prefilling", "pending",
+                 "free_pages", "delivered", "finished")
+
+
 def to_chrome(events: list[dict]) -> dict:
     """Export events as Chrome-trace JSON (chrome://tracing / Perfetto
     `traceEvents` array, microsecond timestamps). Layout: one pid per
@@ -332,7 +341,7 @@ def to_chrome(events: list[dict]) -> dict:
                 "name": f"dispatch x{ev.get('steps', 1)}", "ph": "X",
                 "cat": "quantum", "pid": pid, "tid": 0,
                 "ts": us(ev["t0"]), "dur": max(us(ev["t1"] - ev["t0"]), 1),
-                "args": {"lanes": ev.get("lanes", [])},
+                "args": {k: ev[k] for k in _QUANTUM_ARGS if k in ev},
             })
             if "s1" in ev:
                 out.append({

@@ -16,6 +16,10 @@ is a first-order cost worth metering). Three captures:
     traffic REPORTED from the compiled module instead of estimated from
     first principles. Each strategy declares which kinds it expects
     (`Strategy.comm_ops`), so a report can flag surprises.
+  - `kernel_calls(hlo_text)` / `instruction_scopes(hlo_text)`: which Pallas
+    kernels a module holds, and which of the program's named scopes each
+    instruction belongs to — the join from a trace's op events to the
+    program's own names.
   - `live_memory_stats()`: `device.memory_stats()` gauges (bytes in use,
     peak, limit) for the per-window HBM watermark line. Returns None on
     backends without the API (CPU).
@@ -114,6 +118,56 @@ def kernel_calls(hlo_text: str) -> dict[str, int]:
         # autodiff and scan wrap the name: jvp_flash_fwd_, transpose_jvp_...
         name = re.sub(r"^(?:transpose_|jvp_)+", "", name).rstrip("_")
         out[name] = out.get(name, 0) + 1
+    return out
+
+
+# The program's device-side names (`jax.named_scope`), one spelling
+# everywhere: the model's parts (tpukit/model/gpt.py), the train step's two
+# halves (train.make_step_fns) and the serve programs' (serve/decode.py,
+# serve/paged.py).
+SCOPES = (
+    "embed", "attn", "ffn", "moe", "ln", "head", "loss", "optimizer",
+    "kv_gather", "kv_write", "attend", "sample", "prefill", "decode",
+)
+
+_INSTRUCTION_OP_NAME = re.compile(
+    r'^\s*(?:ROOT )?%([\w.\-]+) = [^\n]*?metadata=\{[^}\n]*?op_name="([^"]*)"',
+    re.MULTILINE,
+)
+
+
+def instruction_scopes(hlo_text: str, scopes=SCOPES) -> dict[str, str]:
+    """{instruction name: scope path} for a compiled module's text, e.g.
+    `{"fusion.12": "loss/attn", "flash_bwd.1": "loss/attn"}`. A profiler
+    trace's op events carry the instruction (their name is its HLO text) and
+    no scope, so this is the join from device time to the program's names.
+    The path keeps the components of the instruction's `op_name` that are in
+    `scopes`, outermost first, with autodiff/vmap wrappers (`jvp(attn)`,
+    `transpose(jvp(attn))`) read through and a wrapper that restates the
+    enclosing scope (`loss/transpose(loss)/...`) counted once; `jit(...)`
+    components name functions, the last component the primitive. Instructions under no scope
+    are left out. A fusion carries the metadata XLA kept for it (its root's)."""
+    out: dict[str, str] = {}
+    for name, op_name in _INSTRUCTION_OP_NAME.findall(hlo_text):
+        path = []
+        # XLA joins the op_names of instructions it merged with ";": the
+        # first is the one the others were folded into
+        parts = op_name.split(";")[0].split("/")
+        for part in parts:
+            if part.startswith(("jit(", "pjit(")):
+                if part == parts[0]:
+                    path = []  # a branch or loop body traced apart repeats the stack from its root
+                continue
+            scope = re.sub(r"^(?:\w+\()+", "", part).rstrip(")")
+            if scope not in scopes:
+                continue
+            # autodiff restates the scope it was taken under (`loss/
+            # transpose(loss)/jvp(attn)`): a wrapper naming the scope we are
+            # already in adds no level
+            if not (scope != part and path and path[-1] == scope):
+                path.append(scope)
+        if path:
+            out[name] = "/".join(path)
     return out
 
 

@@ -58,8 +58,14 @@ class HostPrefetcher:
         depth: int = 2,
         name: str = "tpukit-prefetch",
         skip: int = 0,
+        span: Callable[[str], Any] | None = None,
     ):
-        """`skip` drops the first N raw items BEFORE `process` runs (round
+        """`span(name)` (the trainer hands over `SpanTimeline.annotate`)
+        opens a context manager around each `process(raw)`, so the worker's
+        production shows as `prefetch.produce` on its own thread's line of a
+        profiler trace; None names nothing.
+
+        `skip` drops the first N raw items BEFORE `process` runs (round
         9: the mid-epoch resume fast-forward) — the skipped batches never
         pay host prep or H2D placement, and the drop happens on the worker
         thread, overlapping the restore/compile the training thread is
@@ -70,6 +76,7 @@ class HostPrefetcher:
             raise ValueError(f"prefetch skip must be >= 0, got {skip}")
         self.depth = depth
         self._skip = skip
+        self._span = span if span is not None else (lambda name: contextlib.nullcontext())
         self._queue: queue.Queue = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self._host_lock = threading.Lock()
@@ -116,7 +123,7 @@ class HostPrefetcher:
                     # in device_put, and a training-thread placement (a
                     # rollback's checkpoint restore) racing it can corrupt
                     # the runtime — two threads must never place at once
-                    with self._host_lock:
+                    with self._host_lock, self._span("prefetch.produce"):
                         item = process(raw)
                 if not self._put((_ITEM, item)):
                     return

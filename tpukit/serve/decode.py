@@ -72,6 +72,7 @@ from jax.sharding import PartitionSpec as P
 from tpukit.model import gpt
 
 
+@jax.named_scope("sample")
 def _select_next(last, cursors, keys, temperature: float, top_k: int):
     """Next token per slot from f32 logits `last [N, V]`: exactly
     `sampling._sample_next` — THE one sampling spelling every decode
@@ -88,6 +89,7 @@ def _select_next(last, cursors, keys, temperature: float, top_k: int):
     return jnp.argmax(last, axis=-1)
 
 
+@jax.named_scope("decode")
 def _advance(params, cfg, buf, cache, cursors, active, limits, keys,
              eos_id: int, temperature: float, top_k: int, mesh=None):
     """One decode tick for every slot (shared by `decode_step` and
@@ -192,6 +194,7 @@ def decode_step(params, cfg: gpt.GPTConfig, buf, cache, cursors, active,
     jax.jit,
     static_argnames=("cfg",),
 )
+@jax.named_scope("prefill")
 def prefill_slots(params, cfg: gpt.GPTConfig, buf, cache, cursors, active,
                   limits, keys, slots, rows, prompt_lens, new_limits, new_keys):
     """Admit `A` requests in ONE dispatch: write their bucket-padded
@@ -236,6 +239,7 @@ def prefill_slots(params, cfg: gpt.GPTConfig, buf, cache, cursors, active,
 
 # No donation — see the decode_step note.
 @partial(jax.jit, static_argnames=("cfg",))
+@jax.named_scope("prefill")
 def prefill_chunk_paged(params, cfg: gpt.GPTConfig, buf, cache, cursors,
                         active, limits, keys, slots, rows, starts, is_last,
                         prompt_lens, new_limits, new_keys):

@@ -41,6 +41,8 @@ The decode step's per-step collectives then match the closed form
 from __future__ import annotations
 
 import dataclasses
+import logging
+import statistics
 import time
 from collections import deque
 
@@ -55,6 +57,14 @@ from tpukit.obs import SpanTimeline
 from tpukit.obs import metrics as metrics_lib
 from tpukit.obs import trace as trace_lib
 from tpukit.serve import decode as serve_decode
+
+log = logging.getLogger(__name__)
+
+# A quantum whose period (sync return to sync return) exceeds this many times
+# the median of the last SLOW_QUANTUM_HISTORY periods is logged, traced or not.
+SLOW_QUANTUM_FACTOR = 10.0
+SLOW_QUANTUM_HISTORY = 64
+SLOW_QUANTUM_MIN_HISTORY = 8  # no verdict from the first few (compiles) alone
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,7 +93,9 @@ class Request:
 
 
 def trace_id(req: Request) -> int:
-    """Effective trace id (trace_lib.request_trace_id over a Request)."""
+    """Effective trace id: an explicit `trace` field wins, else the rid —
+    requeued attempts reuse the SAME Request object, so both attempts land
+    under one id either way."""
     return req.trace if req.trace >= 0 else req.rid
 
 
@@ -94,7 +106,15 @@ class Completion:
     (round 15) are 0/absent under the ring cache: `pages` is the request's
     page footprint, `prefix_pages` how many of them were shared-prefix
     hits, and `active_s` when its prefill finished and decode began
-    (== `admit_s` for the ring's one-shot prefill)."""
+    (== `admit_s` for the ring's one-shot prefill).
+
+    `active_s` and `done_s` are stamped when the host DISPATCHED the last
+    prefill chunk and ENTERED the last sync. What a client can see is in
+    `deliveries`: one `(t, n)` per host sync at which this request's cursor
+    advanced — `n` tokens reached the host at run-clock `t`, stamped after
+    the fetch returned. A latency metric reads `first_token_s - arrival_s`
+    for TTFT and the gaps between deliveries for the inter-token tail, at
+    a quantum's grain: that is what a client of this engine sees."""
 
     rid: int
     ids: np.ndarray
@@ -107,6 +127,17 @@ class Completion:
     pages: int = 0
     prefix_pages: int = 0
     active_s: float = 0.0
+    deliveries: tuple[tuple[float, int], ...] = ()
+
+    @property
+    def first_token_s(self) -> float | None:
+        """When a streaming client could first see a token (None: none
+        was generated)."""
+        return self.deliveries[0][0] if self.deliveries else None
+
+    @property
+    def last_token_s(self) -> float | None:
+        return self.deliveries[-1][0] if self.deliveries else None
 
     @property
     def admit_latency_s(self) -> float:
@@ -375,6 +406,11 @@ class _Lane:
     # per-request PRNG key bytes, computed ONCE at admission — chunk
     # dispatches must not pay a device round-trip per lane per iteration
     key: np.ndarray | None = None
+    # (run-clock time, tokens) of every sync at which this lane's cursor
+    # advanced, and their running total (== cursor - prompt_len as of the
+    # last sync)
+    deliveries: list = dataclasses.field(default_factory=list)
+    delivered: int = 0
 
 
 def _pct(vals, q) -> float | None:
@@ -479,7 +515,14 @@ class ServeEngine:
         self.metrics_dir = metrics_dir
         self._metrics_traces_seen: set = set()
         self._metrics_q_mark = -1.0  # quantum watermark (t1 run-clock)
-        self._pending_quantum = None  # dispatch half of the quantum event
+        # the quantum in flight: its dispatch half (`_open_quantum`), closed
+        # and emitted by the sync that fetches it
+        self._quantum: dict | None = None
+        self._quanta = 0  # quanta synced so far: the slow-quantum line's index
+        # run-clock time the last sync returned (0 = the run's start): where
+        # the next quantum's `host` account and period begin
+        self._host_from = 0.0
+        self._periods: deque[float] = deque(maxlen=SLOW_QUANTUM_HISTORY)
         # fused windows (round 21): the device tick counter of the last
         # decode_loop_window dispatch, fetched at the window-boundary sync
         # (the loop may exit early, so the host can't assume the quantum)
@@ -595,7 +638,10 @@ class ServeEngine:
         self._lanes: dict[int, _Lane] = {}
         self._pending: deque[Request] = deque()
         self.completions: list[Completion] = []
-        self.spans = SpanTimeline()
+        # THE host-span primitive (tpukit/obs/spans.py): phase sums, the
+        # profiler's host line (`tpukit:<name>`) and the run-clock stamps the
+        # tracer's events are built from, all from one `with`
+        self.spans = SpanTimeline(annotation=jax.profiler.TraceAnnotation)
         self.buckets_used: set[int] = set()
         self.steps = 0
         self.admitted = 0
@@ -640,33 +686,36 @@ class ServeEngine:
         # Validate EVERY request before popping any slot: a mid-batch raise
         # after partial pops would leak lanes out of the free list and drop
         # the already-popped requests from both queues.
-        validated = []
-        for req in reqs:
-            prompt_len = len(req.ids)
-            if prompt_len < 1:
-                raise ValueError(f"request {req.rid}: empty prompt")
-            validated.append((req, prompt_len, self.bucket_for(prompt_len)))
-        groups: dict[int, list[tuple[int, Request, int]]] = {}
-        for req, prompt_len, bucket in validated:
-            groups.setdefault(bucket, []).append(
-                (self._free.popleft(), req, prompt_len)
-            )
+        with self.spans.span("admit"):
+            validated = []
+            for req in reqs:
+                prompt_len = len(req.ids)
+                if prompt_len < 1:
+                    raise ValueError(f"request {req.rid}: empty prompt")
+                validated.append((req, prompt_len, self.bucket_for(prompt_len)))
+            groups: dict[int, list[tuple[int, Request, int]]] = {}
+            for req, prompt_len, bucket in validated:
+                groups.setdefault(bucket, []).append(
+                    (self._free.popleft(), req, prompt_len)
+                )
         tr = self.tracer
         for bucket, entries in sorted(groups.items()):
-            a = 1 << (len(entries) - 1).bit_length()  # pad to power of two
-            rows = np.zeros((a, bucket), np.int32)
-            slots = np.zeros((a,), np.int32)
-            plens = np.zeros((a,), np.int32)
-            lims = np.zeros((a,), np.int32)
-            keys = np.zeros((a, 2), np.uint32)
-            for i in range(a):
-                slot, req, plen = entries[min(i, len(entries) - 1)]
-                rows[i, :plen] = req.ids
-                slots[i], plens[i] = slot, plen
-                lims[i] = min(plen + req.max_new_tokens, self.serve.width)
-                keys[i] = np.asarray(jax.random.PRNGKey(req.seed), np.uint32)
-            p0 = tr.now() if tr is not None else 0.0
-            with self.spans.span("prefill"):
+            # the ring's admission dispatches its prefill: the two spans run
+            # in turn, never nested, so `prefill` keeps the dispatch wall
+            with self.spans.span("admit"):
+                a = 1 << (len(entries) - 1).bit_length()  # pad to power of two
+                rows = np.zeros((a, bucket), np.int32)
+                slots = np.zeros((a,), np.int32)
+                plens = np.zeros((a,), np.int32)
+                lims = np.zeros((a,), np.int32)
+                keys = np.zeros((a, 2), np.uint32)
+                for i in range(a):
+                    slot, req, plen = entries[min(i, len(entries) - 1)]
+                    rows[i, :plen] = req.ids
+                    slots[i], plens[i] = slot, plen
+                    lims[i] = min(plen + req.max_new_tokens, self.serve.width)
+                    keys[i] = np.asarray(jax.random.PRNGKey(req.seed), np.uint32)
+            with self.spans.span("prefill") as sp:
                 (self.buf, self.cache, self.cursors, self.active, self.limits,
                  self.keys) = serve_decode.prefill_slots(
                     self.params, self.cfg, self.buf, self.cache, self.cursors,
@@ -689,7 +738,6 @@ class ServeEngine:
                         self._place(keys, P()),
                     )
             self.buckets_used.add(bucket)
-            p1 = tr.now() if tr is not None else 0.0
             for slot, req, plen in entries:
                 self._lanes[slot] = _Lane(req, now, plen, bucket, active_s=now)
                 self.admitted += 1
@@ -697,9 +745,9 @@ class ServeEngine:
                     tid = trace_id(req)
                     tr.emit("admit", tid, rid=req.rid, t=now, slot=slot,
                             replica=self.replica)
-                    tr.emit("prefill", tid, rid=req.rid, t0=p0, t1=p1,
+                    tr.emit("prefill", tid, rid=req.rid, t0=sp.t0, t1=sp.t1,
                             chunk=0, replica=self.replica)
-                    tr.emit("prefill_done", tid, rid=req.rid, t=p1,
+                    tr.emit("prefill_done", tid, rid=req.rid, t=sp.t1,
                             replica=self.replica)
         self.max_live = max(self.max_live, len(self._lanes))
 
@@ -775,37 +823,41 @@ class ServeEngine:
         their last chunk arm decode state on-device and are registered
         into the prefix registry here (host metadata; device ordering
         guarantees the chunk's writes land before any later read)."""
-        entries = []
-        c = self.serve.chunk
-        for slot, lane in self._lanes.items():
-            if lane.phase != "prefill":
-                continue
-            start = lane.next_chunk
-            seg = lane.req.ids[start : start + c]
-            row = np.zeros((c,), np.int32)
-            row[: len(seg)] = seg
-            entries.append((slot, lane, start, row, start + c >= lane.prefill_end))
-        if not entries:
+        prefilling = [(slot, lane) for slot, lane in self._lanes.items()
+                      if lane.phase == "prefill"]
+        if not prefilling:
             return
-        a = 1 << (len(entries) - 1).bit_length()  # pad to power of two
-        rows = np.zeros((a, c), np.int32)
-        slots = np.zeros((a,), np.int32)
-        starts = np.zeros((a,), np.int32)
-        last = np.zeros((a,), bool)
-        plens = np.zeros((a,), np.int32)
-        lims = np.zeros((a,), np.int32)
-        keys = np.zeros((a, 2), np.uint32)
-        for i in range(a):  # repeats are idempotent (round-14 admit trick)
-            slot, lane, start, row, is_last = entries[min(i, len(entries) - 1)]
-            rows[i], slots[i], starts[i], last[i] = row, slot, start, is_last
-            plens[i] = lane.prompt_len
-            lims[i] = min(lane.prompt_len + lane.req.max_new_tokens,
-                          self.serve.width)
-            keys[i] = lane.key
+        c = self.serve.chunk
+        # two `prefill` spans with `place` between them (spans never nest
+        # here: nested time would go to the outer one): the first is the
+        # host assembling the chunk batch, the second the dispatch whose
+        # wall the tracer's `prefill` events carry
+        with self.spans.span("prefill"):
+            entries = []
+            for slot, lane in prefilling:
+                start = lane.next_chunk
+                seg = lane.req.ids[start : start + c]
+                row = np.zeros((c,), np.int32)
+                row[: len(seg)] = seg
+                entries.append((slot, lane, start, row, start + c >= lane.prefill_end))
+            a = 1 << (len(entries) - 1).bit_length()  # pad to power of two
+            rows = np.zeros((a, c), np.int32)
+            slots = np.zeros((a,), np.int32)
+            starts = np.zeros((a,), np.int32)
+            last = np.zeros((a,), bool)
+            plens = np.zeros((a,), np.int32)
+            lims = np.zeros((a,), np.int32)
+            keys = np.zeros((a, 2), np.uint32)
+            for i in range(a):  # repeats are idempotent (round-14 admit trick)
+                slot, lane, start, row, is_last = entries[min(i, len(entries) - 1)]
+                rows[i], slots[i], starts[i], last[i] = row, slot, start, is_last
+                plens[i] = lane.prompt_len
+                lims[i] = min(lane.prompt_len + lane.req.max_new_tokens,
+                              self.serve.width)
+                keys[i] = lane.key
         self._refresh_bt()
         tr = self.tracer
-        p0 = tr.now() if tr is not None else 0.0
-        with self.spans.span("prefill"):
+        with self.spans.span("prefill") as sp:
             (self.buf, self.cache, self.cursors, self.active, self.limits,
              self.keys) = serve_decode.prefill_chunk_paged(
                 self.params, self.cfg, self.buf, self.cache, self.cursors,
@@ -815,15 +867,14 @@ class ServeEngine:
                 self._place(plens, P()), self._place(lims, P()),
                 self._place(keys, P()),
             )
-        p1 = tr.now() if tr is not None else 0.0
         for slot, lane, start, row, is_last in entries:
             lane.next_chunk = start + c
             if tr is not None:
                 tid = trace_id(lane.req)
-                tr.emit("prefill", tid, rid=lane.req.rid, t0=p0, t1=p1,
+                tr.emit("prefill", tid, rid=lane.req.rid, t0=sp.t0, t1=sp.t1,
                         chunk=start // c, replica=self.replica)
                 if is_last:
-                    tr.emit("prefill_done", tid, rid=lane.req.rid, t=p1,
+                    tr.emit("prefill_done", tid, rid=lane.req.rid, t=sp.t1,
                             replica=self.replica)
             if is_last:
                 lane.phase = "decode"
@@ -836,14 +887,30 @@ class ServeEngine:
         read. Tables change only at admission/eviction; between those the
         cached device array rides along unchanged through every jit."""
         if self._bt_dirty:
-            self.cache["bt"] = self._place(self._bt, P())
+            with self.spans.span("place"):
+                self.cache["bt"] = self._place(self._bt, P())
             self._bt_dirty = False
+
+    def _open_quantum(self, t0: float, t1: float, steps: int) -> None:
+        """The dispatch half of the quantum record: `[t0, t1]` is the async
+        dispatch wall, and the counters are read here, where the work
+        happens. `sync()` adds the wall-to-sync half and emits ONE ring
+        record per quantum, not per lane — the ring stays O(quanta)."""
+        decoding = [l for _, l in sorted(self._lanes.items())
+                    if l.phase == "decode"]
+        self._quantum = dict(
+            t0=t0, t1=t1, steps=steps,
+            lanes=[trace_id(l.req) for l in decoding],
+            decoding=len(decoding),
+            prefilling=len(self._lanes) - len(decoding),
+            pending=len(self._pending),
+            free_pages=(self.allocator.available_pages
+                        if self.serve.paged else None),
+        )
 
     def _step(self) -> None:
         if self.serve.paged:
             self._refresh_bt()
-        tr = self.tracer
-        t0 = tr.now() if tr is not None else 0.0
         if self.serve.fused_decode:
             # round 21: the whole quantum runs as ONE on-device
             # while_loop dispatch (decode.decode_loop_window) — cursors,
@@ -867,7 +934,7 @@ class ServeEngine:
                               self.serve.width) // self.serve.page_size)
             else:
                 need = 1 << 30
-            with self.spans.span("decode"):
+            with self.spans.span("decode") as sp:
                 (self.buf, self.cache, self.cursors, self.active, ticks,
                  _) = serve_decode.decode_loop_window(
                     self.params, self.cfg, self.buf, self.cache,
@@ -880,31 +947,17 @@ class ServeEngine:
                     self._top_k, self.mesh,
                 )
             self._pending_ticks = ticks
-            if tr is not None:
-                # steps is filled at sync, once the device count lands
-                self._pending_quantum = dict(
-                    t0=t0, t1=tr.now(), steps=0,
-                    lanes=[trace_id(l.req)
-                           for s, l in sorted(self._lanes.items())
-                           if l.phase == "decode"],
-                )
+            # steps is filled at sync, once the device count lands
+            self._open_quantum(sp.t0, sp.t1, steps=0)
             return
-        with self.spans.span("decode"):
+        with self.spans.span("decode") as sp:
             self.buf, self.cache, self.cursors, self.active = serve_decode.decode_step(
                 self.params, self.cfg, self.buf, self.cache, self.cursors,
                 self.active, self.limits, self.keys, self.eos_id,
                 float(self.serve.temperature), self._top_k, self.mesh,
                 steps=self.serve.decode_quantum,
             )
-        if tr is not None:
-            # dispatch half of the quantum event; `sync()` adds the
-            # wall-to-sync half and emits (one ring record per quantum,
-            # not per lane — the ring stays O(quanta))
-            self._pending_quantum = dict(
-                t0=t0, t1=tr.now(), steps=self.serve.decode_quantum,
-                lanes=[trace_id(l.req) for s, l in sorted(self._lanes.items())
-                       if l.phase == "decode"],
-            )
+        self._open_quantum(sp.t0, sp.t1, steps=self.serve.decode_quantum)
         self.steps += self.serve.decode_quantum
         self._win["steps"] += self.serve.decode_quantum
 
@@ -926,10 +979,8 @@ class ServeEngine:
         for s, lane in self._lanes.items():
             if lane.phase == "decode":
                 live[s] = True
-        tr = self.tracer
-        t0 = tr.now() if tr is not None else 0.0
         if self.serve.draft == "model":
-            with self.spans.span("draft"):
+            with self.spans.span("draft") as sp:
                 draft_toks, draft_q, self.draft_cache = spec_lib.draft_propose(
                     self.draft_params, self.draft_cfg, self.buf,
                     self.draft_cache, self.cursors, self.keys,
@@ -940,7 +991,8 @@ class ServeEngine:
                 draft_len = self._place(
                     np.full((n,), k, np.int32), self._slot_spec
                 )
-            with self.spans.span("verify"):
+            t0 = sp.t0  # the quantum's dispatch wall spans draft and verify
+            with self.spans.span("verify") as sp:
                 (self.buf, self.cache, self.cursors, self.active, acc,
                  napp) = spec_lib.verify_step(
                     self.params, self.cfg, self.buf, self.cache,
@@ -955,7 +1007,7 @@ class ServeEngine:
             # one sync per quantum, the vanilla step's host rhythm; a
             # host-side proposer would pay buf D2H + draft H2D + a
             # second dispatch every quantum
-            with self.spans.span("verify"):
+            with self.spans.span("verify") as sp:
                 (self.buf, self.cache, self.cursors, self.active, acc,
                  napp, dlen) = spec_lib.spec_ngram_step(
                     self.params, self.cfg, self.buf, self.cache,
@@ -964,13 +1016,9 @@ class ServeEngine:
                     self._top_k, k=k, max_ngram=self.serve.ngram_max,
                     mesh=self.mesh,
                 )
+            t0 = sp.t0
         self._pending_spec = (live, dlen, acc, napp)
-        if tr is not None:
-            self._pending_quantum = dict(
-                t0=t0, t1=tr.now(), steps=1,
-                lanes=[trace_id(l.req) for s, l in sorted(self._lanes.items())
-                       if l.phase == "decode"],
-            )
+        self._open_quantum(t0, sp.t1, steps=1)
         self.steps += 1
         self._win["steps"] += 1
 
@@ -989,48 +1037,52 @@ class ServeEngine:
             self.spec_accepted += int(min(acc[s], dlen[s]))
             self.spec_hist[int(napp[s])] += 1
 
-    def _sync_evict(self, now: float) -> None:
-        """The per-step host sync: fetch cursors + active flags, retire
-        lanes that finished, and account generated tokens. One small D2H
-        per step — the price of host-side EOS detection."""
+    def _fetch_cursors(self):
+        """The per-quantum D2H: cursors + active flags, with whatever device
+        counters the last dispatch left pending coalesced into the same
+        round trip. One small fetch per quantum — the price of host-side EOS
+        detection. Returns host `(cursors, active)`."""
+        if self._pending_spec is not None:
+            # dlen is a device array on the fused ngram path, host numpy on
+            # the model path — device_get passes the latter through untouched
+            live, dlen, acc, napp = self._pending_spec
+            cur, act, dlen, acc, napp = map(np.asarray, jax.device_get(
+                (self.cursors, self.active, dlen, acc, napp)))
+            self._pending_spec = (live, dlen, acc, napp)
+        elif self._pending_ticks is not None:
+            # fused window (round 21): the actual tick count rides the
+            # same D2H round-trip as the cursors — the loop may have
+            # exited early, so steps are accounted HERE, from the
+            # device's answer, never assumed from the quantum
+            cur, act, ticks = map(np.asarray, jax.device_get(
+                (self.cursors, self.active, self._pending_ticks)))
+            self._pending_ticks = None
+            ran = int(ticks)
+            self.steps += ran
+            self._win["steps"] += ran
+            if self._quantum is not None:
+                self._quantum["steps"] = ran
+        else:
+            cur, act = map(np.asarray,
+                           jax.device_get((self.cursors, self.active)))
+        self._drain_spec()
+        return cur, act
+
+    def _retire(self, now: float, cur, act, fetched_s: float) -> tuple[int, int]:
+        """After the fetch returned (run clock `fetched_s`): stamp the tokens
+        each decoding lane's cursor advanced by as delivered, retire the
+        lanes that finished, and account generated tokens. Returns this
+        sync's `(delivered tokens, finished lanes)`."""
         tr = self.tracer
-        s0 = tr.now() if tr is not None else 0.0
-        with self.spans.span("sync"):
-            if self._pending_spec is not None:
-                # coalesce the spec counters into the same D2H round trip
-                # (dlen is a device array on the fused ngram path, host
-                # numpy on the model path — device_get passes the latter
-                # through untouched)
-                live, dlen, acc, napp = self._pending_spec
-                cur, act, dlen, acc, napp = map(np.asarray, jax.device_get(
-                    (self.cursors, self.active, dlen, acc, napp)))
-                self._pending_spec = (live, dlen, acc, napp)
-            elif self._pending_ticks is not None:
-                # fused window (round 21): the actual tick count rides
-                # the same D2H round-trip as the cursors — the loop may
-                # have exited early, so steps are accounted HERE, from
-                # the device's answer, never assumed from the quantum
-                cur, act, ticks = map(np.asarray, jax.device_get(
-                    (self.cursors, self.active, self._pending_ticks)))
-                self._pending_ticks = None
-                ran = int(ticks)
-                self.steps += ran
-                self._win["steps"] += ran
-                if self._pending_quantum is not None:
-                    self._pending_quantum["steps"] = ran
-            else:
-                cur, act = map(np.asarray,
-                               jax.device_get((self.cursors, self.active)))
-            self._drain_spec()
-        if tr is not None and self._pending_quantum is not None:
-            # complete the dispatch+sync pair started in _step/_spec_step:
-            # [t0,t1] is the async-dispatch wall, [s0,s1] the wall-to-sync
-            # (device) wall — the per-quantum attribution ROADMAP #3 wants
-            q = self._pending_quantum
-            self._pending_quantum = None
-            tr.emit("quantum", -1, t0=q["t0"], t1=q["t1"], s0=s0,
-                    s1=tr.now(), steps=q["steps"], lanes=q["lanes"],
-                    replica=self.replica)
+        delivered = 0
+        for s, lane in self._lanes.items():
+            if lane.phase != "decode":
+                continue
+            n = int(cur[s]) - lane.prompt_len - lane.delivered
+            if n > 0:
+                lane.deliveries.append((fetched_s, n))
+                lane.delivered += n
+                delivered += n
         # prefilling paged lanes are act=False by design, not finished;
         # stuck_request-pinned lanes (chaos, round 24) are REFUSED
         # retirement — they hold their slot until deadline eviction
@@ -1040,13 +1092,11 @@ class ServeEngine:
             and lane.req.rid not in self.stuck_rids
         ]
         gen_live = sum(
-            int(cur[s]) - lane.prompt_len
-            for s, lane in self._lanes.items()
+            lane.delivered for s, lane in self._lanes.items()
             if lane.phase == "decode" and s not in finished
         )
         if finished:
             host_buf = np.asarray(jax.device_get(self.buf))
-            fin_t = tr.now() if tr is not None else 0.0
             for s in finished:
                 lane = self._lanes.pop(s)
                 length = int(cur[s])
@@ -1066,59 +1116,65 @@ class ServeEngine:
                                      self.serve.width)
                     else "eos"
                 )
-                self.evicted[reason] += 1
-                self.completions.append(Completion(
-                    rid=lane.req.rid, ids=ids,
-                    prompt_len=lane.prompt_len, generated=generated,
-                    reason=reason, arrival_s=lane.req.arrival_s,
-                    admit_s=lane.admit_s, done_s=now,
-                    pages=len(lane.pages), prefix_pages=lane.shared,
-                    active_s=lane.active_s or lane.admit_s,
-                ))
-                if tr is not None:
-                    # finish is stamped POST-sync (fin_t > done_s=now,
-                    # which was captured pre-sync): the last quantum's
-                    # sync wall belongs inside the tree's lifetime, so
-                    # the phase walls can sum to the tree's e2e
-                    tr.emit("finish", trace_id(lane.req), rid=lane.req.rid,
-                            t=fin_t, reason=reason, generated=generated,
-                            replica=self.replica)
-                if self.serve.paged:
-                    # drop this lane's references: private pages free (or
-                    # retire into the prefix LRU if registered), shared
-                    # pages survive for their other readers — and zero the
-                    # block-table row so any stale in-flight write lands
-                    # in the null page, never in a re-issued one
-                    self.allocator.release(lane.pages)
-                    self._bt[s] = 0
-                    self._bt_dirty = True
-                self._free.append(s)
+                self._complete(s, lane, ids, generated, reason, now, fetched_s)
         self._gen_total = sum(c.generated for c in self.completions) + gen_live
+        return delivered, len(finished)
 
-    def _evict_deadlines(self, now: float) -> None:
+    def _complete(self, slot: int, lane: _Lane, ids, generated: int,
+                  reason: str, now: float, fetched_s: float) -> None:
+        """Turn a popped lane into its Completion and hand back what it held
+        (natural retirement and deadline eviction alike)."""
+        self.evicted[reason] += 1
+        self.completions.append(Completion(
+            rid=lane.req.rid, ids=ids,
+            prompt_len=lane.prompt_len, generated=generated,
+            reason=reason, arrival_s=lane.req.arrival_s,
+            admit_s=lane.admit_s, done_s=now,
+            pages=len(lane.pages), prefix_pages=lane.shared,
+            active_s=lane.active_s or lane.admit_s,
+            deliveries=tuple(lane.deliveries),
+        ))
+        if self.tracer is not None:
+            # finish is stamped when the fetch RETURNED (> done_s=now,
+            # captured before the sync): the last quantum's sync wall
+            # belongs inside the tree's lifetime, so the phase walls can
+            # sum to the tree's e2e
+            self.tracer.emit("finish", trace_id(lane.req), rid=lane.req.rid,
+                             t=fetched_s, reason=reason, generated=generated,
+                             replica=self.replica)
+        if self.serve.paged:
+            # drop this lane's references: private pages free (or retire
+            # into the prefix LRU if registered), shared pages survive for
+            # their other readers — and zero the block-table row so any
+            # stale in-flight write lands in the null page, never in a
+            # re-issued one
+            self.allocator.release(lane.pages)
+            self._bt[slot] = 0
+            self._bt_dirty = True
+        self._free.append(slot)
+
+    def _evict_deadlines(self, now: float, fetched_s: float) -> int:
         """Retire decode-resident lanes whose end-to-end deadline_ms has
         expired (round 24): the partial output becomes a Completion with
         reason=\"deadline\" plus a `kind=\"deadline_miss\"` JSONL record,
         and the paged engine parks the lane's pages cheaply (release →
         registered lead pages retire into the prefix LRU, private pages
         free, block-table row zeroed — the same write-safety spelling as
-        natural retirement). Runs AFTER _sync_evict, so the quantum is
+        natural retirement). Runs AFTER `_retire`, so the quantum is
         already synced and the extra cursor/buffer fetch happens only on
         the rare eviction path. Prefill-phase lanes wait for their decode
         transition (one chunk of grace) so an in-flight chunk never
-        targets released pages."""
+        targets released pages. Returns the number of lanes evicted."""
         over = [
             (s, lane) for s, lane in self._lanes.items()
             if lane.phase == "decode" and lane.req.deadline_ms > 0
             and (now - lane.req.arrival_s) * 1e3 > lane.req.deadline_ms
         ]
         if not over:
-            return
+            return 0
         cur, host_buf = map(
             np.asarray, jax.device_get((self.cursors, self.buf))
         )
-        tr = self.tracer
-        fin_t = tr.now() if tr is not None else 0.0
         for s, lane in over:
             self._lanes.pop(s)
             length = int(cur[s])
@@ -1126,16 +1182,7 @@ class ServeEngine:
             ids = host_buf[s, :length].copy()
             if self.serve.paged:
                 ids[: lane.prompt_len] = lane.req.ids
-            self.evicted["deadline"] += 1
             over_ms = (now - lane.req.arrival_s) * 1e3 - lane.req.deadline_ms
-            self.completions.append(Completion(
-                rid=lane.req.rid, ids=ids,
-                prompt_len=lane.prompt_len, generated=generated,
-                reason="deadline", arrival_s=lane.req.arrival_s,
-                admit_s=lane.admit_s, done_s=now,
-                pages=len(lane.pages), prefix_pages=lane.shared,
-                active_s=lane.active_s or lane.admit_s,
-            ))
             if self.logger is not None:
                 rec = dict(
                     kind="deadline_miss", rid=lane.req.rid,
@@ -1147,18 +1194,47 @@ class ServeEngine:
                 self.logger.log(**rec)
             if self.metrics is not None:
                 self.metrics.inc("serve_deadline_miss")
-            if tr is not None:
-                tr.emit("finish", trace_id(lane.req), rid=lane.req.rid,
-                        t=fin_t, reason="deadline", generated=generated,
-                        replica=self.replica)
-            if self.serve.paged:
-                self.allocator.release(lane.pages)
-                self._bt[s] = 0
-                self._bt_dirty = True
-            self._free.append(s)
+            self._complete(s, lane, ids, generated, "deadline", now, fetched_s)
         # _gen_total is untouched: the evicted tokens were already counted
-        # through the last sync's gen_live term, and the next _sync_evict
+        # through the last sync's gen_live term, and the next _retire
         # recomputes from completions + live lanes
+        return len(over)
+
+    def _close_quantum(self, host: dict, s0: float, s1: float,
+                       delivered: int, finished: int) -> None:
+        """Complete the dispatch+sync pair `_open_quantum` started: `[s0, s1]`
+        is the wall-to-sync (device) wall, `host` the walls of the spans that
+        ran between the previous sync's return and `s0`, by name, with
+        `other` so that they sum to that gap — the serial host time no
+        device work hides except an in-flight prefill chunk. Says so on
+        `logging` when the period is far beyond the recent median, traced
+        or not, and emits the quantum event when traced."""
+        q, self._quantum = self._quantum, None
+        since, self._host_from = self._host_from, s1
+        if q is None:  # a sync with nothing dispatched (step primitives driven by hand)
+            return
+        host["other"] = max(s0 - since - sum(host.values()), 0.0)
+        period = s1 - since
+        if len(self._periods) >= SLOW_QUANTUM_MIN_HISTORY:
+            median = statistics.median(self._periods)
+            if period > SLOW_QUANTUM_FACTOR * median:
+                log.warning(
+                    "slow quantum %d%s: period %.1f ms against a median of "
+                    "%.1f ms over the last %d; sync wait %.1f ms, host ms %s, "
+                    "decoding %d, prefilling %d, pending %d",
+                    self._quanta,
+                    "" if self.replica is None else f" (replica {self.replica})",
+                    period * 1e3, median * 1e3, len(self._periods),
+                    (s1 - s0) * 1e3,
+                    {k: round(v * 1e3, 3) for k, v in host.items()},
+                    q["decoding"], q["prefilling"], q["pending"],
+                )
+        self._periods.append(period)
+        self._quanta += 1
+        if self.tracer is not None:
+            self.tracer.emit("quantum", -1, s0=s0, s1=s1, host=host,
+                             delivered=delivered, finished=finished,
+                             replica=self.replica, **q)
 
     # ---- telemetry -------------------------------------------------------
 
@@ -1459,10 +1535,12 @@ class ServeEngine:
                 self._admit_batch(take, now)
             return list(reqs[len(take):])
         left = list(reqs)
-        while left and self._free:
-            if not self._admit_paged_one(left[0], now):
-                break
-            left.pop(0)
+        if left:
+            with self.spans.span("admit"):
+                while left and self._free:
+                    if not self._admit_paged_one(left[0], now):
+                        break
+                    left.pop(0)
         return left
 
     def poll_prefill(self, now: float) -> None:
@@ -1485,13 +1563,21 @@ class ServeEngine:
         return True
 
     def sync(self, now: float) -> None:
-        """The per-quantum host sync: fetch cursors/flags, retire finished
-        lanes, evict deadline-expired ones, and emit a `kind="serve"`
-        window when one is due."""
-        self._sync_evict(now)
-        self._evict_deadlines(now)
+        """The per-quantum host sync: fetch cursors/flags (`sync`, the wait
+        for the device), then deliver tokens, retire finished lanes and
+        evict deadline-expired ones (`retire`), close the quantum's record,
+        and emit a `kind="serve"` window when one is due (`window`)."""
+        host = self.spans.lap()  # what the host did since the last sync returned
+        with self.spans.span("sync") as sp:
+            cur, act = self._fetch_cursors()
+        self.spans.lap()  # the wait is no host work: the next account starts at s1
+        with self.spans.span("retire"):
+            delivered, finished = self._retire(now, cur, act, sp.t1)
+            finished += self._evict_deadlines(now, sp.t1)
+        self._close_quantum(host, sp.t0, sp.t1, delivered, finished)
         if self._win["steps"] >= self.serve.window_steps:
-            self._emit_window()
+            with self.spans.span("window"):
+                self._emit_window()
 
     def finish(self, wall_s: float) -> list[Completion]:
         """Flush the partial window and emit the `kind="serve_summary"`
@@ -1628,6 +1714,18 @@ class ServeEngine:
 
     # ---- the loop --------------------------------------------------------
 
+    def begin_run(self, t0: float, joined_s: float = 0.0) -> None:
+        """Pin the run clock at `t0` (perf_counter) and start every account
+        at run-clock `joined_s` (0: the run's start; later for a replica
+        scaled up mid-run): the timeline was constructed earlier, and the
+        construction->run gap would otherwise leak into the summary's
+        `other_s` residual and the first quantum's `host`. A loop that
+        drives the step primitives itself (`FleetRouter`) calls this with
+        its own t0, so span times compare with the `now` it passes in."""
+        self.spans.set_epoch(t0)
+        self.spans.epoch()
+        self._host_from = joined_s
+
     def run(self, requests, max_wall_s: float | None = None) -> list[Completion]:
         """Serve `requests` (admitted no earlier than their `arrival_s`)
         to completion. Admission fills free slots between decode steps —
@@ -1637,11 +1735,8 @@ class ServeEngine:
         and a final `kind="serve_summary"`; returns the completions in
         finish order."""
         self._pending = deque(sorted(requests, key=lambda r: (r.arrival_s, r.rid)))
-        # reset the span epoch to the RUN start (round 20): the timeline
-        # was constructed earlier, and the construction->run gap would
-        # otherwise leak into the summary's `other_s` residual
-        self.spans.epoch()
         t0 = time.perf_counter()
+        self.begin_run(t0)
         if self.tracer is not None:
             self.tracer.set_epoch(t0)
             for r in self._pending:
@@ -1670,7 +1765,9 @@ class ServeEngine:
                     # nothing decoding and the next arrival is in the future
                     wait = self._pending[0].arrival_s - now
                     if wait > 0:
-                        time.sleep(min(wait, 0.05))
+                        # an open loop's waiting must not read as host work
+                        with self.spans.span("idle"):
+                            time.sleep(min(wait, 0.05))
                 continue
             self.sync(time.perf_counter() - t0)
         return self.finish(time.perf_counter() - t0)

@@ -322,6 +322,7 @@ class FleetRouter:
         # clock and ring set that survives replica kills, so the router
         # owns it and flushes it once at fleet shutdown.
         self.tracer = tracer
+        self._t0: float | None = None  # perf_counter origin of the run clock
         # ONE MetricRegistry shared the same way (round 22): every
         # replica engine observes into it replica-labeled, the router
         # accounts the fleet-level SLOs and owns the snapshot-file
@@ -465,6 +466,8 @@ class FleetRouter:
             metrics=self.metrics,
         )
         eng.stuck_rids = self._chaos.stuck
+        if self._t0 is not None:  # scaled up mid-run: on the fleet's clock too
+            eng.begin_run(self._t0, joined_s=time.perf_counter() - self._t0)
         self._replicas[idx] = eng
         self._metrics_replicas.add(idx)
         self.replicas_peak = max(self.replicas_peak, len(self._replicas))
@@ -1083,13 +1086,13 @@ class FleetRouter:
 
     def _run_loop(self, pending: deque,
                   max_wall_s: float | None) -> list[Completion]:
-        # reset every engine's span epoch to the FLEET run start so the
-        # construction->run gap lands nowhere (the engine.run discipline)
+        # start every engine's clock and accounts at the FLEET run start so
+        # the construction->run gap lands nowhere (the engine.run discipline)
+        t0 = self._t0 = time.perf_counter()
         for eng in self._replicas.values():
-            eng.spans.epoch()
+            eng.begin_run(t0)
         if self.prefill is not None:
-            self.prefill.spans.epoch()
-        t0 = time.perf_counter()
+            self.prefill.begin_run(t0)
         if self.tracer is not None:
             self.tracer.set_epoch(t0)
             for r in pending:

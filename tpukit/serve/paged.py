@@ -152,6 +152,7 @@ def pool_bytes(cfg, num_pages: int, page_size: int, kv_dtype: str) -> int:
 # -- device-side page ops (called per layer from gpt.forward_cached) --------
 
 
+@jax.named_scope("kv_gather")
 def gather_view(pool, scales, bt, out_dtype):
     """Dereference the block tables: `pool [NP, H, P, D]` gathered through
     `bt [N, MP]` into the `[N, H, MP*P, D]` per-row K (or V) view the
@@ -172,6 +173,7 @@ def gather_view(pool, scales, bt, out_dtype):
     return v.astype(out_dtype).transpose(0, 2, 1, 3, 4).reshape(n, h, mp * p, d)
 
 
+@jax.named_scope("kv_write")
 def write_token(pool, scales, bt, start, val, write_mask):
     """Decode-tick write-back: row `b`'s freshly computed K (or V)
     `val [N, H, D]` lands at logical position `start[b]` — page
@@ -209,6 +211,7 @@ def write_token(pool, scales, bt, start, val, write_mask):
     )
 
 
+@jax.named_scope("kv_write")
 def write_pages(pool, scales, bt, start, vals, write_mask):
     """Prefill-chunk write-back: `vals [N, H, C, D]` covers logical
     positions `[start[b], start[b] + C)` per row, with `start` page-aligned

@@ -163,11 +163,13 @@ def make_step_fns(
 
         # autodiff over loss_fn by default; Pipeline1F1B overrides with its
         # explicit per-stage-vjp schedule (see Strategy.value_and_grad)
-        loss, grads = strategy.value_and_grad(
-            state.params, cfg, batch, targets, rng=rng
-        )
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("loss"):
+            loss, grads = strategy.value_and_grad(
+                state.params, cfg, batch, targets, rng=rng
+            )
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         new_state = TrainState(
             params=params, opt_state=opt_state, step=state.step + 1
         )
@@ -177,9 +179,10 @@ def make_step_fns(
 
     def eval_step(state: TrainState, batch, targets):
         state = strategy.to_compute(state)
-        loss, accuracy = strategy.loss_fn(
-            state.params, eval_cfg, batch, targets, with_accuracy=True
-        )
+        with jax.named_scope("loss"):
+            loss, accuracy = strategy.loss_fn(
+                state.params, eval_cfg, batch, targets, with_accuracy=True
+            )
         return loss, accuracy
 
     state_sh = strategy.state_sharding(state_shapes)
@@ -738,7 +741,12 @@ def _fit_body(
     meter = MFUMeter(cfg, seq)
     logger = StepLogger(flags.metrics_log if p0 else "")
     # ---- telemetry (tpukit/obs, round 6) --------------------------------
-    spans = SpanTimeline()
+    # every span below is also a `tpukit:<name>` event on the host thread's
+    # line of a --profile_dir trace, and the `step` span a profiler step
+    spans = SpanTimeline(
+        annotation=jax.profiler.TraceAnnotation,
+        step_annotation=functools.partial(jax.profiler.StepTraceAnnotation, "train"),
+    )
     # Flight recorder (round 8): always on — a bounded ring of recent
     # step/window/sentinel records, read only when a diagnostics bundle is
     # dumped. The cost is one dict + deque append per step (<1% of any
@@ -1481,7 +1489,7 @@ def _fit_body(
             pf = (
                 HostPrefetcher(
                     train_loader, host_pipeline, depth=flags.prefetch,
-                    skip=skip,
+                    skip=skip, span=spans.annotate,
                 )
                 if flags.prefetch > 0
                 else None
@@ -1541,7 +1549,7 @@ def _fit_body(
                     capture_xla("train_step", state_shapes, batch, targets)
                     or train_step
                 )
-                with spans.span("step"):
+                with spans.span("step", step_num=host_step + 1):
                     if flags.log_grad_norms:
                         state, loss, norms = train_step(state, batch, targets)
                     else:

@@ -192,13 +192,14 @@ def _scoped_train_step(devices):
     return step.lower(state, batch, arr(I32))
 
 
-def _scoped_fused_decode(devices):
-    """The serve engine's decode quantum with the fused paged kernel on, for
-    one described chip: `paged_attend` sits under decode/attn."""
+def _paged_decode_quantum(devices, **cfg_kw):
+    """The serve engine's paged decode quantum (two ticks, bf16 pools) at
+    GPT-small widths, lowered for one described chip. Returns the lowering
+    and the pool's shape `(L, NP, H, P, D)`."""
     from tpukit.model import gpt
     from tpukit.serve import decode, paged
 
-    cfg = _small_cfg(fused_decode=True)
+    cfg = _small_cfg(**cfg_kw)
     one = SingleDeviceSharding(devices[0])
     on = lambda tree: jax.tree.map(  # noqa: E731
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
@@ -206,9 +207,41 @@ def _scoped_fused_decode(devices):
     params = on(jax.eval_shape(lambda: gpt.init_params(jax.random.PRNGKey(0), cfg)))
     cache = on(jax.eval_shape(lambda: paged.init_paged_cache(cfg, n * per_slot + 1, page, per_slot, n, "bf16")))
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
-    return decode.decode_step.lower(
+    lowered = decode.decode_step.lower(
         params, cfg, sds((n, page * per_slot), I32), cache, sds((n,), I32), sds((n,), jnp.bool_),
         sds((n,), I32), sds((n, 2), jnp.uint32), 0, 0.0, 0, None, steps=2)
+    return lowered, cache["k"].shape
+
+
+def _scoped_fused_decode(devices):
+    """The decode quantum with the fused paged kernel on: `paged_attend`
+    sits under decode/attn."""
+    return _paged_decode_quantum(devices, fused_decode=True)[0]
+
+
+def test_v5e_paged_decode_quantum_writes_the_stack_where_it_lies(v5e):
+    """What the chip's compiler makes of the default (gathered) paged decode
+    quantum: each layer's K and V land in the stacked pool `[L, NP, H, P, D]`
+    through ONE scatter whose operand is the stack itself (2 x L of them, on
+    the loop's carry), no layer's pool is sliced out of the stack, the stack
+    is never rebuilt with dynamic-update-slices, and the only whole-pool
+    copies are the parent's four at the loop's edges (in and out, K and V:
+    ROADMAP S1 (c))."""
+    import re
+
+    lowered, pool = _paged_decode_quantum(v5e)
+    text = lowered.compile().as_text()
+    layers = pool[0]
+    dims = lambda shape: "bf16[" + ",".join(map(str, shape)) + "]"  # noqa: E731
+    stack, layer_pools = dims(pool), {dims((1, *pool[1:])), dims(pool[1:])}
+    made = [(m.group(2), m.group(1)) for m in re.finditer(
+        r"= (bf16\[[\d,]*\])\S* ([a-z][a-z\-]*)\(", text)]
+    count = lambda op, shapes: sum(1 for o, s in made if o == op and s in shapes)  # noqa: E731
+    assert count("scatter", {stack}) == 2 * layers
+    assert count("scatter", layer_pools) == 0
+    assert count("slice", layer_pools) == count("dynamic-slice", layer_pools) == 0
+    assert count("dynamic-update-slice", {stack}) == count("concatenate", {stack}) == 0
+    assert count("copy", {stack}) <= 4
 
 
 @pytest.mark.parametrize("name,lower,expect,scope_of", [

@@ -424,6 +424,183 @@ def test_paged_compile_budget(tok, cfg, params):
 
 
 # ---------------------------------------------------------------------------
+# The pool is updated where it lies: the stacked `[L, NP, H, P, D]` pool goes
+# through the layer loop whole and is read and written by index (ISSUE 26).
+# ---------------------------------------------------------------------------
+
+
+def _stack_cfg(kv_dtype):
+    """Two layers, four heads of 8; pages of 32 positions so that an int8
+    (page, head) row is exactly one 256-element quant block. bf16 pages at
+    bf16 compute (the pair that is exact)."""
+    return GPTConfig(
+        dim=32, head_dim=8, heads=4, num_layers=2, vocab_size=97,
+        max_position_embeddings=96,
+        compute_dtype=jnp.bfloat16 if kv_dtype == "bf16" else jnp.float32,
+    )
+
+
+_STACK_GEOMETRY = dict(slots=3, page=32, per_slot=2, num_pages=9)  # pages 7, 8 of 1..8: no table names them
+
+
+def _stack_state(cfg, kv_dtype):
+    g = _STACK_GEOMETRY
+    cache = paged_lib.init_paged_cache(cfg, g["num_pages"], g["page"], g["per_slot"], g["slots"], kv_dtype)
+    # lanes 0 and 1 serve; lane 2 holds pages and stays masked (inactive)
+    cache["bt"] = jnp.asarray([[5, 2], [1, 6], [4, 3]], jnp.int32)
+    n, width = g["slots"], g["page"] * g["per_slot"]
+    return (jnp.zeros((n, width), jnp.int32), cache, jnp.zeros((n,), jnp.int32),
+            jnp.zeros((n,), bool), jnp.zeros((n,), jnp.int32), jnp.zeros((n, 2), jnp.uint32))
+
+
+def _stack_prefill_args(cfg):
+    """One chunk (a whole page) for lanes 0 and 1: a 32-token prompt and a
+    20-token prompt padded to the chunk."""
+    page = _STACK_GEOMETRY["page"]
+    rows = np.random.RandomState(7).randint(1, cfg.vocab_size, (2, page)).astype(np.int32)
+    rows[1, 20:] = 0
+    lens = jnp.asarray([page, 20], jnp.int32)
+    return (jnp.asarray([0, 1], jnp.int32), jnp.asarray(rows), jnp.zeros((2,), jnp.int32),
+            jnp.ones((2,), bool), lens, lens + 8, jnp.zeros((2, 2), jnp.uint32))
+
+
+_POOL_MOVES = ("slice", "dynamic_slice", "concatenate", "dynamic_update_slice")
+
+
+def _pool_shaped_results(text, cache):
+    """(op, shape) of every slice / dynamic_slice / concatenate /
+    dynamic_update_slice in a lowered module whose result has the shape of a
+    stacked pool (or int8 scale sidecar) or of one layer of it; and the
+    number of scatters whose operand and result are a whole stack."""
+    import re
+
+    stacks = {"x".join(map(str, v.shape)) for k, v in cache.items() if k != "bt"}
+    layer = {"x".join(("1", *sh.split("x")[1:])) for sh in stacks} | {sh.split("x", 1)[1] for sh in stacks}
+    moves = [(m.group(1), m.group(2)) for m in re.finditer(
+        r"stablehlo\.(\w+)\b[^\n]*->\s*tensor<([0-9x]+)x[a-z]\w*>", text)
+        if m.group(1) in _POOL_MOVES and m.group(2) in stacks | layer]
+    scatters = [m.group(1) for m in re.finditer(
+        r"\}\) : \(tensor<([0-9x]+)x\w+>, tensor<[0-9x]+xi32>, tensor<[^>]+>\) -> tensor<([0-9x]+)x\w+>", text)
+        if m.group(1) == m.group(2) and m.group(1) in stacks]
+    return moves, len(scatters)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("program", ["decode_1", "decode_4", "prefill_chunk"])
+def test_paged_programs_never_slice_or_restack_the_pool(program, kv_dtype, fresh_compiles):
+    """The count that says the mechanism engages (it engages on every call
+    or on none): in the module text of the decode step (one tick, and a
+    quantum of four) and of a chunked prefill, no slice, dynamic_slice,
+    concatenate or dynamic_update_slice has a result of the pool's shape
+    `[L, NP, H, P, D]` or of one layer's `[1, NP, H, P, D]` / `[NP, H, P, D]`
+    (nor of the int8 scale sidecars'); each layer writes K and V with one
+    scatter on the whole stack (int8: payload and scales)."""
+    from tpukit.serve.decode import prefill_chunk_paged
+
+    cfg = _stack_cfg(kv_dtype)
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    state = _stack_state(cfg, kv_dtype)
+    if program == "prefill_chunk":
+        lowered = prefill_chunk_paged.lower(params, cfg, *state, *_stack_prefill_args(cfg))
+    else:
+        lowered = decode_step.lower(params, cfg, *state, 0, 0.0, 0, None, steps=int(program[-1]))
+    moves, stack_scatters = _pool_shaped_results(lowered.as_text(), state[1])
+    assert moves == []
+    # a quantum's loop body is in the text once, whatever `steps`
+    assert stack_scatters == cfg.num_layers * (4 if kv_dtype == "int8" else 2)
+
+
+def test_pool_shaped_results_sees_a_slice_and_a_restack():
+    """The reader of the count above on a module that does what the parent's
+    `forward_cached` did: a layer sliced out of the stack, the stack rebuilt."""
+    pool = jnp.zeros((2, 9, 4, 32, 8), jnp.bfloat16)
+
+    def old_shape(pool, val):
+        layers = [pool[i].at[3, :, 0, :].set(val) for i in range(pool.shape[0])]
+        return jnp.stack(layers)
+
+    text = jax.jit(old_shape).lower(pool, jnp.ones((4, 8), jnp.bfloat16)).as_text()
+    moves, stack_scatters = _pool_shaped_results(text, {"k": pool})
+    assert stack_scatters == 0
+    assert {op for op, _ in moves} >= {"slice", "concatenate"}
+    assert {shape for _, shape in moves} == {"2x9x4x32x8", "1x9x4x32x8"}
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "bf16", "int8"])
+def test_pool_holds_the_ring_paths_kv_bit_for_bit(kv_dtype):
+    """Bit parity of the POOL, not only of the tokens: after one prefill
+    chunk and three decode ticks (one quantum) with lane 2 masked, every
+    layer's pool holds at each written logical position exactly what the
+    ring path's K/V hold there; pages no block table names, lane 2's pages
+    and the unwritten tail of lane 0's second page are still zero; the null
+    page is the only page the masked lane wrote. bf16 pages at bf16 compute
+    (the benchmark's pair) are exact; f32 pages agree to float32 rounding
+    (two programs, two fusions; their tokens are equal); int8 pages are
+    lossy by construction and are held to the quantizer's own step."""
+    from tpukit.model import gpt
+    from tpukit.ops import quant_comm
+    from tpukit.serve.decode import prefill_chunk_paged
+
+    cfg = _stack_cfg(kv_dtype)
+    params = init_params(jax.random.PRNGKey(1), cfg)
+    g = _STACK_GEOMETRY
+    page, width, layers = g["page"], g["page"] * g["per_slot"], cfg.num_layers
+    admit = _stack_prefill_args(cfg)
+    buf, cache, cursors, active, limits, keys = prefill_chunk_paged(
+        params, cfg, *_stack_state(cfg, kv_dtype), *admit)
+    assert list(np.asarray(active)) == [True, True, False]
+
+    # the ring path on the same rows, at the same view shapes: the chunk
+    # through forward_cached's vector-cursor ring branch, then the same quantum
+    rows, starts = admit[1], admit[2]
+    pos = starts[:, None] + jnp.arange(page, dtype=jnp.int32)[None, :]
+    _, ring2 = jax.jit(gpt.forward_cached, static_argnums=1)(
+        params, cfg, rows, pos, gpt.init_kv_cache(cfg, 2, width), starts)
+    ring = {k: jnp.zeros((layers, g["slots"], cfg.heads, width, cfg.head_dim), v.dtype).at[:, :2].set(v)
+            for k, v in ring2.items()}
+    ring_out = decode_step(params, cfg, buf, ring, cursors, active, limits, keys, 0, 0.0, 0, None, steps=3)
+    buf, cache, cursors, active = decode_step(params, cfg, buf, cache, cursors, active, limits, keys,
+                                              0, 0.0, 0, None, steps=3)
+    ring = ring_out[1]
+    if kv_dtype != "int8":  # the token streams are the same streams
+        assert np.array_equal(np.asarray(buf), np.asarray(ring_out[0]))
+        assert list(np.asarray(cursors)) == list(np.asarray(ring_out[2])) == [page + 3, 23, 0]
+
+    bt = np.asarray(cache["bt"])
+    written = {0: page + 2, 1: page}  # lane -> positions [0, n) written: the chunk's page, then cursor-1 of each tick
+    for name, scales in (("k", "ks"), ("v", "vs")):
+        pool = np.asarray(cache[name].astype(jnp.float32))
+        if kv_dtype == "int8":
+            sh = cache[name].shape
+            pool = np.asarray(quant_comm.dequantize_blocks(
+                cache[name].reshape(*sh[:3], -1), cache[scales]).reshape(sh))
+        want = np.asarray(ring[name].astype(jnp.float32))
+        for lane, n in written.items():
+            for q in range(n):
+                got = pool[:, bt[lane, q // page], :, q % page, :]  # [L, H, D]
+                ref = want[:, lane, :, q, :]
+                if kv_dtype == "int8":
+                    # a position's error is a few quantizer steps of its (page, head) block: one rounding
+                    # per requantisation of the page, and upstream layers attended over lossy K/V
+                    step = np.abs(want[:, lane]).max(axis=(-1, -2))[:, :, None] / 127.0
+                    assert np.all(np.abs(got - ref) <= 4 * step), (name, lane, q)
+                elif kv_dtype == "f32":
+                    # the ring and the paged program fuse differently on XLA:CPU: float32 rounding, the last bits
+                    np.testing.assert_allclose(got, ref, rtol=2e-6, atol=2e-7, err_msg=str((name, lane, q)))
+                else:
+                    assert np.array_equal(got, ref), (name, lane, q)
+        raw = np.asarray(cache[name].astype(jnp.float32))
+        assert not raw[:, [7, 8]].any()  # pages no table names
+        assert not raw[:, bt[2]].any()  # the masked lane's own pages
+        assert not raw[:, bt[1, 1]].any()  # lane 1 never reached its second page
+        assert not raw[:, bt[0, 1], :, 2:, :].any()  # lane 0's second page past positions 32, 33
+        assert raw[:, 0].any(axis=(1, 2, 3)).all()  # every layer's null page took the masked lane's writes
+        if kv_dtype == "int8":
+            sc = np.asarray(cache[scales])
+            assert not sc[:, [7, 8]].any() and not sc[:, bt[2]].any() and not sc[:, bt[1, 1]].any()
+
+
+# ---------------------------------------------------------------------------
 # Sharded serving: the paged gather must add ZERO collectives — compiled
 # HLO matches decode_step_comm(paged=True) exactly, no involuntary remat.
 # ---------------------------------------------------------------------------

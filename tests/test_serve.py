@@ -473,9 +473,11 @@ def test_paged_serve_programs_name_every_serving_scope(cfg, params, fresh_compil
     decode = decode_step.lower(params, cfg, buf, cache, cur, act, lim, keys,
                                0, 0.0, 0, None, steps=2).compile().as_text()
     paths = set(instruction_scopes(decode).values())
+    # the paged forward writes the stacked pool by index inside each layer's
+    # attention: no restack outside the layers (the ring path keeps one)
+    assert "decode/kv_write" not in paths
     assert {"decode/embed", "decode/ln", "decode/attn", "decode/attn/kv_gather",
             "decode/attn/kv_write", "decode/attn/attend", "decode/ffn",
-            "decode/kv_write",  # forward_cached's restack, outside any one layer
             "decode/head", "decode/head/ln", "decode/sample"} <= paths, paths
     a = 2
     z = lambda shape, dt: jnp.zeros(shape, dt)  # noqa: E731

@@ -660,12 +660,17 @@ def _attend_over_cache(layer, cfg: GPTConfig, q, k_cache, v_cache, q_pos):
 
 @jax.named_scope("attn")
 def _apply_attention_paged(layer, cfg: GPTConfig, x, pool_k, pool_v,
-                           scale_k, scale_v, bt, start, write_mask,
+                           scale_k, scale_v, li, bt, start, write_mask,
                            mesh=None):
     """Attention for decode over the PAGED cache (round 15, ROADMAP #2):
     the per-row-cursor indirection of the vector path above with one extra
     hop — each row's K/V comes from fixed-size pages dereferenced through
     its block-table row `bt [B, MP]` instead of a contiguous ring slice.
+
+    `pool_k` / `pool_v` (and the int8 `scale_k` / `scale_v` sidecars) are
+    the STACKED pools `[L, NP, H, P, D]`, whole; `li` is this layer's
+    index. Reads and writes index `(li, page)`, so no layer's pool is ever
+    sliced out and the updated stacks are handed back for the next layer.
 
     The gather (`serve.paged.gather_view`) materializes exactly the
     `[B, H, MP*P, D]` per-row view the vector path writes and attends, the
@@ -683,8 +688,8 @@ def _apply_attention_paged(layer, cfg: GPTConfig, x, pool_k, pool_v,
 
     Under a serving mesh the pools shard heads-over-`model` and stay
     replicated across `data` (the engine enforces a model-only grid for
-    paged serving): gather and scatter index only the unsharded page axis
-    with replicated indices, so the paged hop adds ZERO collectives — the
+    paged serving): gather and scatter index only the unsharded layer and
+    page axes with replicated indices, so the paged hop adds ZERO collectives — the
     `decode_step_comm` closed form is unchanged and the compiled HLO must
     still match it exactly (tests/test_paged.py)."""
     from tpukit.serve import paged as paged_lib  # lazy: tpukit.serve imports gpt
@@ -705,15 +710,18 @@ def _apply_attention_paged(layer, cfg: GPTConfig, x, pool_k, pool_v,
         # is shared.
         from tpukit.ops import paged_attention as paged_kernel
 
+        # the kernel takes ONE layer's pool: this slice is the fused path's
+        # own cost (ROADMAP S1 (b))
+        own = lambda z: None if z is None else z[li]
         attn = paged_kernel.fused_paged_attention(
-            pool_k, pool_v, scale_k, scale_v, bt, start,
+            own(pool_k), own(pool_v), own(scale_k), own(scale_v), bt, start,
             q[:, :, 0, :], k[:, :, 0, :], v[:, :, 0, :], mesh=mesh,
         )
         out = linear(attn.reshape(batch, 1, cfg.inner_dim),
                      layer["attn"]["out"], cfg.compute_dtype)
     else:
-        view_k = paged_lib.gather_view(pool_k, scale_k, bt, cfg.compute_dtype)
-        view_v = paged_lib.gather_view(pool_v, scale_v, bt, cfg.compute_dtype)
+        view_k = paged_lib.gather_view(pool_k, scale_k, li, bt, cfg.compute_dtype)
+        view_v = paged_lib.gather_view(pool_v, scale_v, li, bt, cfg.compute_dtype)
         upd = lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (0, s, 0))
         view_k = jax.vmap(upd)(view_k, k, start)
         view_v = jax.vmap(upd)(view_v, v, start)
@@ -722,14 +730,14 @@ def _apply_attention_paged(layer, cfg: GPTConfig, x, pool_k, pool_v,
 
     if t == 1:
         pool_k, scale_k = paged_lib.write_token(
-            pool_k, scale_k, bt, start, k[:, :, 0, :], write_mask
+            pool_k, scale_k, li, bt, start, k[:, :, 0, :], write_mask
         )
         pool_v, scale_v = paged_lib.write_token(
-            pool_v, scale_v, bt, start, v[:, :, 0, :], write_mask
+            pool_v, scale_v, li, bt, start, v[:, :, 0, :], write_mask
         )
     else:
-        pool_k, scale_k = paged_lib.write_pages(pool_k, scale_k, bt, start, k, write_mask)
-        pool_v, scale_v = paged_lib.write_pages(pool_v, scale_v, bt, start, v, write_mask)
+        pool_k, scale_k = paged_lib.write_pages(pool_k, scale_k, li, bt, start, k, write_mask)
+        pool_v, scale_v = paged_lib.write_pages(pool_v, scale_v, li, bt, start, v, write_mask)
     return out, pool_k, pool_v, scale_k, scale_v
 
 
@@ -751,6 +759,14 @@ def forward_cached(params: Params, cfg: GPTConfig, input_ids, position_ids,
     never write a page it no longer owns. The ring path ignores
     `write_mask` and keeps its original trace byte-unchanged.
 
+    The two branches hold their cache differently through the layer loop.
+    The paged branch threads the stacked pools `[L, NP, H, P, D]` whole and
+    every layer reads and writes its rows by index (one gather and one
+    scatter on the stack per layer and pool), so a decode tick moves the
+    values it writes and not the pool. The ring branch still slices each
+    layer's `[B, H, S, D]` ring out of the stack and restacks all of them
+    on the way out; no benchmark cell runs it (ROADMAP S1).
+
     `mesh` matters only for the paged path with `cfg.fused_decode`: the
     fused kernel must run inside shard_map when heads are sharded over a
     `model` axis (GSPMD cannot partition a pallas_call) — the serve
@@ -765,27 +781,27 @@ def forward_cached(params: Params, cfg: GPTConfig, input_ids, position_ids,
             )
         if write_mask is None:
             write_mask = jnp.ones((bt.shape[0],), bool)
-        quant = "ks" in cache
+        # the stacked pools (and int8 scale sidecars) go through the layer
+        # loop whole: each layer reads and writes its own rows by index
+        pool_k, pool_v = cache["k"], cache["v"]
+        scale_k, scale_v = cache.get("ks"), cache.get("vs")
+    else:
+        new_k, new_v = [], []
     x = apply_embeddings(params, cfg, input_ids, position_ids)
-    new_k, new_v, new_ks, new_vs = [], [], [], []
     for i in range(cfg.num_layers):
         layer = jax.tree_util.tree_map(lambda t: t[i], params["layers"])
         h = layer_norm(x, layer["norm1"]).astype(cfg.compute_dtype)
         if paged:
-            attn, k_c, v_c, ks_c, vs_c = _apply_attention_paged(
-                layer, cfg, h, cache["k"][i], cache["v"][i],
-                cache["ks"][i] if quant else None,
-                cache["vs"][i] if quant else None,
+            attn, pool_k, pool_v, scale_k, scale_v = _apply_attention_paged(
+                layer, cfg, h, pool_k, pool_v, scale_k, scale_v, i,
                 bt, start, write_mask, mesh=mesh,
             )
-            new_ks.append(ks_c)
-            new_vs.append(vs_c)
         else:
             attn, k_c, v_c = _apply_attention_cached(
                 layer, cfg, h, cache["k"][i], cache["v"][i], start
             )
-        new_k.append(k_c)
-        new_v.append(v_c)
+            new_k.append(k_c)
+            new_v.append(v_c)
         x = x + attn
         h = layer_norm(x, layer["norm2"]).astype(cfg.compute_dtype)
         if cfg.num_experts > 0:
@@ -793,13 +809,13 @@ def forward_cached(params: Params, cfg: GPTConfig, input_ids, position_ids,
             x = x + ffn_out
         else:
             x = x + _apply_feed_forward(layer, cfg, h, None, True)
-    with jax.named_scope("kv_write"):  # the per-layer caches restacked
-        cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-        if paged and quant:
-            cache["ks"] = jnp.stack(new_ks)
-            cache["vs"] = jnp.stack(new_vs)
     if paged:
-        cache["bt"] = bt
+        cache = {"k": pool_k, "v": pool_v, "bt": bt}
+        if scale_k is not None:
+            cache.update(ks=scale_k, vs=scale_v)
+    else:
+        with jax.named_scope("kv_write"):  # the per-layer caches restacked
+            cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
     return apply_head(params, cfg, x), cache
 
 

@@ -277,8 +277,10 @@ def paged_attend_reference(pool_k, pool_v, scale_k, scale_v, bt, start, q,
     from tpukit.serve import paged as paged_lib  # lazy: serve imports ops
 
     cdt = q.dtype
-    view_k = paged_lib.gather_view(pool_k, scale_k, bt, cdt)
-    view_v = paged_lib.gather_view(pool_v, scale_v, bt, cdt)
+    # one layer's pool here: gather_view takes the stack and a layer index
+    stack = lambda z: None if z is None else z[None]
+    view_k = paged_lib.gather_view(pool_k[None], stack(scale_k), 0, bt, cdt)
+    view_v = paged_lib.gather_view(pool_v[None], stack(scale_v), 0, bt, cdt)
     upd = lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (0, s, 0))
     view_k = jax.vmap(upd)(view_k, k_new[:, :, None, :], start)
     view_v = jax.vmap(upd)(view_v, v_new[:, :, None, :], start)

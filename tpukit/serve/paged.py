@@ -12,12 +12,22 @@ PagedAttention, PAPERS.md):
     NULL page — never allocated, the sink for masked writes — so a block
     table full of zeros is always safe to dereference.
   - **Block tables**: per-slot `[N, pages_per_slot]` int32 rows of page
-    ids. The decode step dereferences them with ONE gather per layer
-    (`gather_view`) into exactly the `[N, H, W, D]` per-row view the
-    round-14 vector-cursor attention already consumes — the indirection is
-    localized in `gpt._apply_attention_cached`'s paged branch and the
-    decode-step math is otherwise byte-for-byte the ring path, which is
-    what keeps the token-for-token parity bar provable.
+    ids. The decode step dereferences them with ONE gather per layer, on
+    `(layer, page)` of the stacked pool (`gather_view`), into exactly the
+    `[N, H, W, D]` per-row view the round-14 vector-cursor attention
+    already consumes — the indirection is localized in
+    `gpt._apply_attention_paged` and the attention math is the ring path's
+    own (`gpt._attend_over_cache`), which is what keeps the
+    token-for-token parity bar provable.
+  - **The stack is updated where it lies**: `gpt.forward_cached` threads
+    the whole `[L, num_pages, H, P, D]` pools through its layer loop and
+    the three device-side ops below take the stack and a layer index — a
+    layer's pool is never sliced out of the stack and the stack is never
+    rebuilt. A write is one scatter whose operand is the stack, so a
+    caller whose stack dies at the write (the decode quantum's loop
+    carry) has it updated in place; a tick moves the 2 x L x N x H x D
+    values it writes, not the pool (PERF.md section 6, PR 26: slicing and
+    restacking were 35% of the serving cell's device time).
   - **Allocation at request granularity**: a request admitted with prompt
     length p and budget m holds `ceil(min(p + m, width) / P)` pages — its
     actual worst case — instead of a full-width slot. The HBM a short
@@ -149,24 +159,27 @@ def pool_bytes(cfg, num_pages: int, page_size: int, kv_dtype: str) -> int:
     return 2 * cfg.num_layers * num_pages * cfg.heads * row_bytes
 
 
-# -- device-side page ops (called per layer from gpt.forward_cached) --------
+# -- device-side page ops (called per layer from gpt.forward_cached, each on
+# the whole stacked pool and the layer's index) -----------------------------
 
 
 @jax.named_scope("kv_gather")
-def gather_view(pool, scales, bt, out_dtype):
-    """Dereference the block tables: `pool [NP, H, P, D]` gathered through
-    `bt [N, MP]` into the `[N, H, MP*P, D]` per-row K (or V) view the
-    round-14 vector-cursor attention consumes. Logical position `q` of row
-    `b` lives at `view[b, :, q, :]` == page `bt[b, q // P]`, offset
-    `q % P` — the ONE indirection of the paged design. int8 pools
-    dequantize after the gather (per-row blocks, `quant_comm` layout)."""
-    v = pool[bt]  # [N, MP, H, P, D] — gather on the (unsharded) page axis
+def gather_view(pool, scales, layer, bt, out_dtype):
+    """Dereference the block tables: layer `layer`'s pages of the stacked
+    `pool [L, NP, H, P, D]` gathered through `bt [N, MP]` into the
+    `[N, H, MP*P, D]` per-row K (or V) view the round-14 vector-cursor
+    attention consumes — ONE gather on `(layer, bt)`, the layer's pool is
+    never sliced out of the stack. Logical position `q` of row `b` lives at
+    `view[b, :, q, :]` == page `bt[b, q // P]`, offset `q % P` — the one
+    indirection of the paged design. int8 pools dequantize after the gather
+    (per-row blocks, `quant_comm` layout)."""
+    v = pool[layer, bt]  # [N, MP, H, P, D] — gather on the (unsharded) layer and page axes
     n, mp, h, p, d = v.shape
     if scales is not None:
         # dequantize with the head axis PRESERVED (the pools shard heads
         # over `model`; merging H into a rows axis would force a GSPMD
         # reshard — the comm-free audit would break)
-        s = scales[bt]  # [N, MP, H, blocks]
+        s = scales[layer, bt]  # [N, MP, H, blocks]
         v = quant_comm.dequantize_blocks(
             v.reshape(n, mp, h, p * d), s
         ).reshape(n, mp, h, p, d)
@@ -174,30 +187,35 @@ def gather_view(pool, scales, bt, out_dtype):
 
 
 @jax.named_scope("kv_write")
-def write_token(pool, scales, bt, start, val, write_mask):
-    """Decode-tick write-back: row `b`'s freshly computed K (or V)
-    `val [N, H, D]` lands at logical position `start[b]` — page
-    `bt[b, start // P]`, offset `start % P`. Rows with `write_mask`
-    False are routed to the null page (invariant 2 above): an inactive or
-    prefilling slot's re-forward must never touch a real page.
+def write_token(pool, scales, layer, bt, start, val, write_mask):
+    """Decode-tick write-back into the stacked `pool [L, NP, H, P, D]`: row
+    `b`'s freshly computed K (or V) `val [N, H, D]` lands at logical
+    position `start[b]` of layer `layer` — page `bt[b, start // P]`, offset
+    `start % P` — as ONE scatter at `(layer, pids, :, off, :)`. The stack
+    is the scatter's operand, so a caller whose stack dies there (the
+    decode quantum's loop carry) has it updated in place; nothing is
+    sliced out or restacked. Rows with `write_mask` False are routed to
+    the null page (invariant 2 above): an inactive or prefilling slot's
+    re-forward must never touch a real page.
 
     f32/bf16 pools scatter the single position; int8 pools gather the
-    touched page row, dequantize, insert the exact new value, and
-    REQUANTIZE the row (the block scale may move — which is why shared
-    pages are never writable, invariant 1). Writable pages are exclusive
-    per slot, so the scatter's row indices never collide except on the
-    null page, where any winner is garbage by design."""
+    touched page rows `(layer, pids)`, dequantize, insert the exact new
+    value, REQUANTIZE the rows (the block scale may move — which is why
+    shared pages are never writable, invariant 1) and scatter them back at
+    `(layer, pids)`. Writable pages are exclusive per slot, so the
+    scatter's row indices never collide except on the null page, where any
+    winner is garbage by design."""
     n = start.shape[0]
-    p = pool.shape[2]
+    p = pool.shape[3]
     page = start // p
     off = start % p
     pids = jnp.take_along_axis(bt, page[:, None], axis=1)[:, 0]
     pids = jnp.where(write_mask, pids, 0)
     if scales is None:
-        return pool.at[pids, :, off, :].set(val.astype(pool.dtype)), None
-    h, d = pool.shape[1], pool.shape[3]
-    rows = pool[pids]  # [N, H, P, D] int8
-    srows = scales[pids]  # [N, H, blocks]
+        return pool.at[layer, pids, :, off, :].set(val.astype(pool.dtype)), None
+    h, d = pool.shape[2], pool.shape[4]
+    rows = pool[layer, pids]  # [N, H, P, D] int8
+    srows = scales[layer, pids]  # [N, H, blocks]
     # head axis preserved through the quantizer (sharding — gather_view)
     deq = quant_comm.dequantize_blocks(
         rows.reshape(n, h, p * d), srows
@@ -206,22 +224,23 @@ def write_token(pool, scales, bt, start, val, write_mask):
     deq = jnp.where(hit, val[:, :, None, :].astype(jnp.float32), deq)
     q, s = quant_comm.quantize_blocks(deq.reshape(n, h, p * d))
     return (
-        pool.at[pids].set(q.reshape(n, h, p, d)),
-        scales.at[pids].set(s),
+        pool.at[layer, pids].set(q.reshape(n, h, p, d)),
+        scales.at[layer, pids].set(s),
     )
 
 
 @jax.named_scope("kv_write")
-def write_pages(pool, scales, bt, start, vals, write_mask):
-    """Prefill-chunk write-back: `vals [N, H, C, D]` covers logical
-    positions `[start[b], start[b] + C)` per row, with `start` page-aligned
-    and C a page multiple (the engine's chunking contract) — so the write
-    is whole pages, one scatter row per (lane, chunk-page). Masked lanes
-    route to the null page. Chunk positions beyond a lane's allocation
-    dereference block-table zeros and also land in the null page —
-    bucket-pad garbage never occupies a real page."""
+def write_pages(pool, scales, layer, bt, start, vals, write_mask):
+    """Prefill-chunk write-back into the stacked `pool [L, NP, H, P, D]`:
+    `vals [N, H, C, D]` covers logical positions `[start[b], start[b] + C)`
+    per row, with `start` page-aligned and C a page multiple (the engine's
+    chunking contract) — so the write is whole pages of layer `layer`, ONE
+    scatter at `(layer, pids)` with a row per (lane, chunk-page). Masked
+    lanes route to the null page. Chunk positions beyond a lane's
+    allocation dereference block-table zeros and also land in the null
+    page — bucket-pad garbage never occupies a real page."""
     n, h, c, d = vals.shape
-    p = pool.shape[2]
+    p = pool.shape[3]
     npg = c // p
     first = start // p
     j = jnp.arange(npg, dtype=start.dtype)
@@ -233,13 +252,13 @@ def write_pages(pool, scales, bt, start, vals, write_mask):
         .reshape(n * npg, h, p, d)
     )
     if scales is None:
-        return pool.at[pids].set(rows.astype(pool.dtype)), None
+        return pool.at[layer, pids].set(rows.astype(pool.dtype)), None
     q, s = quant_comm.quantize_blocks(  # head axis preserved (sharding)
         rows.astype(jnp.float32).reshape(n * npg, h, p * d)
     )
     return (
-        pool.at[pids].set(q.reshape(n * npg, h, p, d)),
-        scales.at[pids].set(s),
+        pool.at[layer, pids].set(q.reshape(n * npg, h, p, d)),
+        scales.at[layer, pids].set(s),
     )
 
 

@@ -140,6 +140,17 @@ def parse_serve_flags(argv=None):
     ap.add_argument("--moe_dispatch", choices=("xla", "pallas"), default="xla",
                     help="meshless decode dataflow for MoE checkpoints; "
                     "'pallas' (dropless) makes the cached decode exact")
+    ap.add_argument("--model", choices=("gpt", "latent"), default="gpt",
+                    help="block family: 'gpt' (tpukit/model/gpt.py, the flags "
+                    "above) or 'latent' (tpukit/model/latent.py: latent "
+                    "attention with a learned key selection, window layers, a "
+                    "sigmoid-routed expert share; served only, paged cache "
+                    "only, fresh seeded weights on one device)")
+    ap.add_argument("--model_config", type=str, default="",
+                    help="--model latent: a configuration file with the "
+                    "published config.json keys (benchmark/configs/"
+                    "dots3-note-prev.json); empty = the tiny preset at the "
+                    "tokenizer's vocabulary")
     # checkpoint
     ap.add_argument("--checkpoint", type=str, default="",
                     help="path or 'latest'; empty serves fresh seeded params "
@@ -244,6 +255,12 @@ def main(argv=None):
         moe_dispatch=flags.moe_dispatch if flags.num_experts > 0 else "xla",
     )
     buckets = tuple(sorted({int(b) for b in flags.buckets.split(",") if b}))
+
+    # ---- the latent family (--model latent): served only, one device -----
+    if flags.model == "latent":
+        cfg, params = _latent_model(flags, tokenizer)
+        return _serve_stream(flags, cfg, params, None, tokenizer, buckets,
+                             StepLogger(flags.metrics_log), FlightRecorder())
 
     # ---- fleet mode (round 19, --replicas >= 1) --------------------------
     if flags.replicas > 0:
@@ -404,6 +421,20 @@ def main(argv=None):
                 print("draft model: fresh seeded params "
                       "(no --draft_checkpoint)")
 
+    return _serve_stream(flags, cfg, params, mesh, tokenizer, buckets, logger,
+                         recorder, draft_params, draft_cfg)
+
+
+def _serve_stream(flags, cfg, params, mesh, tokenizer, buckets, logger, recorder,
+                  draft_params=None, draft_cfg=None):
+    """The engine, the seeded synthetic stream through it, and the summary:
+    the same for every block family (the engine reaches the model through
+    `tpukit.model.family(cfg)`)."""
+    from tpukit.mesh import is_process_zero
+    from tpukit.obs import MetricRegistry, TraceRecorder, parse_slo
+    from tpukit.serve import ServeConfig, ServeEngine, synthetic_request_stream
+
+    p0 = is_process_zero()
     # ---- the engine + the stream -----------------------------------------
     serve = ServeConfig(
         slots=flags.slots, buckets=buckets,
@@ -498,6 +529,39 @@ def main(argv=None):
     # like the training recipes' FitResult: what came out, for a caller that
     # drives main(argv) in-process (chip_smoke.py); run_recipe ignores it
     return completions
+
+
+def _latent_model(flags, tokenizer):
+    """`--model latent`: the config (a configuration file's published keys,
+    or the tiny preset at the tokenizer's vocabulary) and fresh seeded
+    weights. The family has no training path, so there is no checkpoint to
+    restore, no strategy and no mesh; speculation and the fleet speak of the
+    GPT block's cache and are refused by name."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from tpukit.model import ServedOnlyError, latent
+
+    for flag, why in (("checkpoint", "it is served only: nothing trains it, so nothing saves one"),
+                      ("draft", "speculative decoding verifies on the ring cache"),
+                      ("replicas", "the fleet's page handoff copies the GPT block's K and V pools")):
+        if getattr(flags, flag):
+            raise ServedOnlyError(f"--model latent takes no --{flag}: {why}")
+    if not flags.page_size:
+        raise ServedOnlyError("--model latent needs the paged cache (--page_size > 0): "
+                              "its window layers keep a ring of pages")
+    dtype = jnp.float32 if flags.disable_amp else jnp.bfloat16
+    if flags.model_config:
+        with open(flags.model_config) as f:
+            cfg = latent.config_from_hf(json.load(f), compute_dtype=dtype, param_dtype=dtype)
+    else:
+        cfg = latent.tiny_config(vocab_size=tokenizer.vocab_size, compute_dtype=dtype, param_dtype=dtype)
+    params = jax.jit(lambda r: latent.init_params(r, cfg))(jax.random.PRNGKey(flags.seed))
+    print(f"serving fresh seeded params of the latent family "
+          f"({cfg.num_layers} layers, {cfg.experts_held} of {cfg.n_experts} experts held)")
+    return cfg, params
 
 
 def _apply_request_knobs(requests, flags):

@@ -244,6 +244,48 @@ def test_v5e_paged_decode_quantum_writes_the_stack_where_it_lies(v5e):
     assert count("copy", {stack}) <= 4
 
 
+def test_v5e_latent_family_decode_quantum_and_prefill_chunk_compile_at_published_widths(v5e):
+    """The latent family's serve programs at the dots3-note-prev
+    configuration's real widths (8 slots of 2,048 tokens: the pools are
+    small, the weights and every width are the cell's), for one described
+    chip: the v5e compiler takes the sorted top-k, the per-query row gather
+    from the page pool, the ring's wrapped page indices and the grouped
+    matmuls over the held experts (XLA's ragged dot, a Mosaic kernel of the
+    compiler's own), and both programs fit the chip."""
+    import json
+
+    from tpukit.model import latent
+    from tpukit.serve import decode
+
+    with open(os.path.join(REPO, "benchmark", "configs", "dots3-note-prev.json")) as f:
+        cfg = latent.config_from_hf(json.load(f))
+    one = SingleDeviceSharding(v5e[0])
+    on = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    n, page, per_slot, chunk = 8, 16, 128, 128
+    kinds = latent.page_kinds(cfg, page, "bf16")
+    pages = {k.table: n * k.pages_for(page * per_slot, page) + 1 for k in kinds}
+    params = on(jax.eval_shape(lambda: latent.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on(jax.eval_shape(lambda: latent.init_paged_cache(cfg, pages, page, per_slot, n, "bf16")))
+    state = (sds((n, page * per_slot), I32), cache, sds((n,), I32), sds((n,), jnp.bool_), sds((n,), I32),
+             sds((n, 2), jnp.uint32))
+    programs = {
+        "decode": decode.decode_step.lower(params, cfg, *state, 0, 0.0, 0, None, steps=2),
+        "prefill": decode.prefill_chunk_paged.lower(
+            params, cfg, *state, sds((2,), I32), sds((2, chunk), I32), sds((2,), I32), sds((2,), jnp.bool_),
+            sds((2,), I32), sds((2,), I32), sds((2, 2), jnp.uint32)),
+    }
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        assert "ragged-dot" in text, name  # the grouped matmul is the compiler's kernel, not a dense expansion
+        assert " sort(" in text and "gather(" in text, name
+        m = compiled.memory_analysis()
+        total = m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+        assert m.argument_size_in_bytes > 6e9 and total < 15.75e9, (name, total)  # the weights are real, and it fits
+
+
 @pytest.mark.parametrize("name,lower,expect,scope_of", [
     ("train_step", _scoped_train_step,
      {"flash_fwd": 2, "flash_bwd": 2, "head_ce_fwd": 1, "head_ce_bwd": 1},

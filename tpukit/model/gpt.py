@@ -819,6 +819,77 @@ def forward_cached(params: Params, cfg: GPTConfig, input_ids, position_ids,
     return apply_head(params, cfg, x), cache
 
 
+# --------------------------------------------------------------------------
+# What a block family offers the serving stack (`tpukit.model.family(cfg)`;
+# tpukit/model/latent.py offers the same names): the serve programs, the
+# engine and the samplers reach the model through these and through
+# `init_params` / `forward` / `forward_cached` / `init_kv_cache` above.
+# --------------------------------------------------------------------------
+
+
+def max_context(cfg: GPTConfig) -> int:
+    """The longest context the model can be served at: its learned position
+    table. Beyond it position lookups silently clamp instead of erroring."""
+    return cfg.max_position_embeddings
+
+
+def kv_heads(cfg: GPTConfig) -> int:
+    """Heads the cache keeps K and V rows for: what a serving mesh's `model`
+    axis has to divide to shard the cache over it."""
+    return cfg.heads
+
+
+def cached_decode_exact(cfg: GPTConfig) -> bool:
+    """True when the KV-cached decode is token-for-token the full-reforward
+    decode. Dense models always are (causality). MoE models route each
+    cached chunk with its own capacity window, so the buffer dispatches
+    ("xla"/"a2a") can drop different tokens cached vs uncached — EXCEPT
+    dropless "pallas" (no capacity override): per-token routing there is
+    chunk-composition-independent and nothing is ever dropped (round 14;
+    equivalence tested in tests/test_serve.py, rationale at
+    `_apply_moe_ffn`)."""
+    return cfg.num_experts == 0 or (
+        cfg.moe_dispatch == "pallas" and cfg.moe_capacity == 0
+    )
+
+
+def page_kinds(cfg: GPTConfig, page_size: int, kv_dtype: str):
+    """One kind of page: K and V rows of every head, every layer behind the
+    one block table `bt` (`serve.paged.gpt_page_kinds`)."""
+    from tpukit.serve import paged as paged_lib  # lazy: tpukit.serve imports gpt
+
+    paged_lib.validate_kv_layout(cfg, page_size, kv_dtype)
+    return paged_lib.gpt_page_kinds(cfg, page_size, kv_dtype)
+
+
+def init_paged_cache(cfg: GPTConfig, num_pages, page_size: int, pages_per_slot: int,
+                     slots: int, kv_dtype: str = "f32") -> dict:
+    """The paged-cache pytree of `serve.paged.init_paged_cache`."""
+    from tpukit.serve import paged as paged_lib
+
+    if isinstance(num_pages, dict):
+        num_pages = num_pages["bt"]
+    return paged_lib.init_paged_cache(cfg, num_pages, page_size, pages_per_slot, slots, kv_dtype)
+
+
+def select_lanes(cache: dict, slots, prompt_lens) -> dict:
+    """The admit batch's view of the paged cache for one prefill chunk: the
+    pools whole, the block table cut to the batch's lanes."""
+    return dict(cache, bt=cache["bt"][slots])
+
+
+def merge_lanes(cache: dict, sub: dict) -> dict:
+    """The whole cache again after the chunk: the pools carry the writes,
+    the global block tables are kept."""
+    return dict(sub, bt=cache["bt"])
+
+
+def counters(cache: dict) -> tuple:
+    """Device counters the cache carries for the engine to fetch with the
+    cursors, as `(names, arrays)`: this block keeps none."""
+    return (), ()
+
+
 def forward_hidden(
     params: Params,
     cfg: GPTConfig,

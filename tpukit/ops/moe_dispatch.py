@@ -390,3 +390,64 @@ def expected_a2a(cfg, data_size: int, expert_size: int, global_batch: int,
         "train": entry(cfg.compute_dtype, train_ops),
         "eval": entry(jnp.bfloat16, 2),
     }
+
+
+# --------------------------------------------------------------------------
+# One chip's share of an expert layer (the latent family, tpukit/model/
+# latent.py): the router keeps its published width and scores every expert;
+# this chip holds a contiguous range of them and computes their part of the
+# result for the rows routed to them. No exchange, no capacity, nothing
+# dropped, and nothing stands in for the experts that live elsewhere.
+# --------------------------------------------------------------------------
+
+
+@jax.named_scope("router")
+def sigmoid_topk_route(x, router_kernel, select_bias, top_k: int):
+    """Sigmoid scores over ALL experts, the `top_k` of largest `score + bias`
+    (the bias steers the choice only, `noaux_tc`), gates = the chosen scores
+    normalised over the chosen (`norm_topk_prob`), whoever holds them.
+    x `[T, D]`; returns `(idx [T, k] int32, gates [T, k] float32)`. Scores are
+    float32 at full matmul precision: a bf16 pass reorders near-ties at the
+    k-th place."""
+    logits = jnp.matmul(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx.astype(jnp.int32), chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+
+
+@jax.named_scope("experts")
+def held_experts_ffn(x, idx, gates, experts, expert_lo: int, compute_dtype, row_mask=None):
+    """`sum over chosen AND held experts of gate x E(x)` for `x [T, D]`, with
+    `E(x) = (silu(x Wg) * x Wu) Wd` and `experts = {gate, up [E, D, F], down
+    [E, F, D]}` the held range `[expert_lo, expert_lo + E)`. Dropless: the
+    (row, choice) pairs are sorted by held expert (pairs of absent experts
+    last) and the three products run as grouped matmuls (`lax.ragged_dot`)
+    over exactly the rows each expert was given; the static row count is the
+    worst case `T x k`, the computed one is what was routed here. Rows with
+    `row_mask` False (a frozen decode lane) are given to no expert.
+
+    Returns `(y [T, D] float32, rows [E] int32)`, `rows[e]` the rows expert
+    `expert_lo + e` computed."""
+    t, d = x.shape
+    k = idx.shape[1]
+    e = experts["gate"].shape[0]
+    local = idx - expert_lo
+    held = (local >= 0) & (local < e)
+    if row_mask is not None:
+        held = held & row_mask[:, None]
+    key = jnp.where(held, local, e).reshape(-1)  # [T*k]; `e` sorts the rest last
+    order = jnp.argsort(key, stable=True)
+    rows = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
+    xs = x.astype(compute_dtype)[order // k]  # [T*k, D], sorted by held expert
+    dot = lambda a, w, out: jax.lax.ragged_dot(  # noqa: E731
+        a, w.astype(compute_dtype), rows, preferred_element_type=out)
+    act = (jax.nn.silu(dot(xs, experts["gate"], jnp.float32))
+           * dot(xs, experts["up"], jnp.float32)).astype(compute_dtype)
+    out = dot(act, experts["down"], compute_dtype)  # accumulated in float32, rounded once on the way out
+    # rows past the routed ones belong to no group: whatever the kernel left there is dropped
+    routed = (jnp.arange(t * k) < jnp.sum(rows))[:, None]
+    out = jnp.where(routed, out * gates.reshape(-1)[order][:, None], 0.0).astype(compute_dtype)
+    back = jnp.zeros((t * k,), jnp.int32).at[order].set(jnp.arange(t * k, dtype=jnp.int32))
+    return out[back].reshape(t, k, d).astype(jnp.float32).sum(axis=1), rows

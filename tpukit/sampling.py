@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpukit.model import gpt
+from tpukit.model import family
 
 
 @partial(
@@ -49,7 +49,7 @@ from tpukit.model import gpt
     static_argnames=("cfg", "prompt_len", "max_new_tokens", "eos_id", "temperature", "top_k"),
 )
 def _decode_loop(
-    params, cfg: gpt.GPTConfig, buf, prompt_len: int, max_new_tokens: int,
+    params, cfg, buf, prompt_len: int, max_new_tokens: int,
     eos_id: int, temperature: float = 0.0, top_k: int = 0, rng=None,
 ):
     """Returns (buf, final_length). buf: [1, prompt_len + max_new_tokens].
@@ -68,7 +68,7 @@ def _decode_loop(
 
     def body(carry):
         buf, cur, _ = carry
-        logits = gpt.forward(params, cfg, buf, position_ids)
+        logits = family(cfg).forward(params, cfg, buf, position_ids)
         last = logits[0, cur - 1].astype(jnp.float32)
         next_token = _sample_next(last, cur, rng, temperature, top_k).astype(buf.dtype)
         done = next_token == eos_id
@@ -87,7 +87,7 @@ def _decode_loop(
     static_argnames=("cfg", "prompt_len", "max_new_tokens", "eos_id", "temperature", "top_k"),
 )
 def _decode_loop_cached(
-    params, cfg: gpt.GPTConfig, buf, prompt_len: int, max_new_tokens: int,
+    params, cfg, buf, prompt_len: int, max_new_tokens: int,
     eos_id: int, temperature: float = 0.0, top_k: int = 0, rng=None,
 ):
     """KV-cached twin of `_decode_loop`: the prompt is prefilled once, then
@@ -104,11 +104,12 @@ def _decode_loop_cached(
     same-seed equivalence tests/test_sampling.py asserts. The static
     temperature==0 branch keeps the greedy decode trace byte-unchanged."""
     total = buf.shape[1]
-    cache = gpt.init_kv_cache(cfg, 1, total)
+    model = family(cfg)
+    cache = model.init_kv_cache(cfg, 1, total)
     if prompt_len > 1:
         ids = buf[:, : prompt_len - 1]
         pos = jnp.arange(prompt_len - 1, dtype=jnp.int32)[None, :]
-        _, cache = gpt.forward_cached(params, cfg, ids, pos, cache, 0)
+        _, cache = model.forward_cached(params, cfg, ids, pos, cache, 0)
 
     def cond(carry):
         _, _, cur, done = carry
@@ -118,7 +119,7 @@ def _decode_loop_cached(
         buf, cache, cur, _ = carry
         tok = jax.lax.dynamic_slice(buf, (0, cur - 1), (1, 1))
         pos = jnp.reshape(cur - 1, (1, 1)).astype(jnp.int32)
-        logits, cache = gpt.forward_cached(params, cfg, tok, pos, cache, cur - 1)
+        logits, cache = model.forward_cached(params, cfg, tok, pos, cache, cur - 1)
         last = logits[0, -1].astype(jnp.float32)
         next_token = _sample_next(last, cur, rng, temperature, top_k).astype(buf.dtype)
         done = next_token == eos_id
@@ -163,18 +164,12 @@ def _sample_next(last, cur, rng, temperature: float = 0.0, top_k: int = 0):
     return jnp.argmax(last, axis=-1)
 
 
-def _cached_decode_exact(cfg: gpt.GPTConfig) -> bool:
+def _cached_decode_exact(cfg) -> bool:
     """True when the KV-cached decode is token-for-token the full-reforward
-    decode. Dense models always are (causality — module docstring). MoE
-    models route each cached chunk with its own capacity window, so the
-    buffer dispatches ("xla"/"a2a") can drop different tokens cached vs
-    uncached — EXCEPT dropless "pallas" (no capacity override): per-token
-    routing there is chunk-composition-independent and nothing is ever
-    dropped, so cached decode is exact (round 14; equivalence tested in
-    tests/test_serve.py, rationale at gpt._apply_moe_ffn)."""
-    return cfg.num_experts == 0 or (
-        cfg.moe_dispatch == "pallas" and cfg.moe_capacity == 0
-    )
+    decode: the model's own statement (`cached_decode_exact` of its family —
+    dense GPT models and dropless expert layers are, capacity'd buffer
+    dispatches are not; rationale at gpt.cached_decode_exact)."""
+    return family(cfg).cached_decode_exact(cfg)
 
 
 def _replicate_like(params, buf):
@@ -201,7 +196,7 @@ def _replicate_like(params, buf):
 
 def generate(
     params,
-    cfg: gpt.GPTConfig,
+    cfg,
     prompt: str,
     tokenizer,
     max_new_tokens: int = 20,
@@ -217,11 +212,12 @@ def generate(
     # at the position-embedding table so the whole buffer (prompt + new
     # tokens) stays in-range — beyond it, position lookups would silently
     # clamp to the last learned position instead of erroring.
-    max_prompt = min(256, cfg.max_position_embeddings - max_new_tokens)
+    max_prompt = min(256, family(cfg).max_context(cfg) - max_new_tokens)
     if max_prompt < 1:
         raise ValueError(
             f"max_new_tokens={max_new_tokens} leaves no room for a prompt "
-            f"within max_position_embeddings={cfg.max_position_embeddings}"
+            f"within the model's longest context "
+            f"({family(cfg).max_context(cfg)})"
         )
     encoded = tokenizer([prompt], truncation=True, max_length=max_prompt)
     ids = np.asarray(encoded["input_ids"][0], dtype=np.int32)
@@ -270,7 +266,7 @@ def generate(
 
 def generate_batch(
     params,
-    cfg: gpt.GPTConfig,
+    cfg,
     prompts: list[str],
     tokenizer,
     max_new_tokens: int = 20,
@@ -297,11 +293,12 @@ def generate_batch(
     (dense, or dropless-pallas MoE — gpt._apply_moe_ffn docstring)."""
     if not prompts:
         return []
-    max_prompt = min(256, cfg.max_position_embeddings - max_new_tokens)
+    max_prompt = min(256, family(cfg).max_context(cfg) - max_new_tokens)
     if max_prompt < 1:
         raise ValueError(
             f"max_new_tokens={max_new_tokens} leaves no room for a prompt "
-            f"within max_position_embeddings={cfg.max_position_embeddings}"
+            f"within the model's longest context "
+            f"({family(cfg).max_context(cfg)})"
         )
     encoded = tokenizer(list(prompts), truncation=True, max_length=max_prompt)
     ids = [np.asarray(row, dtype=np.int32) for row in encoded["input_ids"]]

@@ -4,7 +4,7 @@ Round 14 (ROADMAP #1): the device half of `tpukit/serve`. Three jitted
 programs generalize the single-sequence cached decode of
 `tpukit/sampling.py` from batch=1 to `[N_slots, W]` with PER-SLOT state —
 cursors, EOS/limit flags, rng keys — over one preallocated per-slot KV
-ring (`gpt.init_kv_cache(cfg, slots, max_len)`):
+ring (the model's `init_kv_cache(cfg, slots, max_len)`):
 
   - `prefill_slots`: write an admit-batch of (bucket-padded) prompts
     into their slots' token-buffer rows and K/V ring rows in ONE
@@ -16,7 +16,7 @@ ring (`gpt.init_kv_cache(cfg, slots, max_len)`):
     (`ServeConfig.compile_budget`).
   - `decode_step`: ONE token for every slot — each slot forwards the
     token at its own cursor (a per-row `start` vector through
-    `gpt.forward_cached`), samples with its own key fold, and appends
+    the model's `forward_cached`), samples with its own key fold, and appends
     unless it hit EOS or its length limit. One compile total, any slot
     occupancy. The "decode" phase; the host scheduler interleaves
     prefills between steps without ever stalling active slots.
@@ -50,6 +50,13 @@ one all-gather per step at a known size — so the per-step collectives
 have a closed form (`decode_step_comm`) the compiled HLO must match
 (the round-10/12 audit discipline, tests/test_serve.py).
 
+The model behind the programs: every call into the model goes through
+`tpukit.model.family(cfg)` — the module that implements the config's block
+family and offers `forward_cached`, `init_kv_cache`, `select_lanes` and
+`merge_lanes` — so the GPT block and the latent family (tpukit/model/
+latent.py) run under the same three programs. Nothing here names a model's
+function or reads a model's sizes.
+
 Paged KV (round 15, tpukit/serve/paged.py): when the cache pytree
 carries block tables (`"bt"`), the same programs run against the page
 pool — `decode_step` threads the live-slot mask into the pool
@@ -69,7 +76,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from tpukit.model import gpt
+from tpukit.model import family
 
 
 @jax.named_scope("sample")
@@ -106,12 +113,12 @@ def _advance(params, cfg, buf, cache, cursors, active, limits, keys,
         # cursor-0 write would corrupt its own first page. `write_mask`
         # routes masked rows to the null page; the ring path needs no mask
         # because each slot exclusively owns its full-width ring rows.
-        logits, cache = gpt.forward_cached(
+        logits, cache = family(cfg).forward_cached(
             params, cfg, tok, read[:, None].astype(jnp.int32), cache, read,
             write_mask=active, mesh=mesh,
         )
     else:
-        logits, cache = gpt.forward_cached(
+        logits, cache = family(cfg).forward_cached(
             params, cfg, tok, read[:, None].astype(jnp.int32), cache, read
         )
     last = logits[:, -1].astype(jnp.float32)
@@ -154,7 +161,7 @@ def _advance(params, cfg, buf, cache, cursors, active, limits, keys,
     jax.jit,
     static_argnames=("cfg", "eos_id", "temperature", "top_k", "mesh", "steps"),
 )
-def decode_step(params, cfg: gpt.GPTConfig, buf, cache, cursors, active,
+def decode_step(params, cfg, buf, cache, cursors, active,
                 limits, keys, eos_id: int, temperature: float = 0.0,
                 top_k: int = 0, mesh=None, steps: int = 1):
     """`steps` tokens for every slot (default 1). buf `[N, W]`, cache the
@@ -195,7 +202,7 @@ def decode_step(params, cfg: gpt.GPTConfig, buf, cache, cursors, active,
     static_argnames=("cfg",),
 )
 @jax.named_scope("prefill")
-def prefill_slots(params, cfg: gpt.GPTConfig, buf, cache, cursors, active,
+def prefill_slots(params, cfg, buf, cache, cursors, active,
                   limits, keys, slots, rows, prompt_lens, new_limits, new_keys):
     """Admit `A` requests in ONE dispatch: write their bucket-padded
     prompts `rows [A, bucket]` into the token buffer at `slots [A]` and
@@ -216,8 +223,9 @@ def prefill_slots(params, cfg: gpt.GPTConfig, buf, cache, cursors, active,
     what lets the scheduler admit mid-decode without stalling anyone."""
     a, bucket = rows.shape
     pos = jnp.broadcast_to(jnp.arange(bucket, dtype=jnp.int32), rows.shape)
-    scratch = gpt.init_kv_cache(cfg, a, bucket)
-    _, scratch = gpt.forward_cached(params, cfg, rows, pos, scratch, 0)
+    model = family(cfg)
+    scratch = model.init_kv_cache(cfg, a, bucket)
+    _, scratch = model.forward_cached(params, cfg, rows, pos, scratch, 0)
     for i in range(a):  # A is static and small (<= slots): unrolled writes
         buf = jax.lax.dynamic_update_slice(
             buf, rows[i : i + 1].astype(buf.dtype), (slots[i], 0)
@@ -240,7 +248,7 @@ def prefill_slots(params, cfg: gpt.GPTConfig, buf, cache, cursors, active,
 # No donation — see the decode_step note.
 @partial(jax.jit, static_argnames=("cfg",))
 @jax.named_scope("prefill")
-def prefill_chunk_paged(params, cfg: gpt.GPTConfig, buf, cache, cursors,
+def prefill_chunk_paged(params, cfg, buf, cache, cursors,
                         active, limits, keys, slots, rows, starts, is_last,
                         prompt_lens, new_limits, new_keys):
     """One CHUNKED-PREFILL dispatch against the paged cache (round 15):
@@ -263,11 +271,11 @@ def prefill_chunk_paged(params, cfg: gpt.GPTConfig, buf, cache, cursors,
     and lane state with the same values), so compiles stay bounded by the
     power-of-two admit sizes — one program per (A, C) pair."""
     a, c = rows.shape
-    bt = cache["bt"]
-    sub = dict(cache, bt=bt[slots])  # the A lanes' block-table rows
+    model = family(cfg)
+    sub = model.select_lanes(cache, slots, prompt_lens)  # the A lanes' block-table rows
     pos = starts[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
-    _, sub = gpt.forward_cached(params, cfg, rows, pos, sub, starts)
-    cache = dict(sub, bt=bt)  # pools carry the writes; global tables kept
+    _, sub = model.forward_cached(params, cfg, rows, pos, sub, starts)
+    cache = model.merge_lanes(cache, sub)  # pools carry the writes; global tables kept
     for i in range(a):  # A is static and small: unrolled lane updates
         buf = jax.lax.dynamic_update_slice(
             buf, rows[i : i + 1].astype(buf.dtype), (slots[i], starts[i])
@@ -307,7 +315,7 @@ def adopt_slot(buf, cursors, active, limits, keys, slot, row, prompt_len,
     jax.jit,
     static_argnames=("cfg", "max_new_tokens", "eos_id", "temperature", "top_k"),
 )
-def decode_loop(params, cfg: gpt.GPTConfig, buf, prompt_lens,
+def decode_loop(params, cfg, buf, prompt_lens,
                 max_new_tokens: int, eos_id: int, temperature: float = 0.0,
                 top_k: int = 0, rng=None):
     """Fused whole-batch cached decode: prefill the full `[N, W]` buffer
@@ -322,9 +330,10 @@ def decode_loop(params, cfg: gpt.GPTConfig, buf, prompt_lens,
     serial cached decode for every row; see the module docstring for why
     the full-width prefill's pad-position K/V garbage is never read."""
     n, total = buf.shape
-    cache = gpt.init_kv_cache(cfg, n, total)
+    model = family(cfg)
+    cache = model.init_kv_cache(cfg, n, total)
     pos = jnp.broadcast_to(jnp.arange(total, dtype=jnp.int32), buf.shape)
-    _, cache = gpt.forward_cached(params, cfg, buf, pos, cache, 0)
+    _, cache = model.forward_cached(params, cfg, buf, pos, cache, 0)
     cursors = prompt_lens.astype(jnp.int32)
     limits = jnp.minimum(cursors + max_new_tokens, total)
     active = cursors < limits
@@ -353,7 +362,7 @@ def decode_loop(params, cfg: gpt.GPTConfig, buf, prompt_lens,
     jax.jit,
     static_argnames=("cfg", "eos_id", "temperature", "top_k", "mesh"),
 )
-def decode_loop_window(params, cfg: gpt.GPTConfig, buf, cache, cursors,
+def decode_loop_window(params, cfg, buf, cache, cursors,
                        active, limits, keys, pages_held, max_ticks,
                        stop_when_freed, eos_id: int,
                        temperature: float = 0.0, top_k: int = 0, mesh=None):
@@ -413,7 +422,7 @@ def decode_loop_window(params, cfg: gpt.GPTConfig, buf, cache, cursors,
     )
 
 
-def decode_step_comm(cfg: gpt.GPTConfig, mesh, slots: int, top_k: int = 0,
+def decode_step_comm(cfg, mesh, slots: int, top_k: int = 0,
                      paged: bool = False, verify_tokens: int = 1) -> dict:
     """Closed-form PER-DEVICE collective expectation for one compiled
     `decode_step` under a (data x model) serving mesh — the round-10/12
@@ -494,9 +503,10 @@ def decode_step_comm(cfg: gpt.GPTConfig, mesh, slots: int, top_k: int = 0,
             f"slots={slots} must be a multiple of the data axis ({d}) — "
             f"slots shard over it"
         )
-    if m > 1 and cfg.heads % m:
+    heads = family(cfg).kv_heads(cfg)
+    if m > 1 and heads % m:
         raise ValueError(
-            f"heads={cfg.heads} must divide the model axis ({m}) for the "
+            f"heads={heads} must divide the model axis ({m}) for the "
             f"closed-form decode audit — undividable heads leave the KV "
             f"ring unsharded and GSPMD inserts resharding this formula "
             f"does not model"

@@ -6,7 +6,7 @@ everything around them — admission, eviction, the request stream, and
 the serving telemetry — in the shape real TPU serving engines take:
 
   - A **slot ring**: `slots` decode lanes over one preallocated KV ring
-    (`gpt.init_kv_cache(cfg, slots, width)`). A free-list (ring order)
+    (the model's `init_kv_cache(cfg, slots, width)`). A free-list (ring order)
     assigns arriving requests to lanes; eviction on EOS/length returns
     the lane, and the next prefill alone makes it safe to reuse (stale
     cache garbage above the new cursor is never attended — decode.py).
@@ -52,7 +52,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from tpukit.model import gpt
+from tpukit.model import family
 from tpukit.obs import SpanTimeline
 from tpukit.obs import metrics as metrics_lib
 from tpukit.obs import trace as trace_lib
@@ -398,6 +398,9 @@ class _Lane:
     # lane is decoding once it reaches `prefill_end`), and when decode
     # became ready.
     pages: list[int] = dataclasses.field(default_factory=list)
+    # pages of the model's further page kinds, by block-table key (a window
+    # layer's ring): allocated with `pages`, released with them
+    more_pages: dict = dataclasses.field(default_factory=dict)
     shared: int = 0
     next_chunk: int = 0
     prefill_end: int = 0
@@ -426,20 +429,24 @@ class ServeEngine:
     trainer's StepLogger / FlightRecorder — pass None for silent runs.
     """
 
-    def __init__(self, params, cfg: gpt.GPTConfig, serve: ServeConfig,
+    def __init__(self, params, cfg, serve: ServeConfig,
                  eos_id: int, mesh=None, logger=None, recorder=None,
                  draft_params=None, draft_cfg=None, replica=None,
                  tracer=None, metrics=None, slo=None, metrics_dir=None):
-        if serve.kv_width > cfg.max_position_embeddings:
+        # the model is reached through what its family offers
+        # (tpukit/model/__init__.py); nothing below names a model's
+        # functions or sizes
+        model = self._model = family(cfg)
+        if serve.kv_width > model.max_context(cfg):
             raise ValueError(
                 f"KV ring width {serve.kv_width} (max bucket "
                 f"{max(serve.buckets)} + max_new_tokens "
                 f"{serve.max_new_tokens}"
                 + (f" + spec_k {serve.spec_k} verify scratch"
                    if serve.draft else "")
-                + f") exceeds the position table "
-                f"({cfg.max_position_embeddings}) — beyond it position "
-                f"lookups silently clamp instead of erroring"
+                + f") exceeds the model's longest context "
+                f"({model.max_context(cfg)}): a learned position table "
+                f"would silently clamp beyond it instead of erroring"
             )
         if serve.draft == "model":
             if draft_params is None or draft_cfg is None:
@@ -465,10 +472,10 @@ class ServeEngine:
                     f"correction compares their distributions token id "
                     f"by token id"
                 )
-            if serve.kv_width > draft_cfg.max_position_embeddings:
+            if serve.kv_width > family(draft_cfg).max_context(draft_cfg):
                 raise ValueError(
-                    f"draft model position table "
-                    f"({draft_cfg.max_position_embeddings}) is smaller "
+                    f"draft model's longest context (its position table, "
+                    f"{family(draft_cfg).max_context(draft_cfg)}) is smaller "
                     f"than the KV ring width {serve.kv_width} — the "
                     f"draft decodes the same positions the target serves"
                 )
@@ -536,8 +543,19 @@ class ServeEngine:
         if serve.paged:
             from tpukit.serve import paged as paged_lib
 
-            # named at construction, not an XLA shape error at first write
-            paged_lib.validate_kv_layout(cfg, serve.page_size, serve.kv_dtype)
+            # the model's own statement of the pages it keeps (a layout it
+            # cannot store is named here, at construction, not by an XLA
+            # shape error at the first write)
+            kinds = model.page_kinds(cfg, serve.page_size, serve.kv_dtype)
+            for kind in kinds:
+                if kind.ring_pages and serve.chunk > kind.ring_pages * serve.page_size:
+                    raise ValueError(
+                        f"prefill_chunk={serve.chunk} is more than the "
+                        f"{kind.ring_pages}-page ring of page kind "
+                        f"{kind.table!r} holds ({kind.ring_pages} x "
+                        f"{serve.page_size} tokens): a chunk's pages would "
+                        f"overwrite each other"
+                    )
         if mesh is not None:
             from tpukit.mesh import place_host_array
 
@@ -565,7 +583,7 @@ class ServeEngine:
                     f"({d}) — slots shard over it"
                 )
             m = mesh.shape.get("model", 1)
-            heads_ax = "model" if (m > 1 and cfg.heads % m == 0) else None
+            heads_ax = "model" if (m > 1 and model.kv_heads(cfg) % m == 0) else None
             batch_ax = "data" if d > 1 else None
             # place_host_array: multi-host safe (every process calls with
             # the same value; single-process is a plain device_put)
@@ -587,26 +605,44 @@ class ServeEngine:
 
         self.buf = place(np.zeros((n, w), np.int32), P(*slot_spec, None))
         if serve.paged:
+            # one pool, one allocator and one host block table per page
+            # kind. The first kind is the primary one: `num_pages` sizes
+            # it, the prefix registry and the fleet handoff speak of it
+            # (`self.allocator`, `self._bt`, `_Lane.pages`); any further
+            # kind gets the ring-equivalent pool, every slot's worst case
+            self._kinds = kinds
             self.num_pages = serve.num_pages or n * serve.pages_per_slot + 1
-            tree = paged_lib.init_paged_cache(
-                cfg, self.num_pages, serve.page_size, serve.pages_per_slot,
+            pool_pages = {
+                k.table: n * k.pages_for(serve.padded_width, serve.page_size) + 1
+                for k in kinds
+            }
+            pool_pages[kinds[0].table] = self.num_pages
+            tree = model.init_paged_cache(
+                cfg, pool_pages, serve.page_size, serve.pages_per_slot,
                 n, serve.kv_dtype,
             )
             specs = {"k": pool_spec, "v": pool_spec, "ks": scale_spec,
-                     "vs": scale_spec, "bt": P()}
-            self.cache = {key: place(val, specs[key]) for key, val in tree.items()}
-            self.allocator = paged_lib.PageAllocator(
-                self.num_pages, serve.page_size
-            )
+                     "vs": scale_spec}
+            self.cache = {key: place(val, specs.get(key, P()))
+                          for key, val in tree.items()}
+            self.allocators = {
+                k.table: paged_lib.PageAllocator(pool_pages[k.table], serve.page_size)
+                for k in kinds
+            }
+            self.allocator = self.allocators[kinds[0].table]
             self.kv_bytes = paged_lib.pool_bytes(
-                cfg, self.num_pages, serve.page_size, serve.kv_dtype
+                cfg, pool_pages, serve.page_size, serve.kv_dtype
             )
-            self._bt = np.zeros((n, serve.pages_per_slot), np.int32)
+            self._tables = {
+                k.table: np.zeros(tree[k.table].shape, np.int32) for k in kinds
+            }
+            self._bt = self._tables[kinds[0].table]
             self._bt_dirty = False
         else:
+            self._kinds = ()
             self.num_pages = 0
             self.allocator = None
-            ring = gpt.init_kv_cache(cfg, n, serve.kv_width)
+            ring = model.init_kv_cache(cfg, n, serve.kv_width)
             self.kv_bytes = sum(
                 int(np.prod(c.shape)) * c.dtype.itemsize for c in ring.values()
             )
@@ -620,7 +656,7 @@ class ServeEngine:
             # any head count legal whatever the model axis)
             self.draft_cache = jax.tree.map(
                 lambda c: place(c, P()),
-                gpt.init_kv_cache(draft_cfg, n, serve.kv_width),
+                family(draft_cfg).init_kv_cache(draft_cfg, n, serve.kv_width),
             )
         # spec telemetry (round 17): proposed/accepted draft tokens, the
         # appended-tokens-per-verify histogram (index 0..spec_k+1), and
@@ -653,6 +689,10 @@ class ServeEngine:
         # control plane, the compiled decode step is untouched
         self.stuck_rids: set[int] = set()
         self._gen_total = 0
+        # cumulative device counters the model's cache carries
+        # (`model.counters`), as of the last sync: a quantum reports the
+        # difference under the model's names for them
+        self._counters = 0
         self.last_summary: dict | None = None
         # per-window deltas
         self._win = dict(steps=0, gen0=0, admit0=0, comps0=0, hits0=0,
@@ -776,11 +816,27 @@ class ServeEngine:
         p, c = self.serve.page_size, self.serve.chunk
         limit = min(plen + req.max_new_tokens, self.serve.width)
         total = -(-limit // p)
-        matched = self.allocator.lookup_prefix(req.ids, (plen - 1) // p)
+        # a shared prefix skips its chunks' prefill, which only a model whose
+        # every layer keeps the whole context can do: a window layer's ring
+        # would miss the tokens before the suffix
+        matched = (self.allocator.lookup_prefix(req.ids, (plen - 1) // p)
+                   if len(self._kinds) == 1 else [])
         s_tokens = (len(matched) * p // c) * c
         shared = matched[: s_tokens // p]
         self.allocator.claim(shared)
         fresh = self.allocator.alloc(total - len(shared))
+        more = {}
+        for kind in self._kinds[1:]:
+            held = (None if fresh is None else
+                    self.allocators[kind.table].alloc(kind.pages_for(limit, p)))
+            if held is None:  # a pool cannot cover the request yet: give back what was taken
+                for table, taken in more.items():
+                    self.allocators[table].release(taken)
+                if fresh is not None:
+                    self.allocator.release(fresh)
+                fresh = None
+                break
+            more[kind.table] = held
         if fresh is None:
             self.allocator.release(shared)
             return False
@@ -788,6 +844,9 @@ class ServeEngine:
         pages = list(shared) + fresh
         self._bt[slot] = 0
         self._bt[slot, : len(pages)] = pages
+        for table, held in more.items():
+            self._tables[table][slot] = 0
+            self._tables[table][slot, : len(held)] = held
         self._bt_dirty = True
         # prefill only the chunks that hold prompt tokens — the ring
         # prefilled the whole bucket, but bucket-pad K/V is causally dead
@@ -797,8 +856,8 @@ class ServeEngine:
         # chunk (s_tokens <= ((plen-1)//p)*p < plen <= prefill_end).
         prefill_end = -(-plen // c) * c
         self._lanes[slot] = _Lane(
-            req, now, plen, bucket, pages=pages, shared=len(shared),
-            next_chunk=s_tokens, prefill_end=prefill_end, phase="prefill",
+            req, now, plen, bucket, pages=pages, more_pages=more,
+            shared=len(shared), next_chunk=s_tokens, prefill_end=prefill_end, phase="prefill",
             key=np.asarray(jax.random.PRNGKey(req.seed), np.uint32),
         )
         self.admitted += 1
@@ -872,7 +931,8 @@ class ServeEngine:
             if tr is not None:
                 tid = trace_id(lane.req)
                 tr.emit("prefill", tid, rid=lane.req.rid, t0=sp.t0, t1=sp.t1,
-                        chunk=start // c, replica=self.replica)
+                        chunk=start // c, replica=self.replica,
+                        tokens=min(start + c, lane.prompt_len) - start)
                 if is_last:
                     tr.emit("prefill_done", tid, rid=lane.req.rid, t=sp.t1,
                             replica=self.replica)
@@ -888,7 +948,8 @@ class ServeEngine:
         cached device array rides along unchanged through every jit."""
         if self._bt_dirty:
             with self.spans.span("place"):
-                self.cache["bt"] = self._place(self._bt, P())
+                for table, host in self._tables.items():
+                    self.cache[table] = self._place(host, P())
             self._bt_dirty = False
 
     def _open_quantum(self, t0: float, t1: float, steps: int) -> None:
@@ -906,6 +967,12 @@ class ServeEngine:
             pending=len(self._pending),
             free_pages=(self.allocator.available_pages
                         if self.serve.paged else None),
+            # what the decoding lanes have behind them (as of the last
+            # sync) and what the page pools hold for every live lane
+            ctx_tokens=sum(l.prompt_len + l.delivered for l in decoding),
+            kv_bytes=(sum(k.layers * k.page_bytes * self.allocators[k.table].live_pages
+                          for k in self._kinds)
+                      if self.serve.paged else self.kv_bytes),
         )
 
     def _step(self) -> None:
@@ -1063,8 +1130,16 @@ class ServeEngine:
             if self._quantum is not None:
                 self._quantum["steps"] = ran
         else:
-            cur, act = map(np.asarray,
-                           jax.device_get((self.cursors, self.active)))
+            # whatever counters the model keeps in its cache ride the same
+            # round trip (none for a model that keeps none)
+            names, counters = self._model.counters(self.cache)
+            cur, act, *counters = map(np.asarray, jax.device_get(
+                (self.cursors, self.active, *counters)))
+            if names:
+                seen = np.concatenate(counters).astype(np.int64)
+                if self._quantum is not None:
+                    self._quantum.update(zip(names, (seen - self._counters).tolist()))
+                self._counters = seen
         self._drain_spec()
         return cur, act
 
@@ -1148,10 +1223,18 @@ class ServeEngine:
             # their other readers — and zero the block-table row so any
             # stale in-flight write lands in the null page, never in a
             # re-issued one
-            self.allocator.release(lane.pages)
-            self._bt[slot] = 0
-            self._bt_dirty = True
+            self._release_pages(slot, lane)
         self._free.append(slot)
+
+    def _release_pages(self, slot: int, lane: _Lane) -> None:
+        """Hand back every page `lane` held, of every kind, and zero the
+        slot's block-table rows."""
+        self.allocator.release(lane.pages)
+        for table, held in lane.more_pages.items():
+            self.allocators[table].release(held)
+        for host in self._tables.values():
+            host[slot] = 0
+        self._bt_dirty = True
 
     def _evict_deadlines(self, now: float, fetched_s: float) -> int:
         """Retire decode-resident lanes whose end-to-end deadline_ms has
@@ -1650,9 +1733,7 @@ class ServeEngine:
         lands in the null page (write-safety invariant 2)."""
         lane = self._lanes.pop(slot)
         if self.serve.paged:
-            self.allocator.release(lane.pages)
-            self._bt[slot] = 0
-            self._bt_dirty = True
+            self._release_pages(slot, lane)
         self._free.append(slot)
 
     def adopt_prefilled(self, req: Request, pages: list[int], shared: int,
@@ -1675,6 +1756,12 @@ class ServeEngine:
             raise ValueError(
                 "adopt_prefilled requires the paged cache (page_size > 0) "
                 "— the disaggregated handoff rides page granularity"
+            )
+        if len(self._kinds) > 1:
+            raise NotImplementedError(
+                f"the prefill handoff copies one kind of page; "
+                f"{type(self.cfg).__name__} keeps "
+                f"{[k.table for k in self._kinds]}"
             )
         plen = len(req.ids)
         slot = self._free.popleft()

@@ -49,6 +49,17 @@ PagedAttention, PAPERS.md):
     mirroring the round-12 loss-trajectory gate; f32/bf16 page storage at
     the matching compute dtype stays token-for-token exact.
 
+  - **Pages by kind** (PR 27): what a page holds is the model's statement
+    (`PageKind`, from `tpukit.model.family(cfg).page_kinds`). The GPT block
+    keeps the one kind above. The latent family (tpukit/model/latent.py)
+    keeps ROW pools `[L, NP, P, W]` — one latent row and one indexer key a
+    token in its full layers, behind the same kind of block table — and a
+    second kind for its window layers whose table is a RING: a request
+    holds at most `ceil(window / P) + 1` of those pages, logical page `j`
+    at column `j % R`, so the page that fell behind the window is the next
+    one written. The engine keeps one pool, one `PageAllocator` and one
+    host block table per kind; `pool_bytes` counts them all.
+
 Write-safety invariants (everything here leans on them):
 
   1. A slot's WRITABLE pages are exclusively owned. Shared (registered)
@@ -147,16 +158,56 @@ def init_paged_cache(cfg, num_pages: int, page_size: int, pages_per_slot: int,
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt), "bt": bt}
 
 
-def pool_bytes(cfg, num_pages: int, page_size: int, kv_dtype: str) -> int:
-    """Closed-form HBM bytes of the K+V pools (the equal-HBM bench math:
-    int8 pays 1 byte per element plus the 4-byte-per-block f32 scale
-    sidecar, i.e. `packed_bytes` per (page, head) row)."""
+def pool_bytes(cfg, num_pages, page_size: int, kv_dtype: str) -> int:
+    """Closed-form HBM bytes of every page pool `cfg`'s model keeps, by its
+    own statement of its page kinds (`tpukit.model.family(cfg).page_kinds`):
+    layers x pages x page_size x bytes a token, summed over the kinds. For
+    the GPT block that is the K+V pools (the equal-HBM bench math: int8 pays
+    1 byte per element plus the 4-byte-per-block f32 scale sidecar, i.e.
+    `packed_bytes` per (page, head) row). `num_pages` is one count for a
+    model with one kind, else `{block-table key: pages}`."""
+    from tpukit.model import family  # lazy: tpukit.model imports this module's users
+
+    kinds = family(cfg).page_kinds(cfg, page_size, kv_dtype)
+    if not isinstance(num_pages, dict):
+        if len(kinds) != 1:
+            raise ValueError(
+                f"{type(cfg).__name__} keeps {len(kinds)} page kinds "
+                f"({[k.table for k in kinds]}): give pages per kind as a dict"
+            )
+        num_pages = {kinds[0].table: num_pages}
+    return sum(k.layers * num_pages[k.table] * k.page_bytes for k in kinds)
+
+
+@dataclasses.dataclass(frozen=True)
+class PageKind:
+    """One kind of page a model keeps: which block table addresses it, how
+    many layers share that table, what a page of ONE layer weighs, and how
+    many pages a request can ever hold of it. `ring_pages` 0: a request holds
+    a page for every `page_size` tokens of its worst case, logical page `j`
+    at column `j` of its block-table row. `ring_pages` R > 0 (a window
+    layer's store): it holds at most R, logical page `j` at column `j % R`,
+    so a page that has fallen behind the window is the next one written."""
+
+    table: str
+    layers: int
+    page_bytes: int
+    ring_pages: int = 0
+
+    def pages_for(self, tokens: int, page_size: int) -> int:
+        need = -(-tokens // page_size)
+        return min(need, self.ring_pages) if self.ring_pages else need
+
+
+def gpt_page_kinds(cfg, page_size: int, kv_dtype: str) -> tuple[PageKind, ...]:
+    """The GPT block's one kind: K and V rows of every head, every layer
+    behind the one block table."""
     per_head_row = page_size * cfg.head_dim
     if kv_dtype == "int8":
         row_bytes = quant_comm.packed_bytes(per_head_row)
     else:
         row_bytes = per_head_row * jnp.dtype(storage_dtype(kv_dtype)).itemsize
-    return 2 * cfg.num_layers * num_pages * cfg.heads * row_bytes
+    return (PageKind("bt", cfg.num_layers, 2 * cfg.heads * row_bytes),)
 
 
 # -- device-side page ops (called per layer from gpt.forward_cached, each on
@@ -260,6 +311,44 @@ def write_pages(pool, scales, layer, bt, start, vals, write_mask):
         pool.at[layer, pids].set(q.reshape(n * npg, h, p, d)),
         scales.at[layer, pids].set(s),
     )
+
+
+# -- row pools (the latent family, tpukit/model/latent.py) -------------------
+# A pool `[L, NP, P, W]` keeps ONE row of width W a token and layer (a latent
+# and its shared rope key, an indexer key) instead of K and V rows per head.
+# Same discipline as above: the stack is indexed `(layer, page)` where it
+# lies, page 0 is the null page, masked writes go there.
+
+
+def init_row_pool(layers: int, num_pages: int, page_size: int, width: int, kv_dtype: str):
+    return jnp.zeros((layers, num_pages, page_size, width), storage_dtype(kv_dtype))
+
+
+def logical_pages(bt, first, count: int, ring: int = 0):
+    """Page ids of logical pages `first[b] + [0, count)` of each row of `bt
+    [N, MP]`: column `j`, or `j % ring` of a ring (negative `j`, before the
+    request began, wraps to some column too: the caller masks those keys by
+    position). `[N, count]`."""
+    j = first[:, None] + jnp.arange(count, dtype=first.dtype)[None, :]
+    col = jnp.mod(j, ring) if ring else jnp.clip(j, 0, bt.shape[1] - 1)
+    return jnp.take_along_axis(bt, col, axis=1)
+
+
+@jax.named_scope("kv_write")
+def write_row(pool, layer, pids, off, val):
+    """Decode-tick write: `val [N, W]` at `(layer, pids[b], off[b])`, one
+    scatter on the stack. `pids` already routes masked rows to page 0."""
+    return pool.at[layer, pids, off, :].set(val.astype(pool.dtype))
+
+
+@jax.named_scope("kv_write")
+def write_row_pages(pool, layer, pids, vals):
+    """Prefill-chunk write: `vals [N, C, W]` as the whole pages `pids [N,
+    C // P]` of layer `layer`, one scatter of `N x C/P` page rows."""
+    n, c, w = vals.shape
+    p = pool.shape[2]
+    return pool.at[layer, pids.reshape(-1)].set(
+        vals.reshape(n * (c // p), p, w).astype(pool.dtype))
 
 
 # -- page handoff (round 19, disaggregated prefill) --------------------------

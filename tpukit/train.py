@@ -105,6 +105,14 @@ class TrainState(struct.PyTreeNode):
 
 
 def create_train_state(rng, cfg: gpt.GPTConfig, optimizer, strategy=None) -> TrainState:
+    if not isinstance(cfg, gpt.GPTConfig):
+        from tpukit.model import ServedOnlyError
+
+        raise ServedOnlyError(
+            f"{type(cfg).__name__}: this block family is served only "
+            f"(main-serve.py --model latent): it has no loss, no backward "
+            f"pass and no training kernels (ROADMAP R0-R3)"
+        )
     params = gpt.init_params(rng, cfg)
     if strategy is not None:
         # layout hook (e.g. Pipeline pads stacked layers to a stage multiple

@@ -491,6 +491,21 @@ def full_width_cfg(sz: Sizes):
     )
 
 
+def make_batch(rng, vocab: int, batch: int, seq: int):
+    """Seeded (model_batch, targets) in the trainer's input format."""
+    import numpy as np
+
+    ids = rng.randint(0, vocab, size=(batch, seq)).astype(np.int32)
+    model_batch = {
+        "input_ids": ids,
+        "position_ids": np.ascontiguousarray(
+            np.broadcast_to(np.arange(seq, dtype=np.int32), ids.shape)
+        ),
+        "mask": np.zeros_like(ids, dtype=bool),
+    }
+    return model_batch, np.roll(ids, -1, axis=1).astype(np.int32)
+
+
 def step_trajectory(sz: Sizes, strategy, compiled: bool, inspect=None,
                     expect=("flash_", "head_ce_")) -> dict:
     """`sz.steps` train steps on one seeded batch through create_train_state
@@ -500,7 +515,6 @@ def step_trajectory(sz: Sizes, strategy, compiled: bool, inspect=None,
     import jax
     import numpy as np
 
-    from tools.bench_ladder import make_batch
     from tpukit.obs.xla import kernel_calls
     from tpukit.train import create_train_state, make_optimizer, make_step_fns
 

@@ -21,7 +21,7 @@ Interleaved virtual stages (round 22): `--pipeline_schedule 1f1b
 --virtual_stages V` splits each device's layer block into V non-contiguous
 chunks (device d owns chunks d, d+S, ..., d+(V-1)S), shrinking the
 warm-up/cool-down bubble toward (S-1)/(M*V) at the same micro-batch count
-(bench.py `pipe_interleave` measures it). MoE rides along: `--num_experts 8
+(`tpukit/pipeline_schedule.py` counts it; no chip run has timed it). MoE rides along: `--num_experts 8
 --moe_dispatch pallas` runs the meshless dropless dispatch inside each
 stage's chunks — the buffer dispatches ('xla'/'a2a') need an expert mesh
 axis the pipeline does not carry and are rejected by name.

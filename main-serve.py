@@ -72,9 +72,8 @@ dispatch the round-20 traces measured per step is paid once per quantum
 instead of once per step; the host syncs only at window boundaries (or
 early, when EOS activity frees enough pages for the head-of-queue admit).
 Token streams are exactly those of the per-step engine (greedy and seeded
-sampling); bench.py's `decode_fused` record measures the window's
-amortization and `tools/report.py --min_decode_speedup` gates it. Needs
-the paged cache (`--page_size`).
+sampling); whether the window is faster than the per-step quantum is not
+measured on the chip (ROADMAP D16). Needs the paged cache (`--page_size`).
 
 Round 24 (tpukit/serve/ledger.py): CRASH-TOLERANT fleet serving. With
 `--fleet_dir` the request lifecycle is durable — write-ahead lease
@@ -154,9 +153,9 @@ def parse_serve_flags(argv=None):
     # checkpoint
     ap.add_argument("--checkpoint", type=str, default="",
                     help="path or 'latest'; empty serves fresh seeded params "
-                    "(smoke/bench mode)")
+                    "(smoke mode)")
     ap.add_argument("--seed", type=int, default=0)
-    # engine shape (shared with bench.py via tpukit.flags.add_serve_flags)
+    # engine shape (tpukit.flags.add_serve_flags)
     from tpukit.flags import add_fleet_flags, add_serve_flags
 
     add_serve_flags(ap)
@@ -189,7 +188,7 @@ def parse_serve_flags(argv=None):
                     "(no 'latest' — it would resolve the same shared "
                     "directory as --checkpoint latest); empty with "
                     "--draft model serves fresh seeded draft params "
-                    "(smoke/bench mode)")
+                    "(smoke mode)")
     ap.add_argument("--draft_dim", type=int, default=64)
     ap.add_argument("--draft_head_dim", type=int, default=16)
     ap.add_argument("--draft_heads", type=int, default=4)
@@ -345,7 +344,7 @@ def main(argv=None):
                   + (f", {skipped} B of opt state skipped" if skipped else "")
                   + (f"; cross-world: {mismatch}" if mismatch else "") + ")")
     else:
-        # smoke/bench mode: fresh seeded params directly at the shardings
+        # smoke mode: fresh seeded params directly at the shardings
         params = jax.jit(
             lambda r: init_fn(r).params, out_shardings=state_sharding.params
         )(jax.random.PRNGKey(flags.seed))

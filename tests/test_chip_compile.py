@@ -106,7 +106,7 @@ def _cases():
     v_pad = -(-VOCAB // 128) * 128
     qkv = ((b, HEADS, s, HEAD_DIM), BF16)
     head = [((n, DIM), BF16, P()), ((DIM, v_pad), BF16, P()), ((n,), I32, P())]
-    m, d, f, e = 128, 256, 1024, 8  # the bench's e8 bank, one small row block
+    m, d, f, e = 128, 256, 1024, 8  # an 8-expert bank, one small row block
     bank = [((m, d), BF16), ((e, d, f), BF16), ((e, f), BF16),
             ((e, f, d), BF16), ((e, d), BF16), ((e + 1,), I32)]
     pages, page, mp, slots = 129, 16, 16, 8
@@ -438,16 +438,3 @@ def test_fleet_workers_are_bound_one_per_chip():
     assert worker_chip_env(1, 2, 0) == {}  # no TPU on the host: nothing to bind
     with pytest.raises(ValueError, match="needs 2 chips, this host has 1"):
         worker_chip_env(0, 2, 1)
-
-
-def test_bench_reports_recorded_probe_errors():
-    import bench
-
-    clean = {"value": 1.0, "moe_error": None, "ladder": [{"shape": "a", "mfu": 0.4}]}
-    assert bench._recorded_errors(clean) == []
-    broken = {"long_context_error": "boom", "serving": {"error": "x"},
-              "ladder": [{"shape": "a"}, {"shape": "b", "error": "oom"}]}
-    assert bench._recorded_errors(broken) == [
-        "result.long_context_error", "result.serving.error",
-        "result.ladder[1].error",
-    ]

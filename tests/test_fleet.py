@@ -20,9 +20,7 @@ Contracts pinned here:
   - occupancy-driven autoscale grows under load and drains when idle,
     with parity throughout;
   - `kind="fleet"`/`fleet_summary` JSONL lands, `tools/report.py` renders
-    the "== fleet ==" section, and the `--min_fleet_tps` gate fails on
-    fleet-less logs, sub-threshold throughput, and exactly-once
-    violations.
+    the "== fleet ==" section.
 """
 
 import dataclasses
@@ -363,7 +361,7 @@ def test_fleet_autoscale_up_and_down(tok, cfg, params, host_params):
 
 
 # ---------------------------------------------------------------------------
-# Telemetry: fleet JSONL + report render + the --min_fleet_tps gate.
+# Telemetry: fleet JSONL + report render.
 # ---------------------------------------------------------------------------
 
 
@@ -411,19 +409,6 @@ def test_fleet_jsonl_and_report_gate(tok, cfg, host_params, tmp_path):
     assert "== fleet ==" in text
     assert "fleet tokens/s" in text and "re-queued" in text
     assert "per-replica occupancy" in text
-
-    ok, msg = report.check_min_fleet_tps(recs, 1.0)
-    assert ok, msg
-    ok, msg = report.check_min_fleet_tps(recs, 1e9)
-    assert not ok and "FAIL" in msg
-    # no fleet records at all -> fail, never a vacuous pass
-    ok, msg = report.check_min_fleet_tps(
-        [r for r in recs if r["kind"] != "fleet_summary"], 1.0)
-    assert not ok and "no fleet_summary" in msg
-    # an exactly-once violation fails the gate even above threshold
-    forged = [dict(s, duplicate_completions=1)]
-    ok, msg = report.check_min_fleet_tps(forged, 1.0)
-    assert not ok and "duplicate" in msg
 
 
 # ---------------------------------------------------------------------------

@@ -647,16 +647,6 @@ def test_generate_auto_cache_with_temperature_uses_cached_loop(
     assert isinstance(out, str)
 
 
-def test_time_windows_zero_warmup():
-    from tools.bench_ladder import time_windows
-
-    def step(state, b, t):
-        return state, np.float32(1.5)
-
-    times, _, last = time_windows(step, None, None, None, steps=2, windows=1, warmup=0)
-    assert len(times) == 1 and last == 1.5
-
-
 def test_moe_config_fails_loudly_from_direct_value_and_grad(tiny_config):
     """ADVICE r5 #1: the curated MoE ValueError (not a TypeError about
     aux_out) from direct strategy.value_and_grad calls."""

@@ -14,10 +14,8 @@ Four layers under test, one table of truth (tpukit/pipeline_schedule.py):
    dispatch inside stage chunks reproduces the per-micro Switch
    objective's loss AND grads exactly, top-1 and top-2, 1F1B and GPipe,
    while "xla"/"a2a" stay rejected by name;
-4. the plumbing — flags, comm plan (pipe_comm feeding train_comm_plan),
-   the param layout round-trip, and the report gate
-   (`--min_bubble_gain`, tools/report.py) that keeps the bench record
-   honest.
+4. the plumbing — flags, comm plan (pipe_comm feeding train_comm_plan)
+   and the param layout round-trip.
 """
 
 import jax
@@ -173,8 +171,7 @@ def test_flat_bubble_closed_form():
 
 def test_bubble_strictly_decreases_on_gate_grid():
     """The gate grid (S=4, M in {4,8,16}, V 1->2->4): interleaving must
-    strictly cut the idle-work fraction at every micro count — the exact
-    monotonicity `report.py --min_bubble_gain` enforces on bench logs."""
+    strictly cut the idle-work fraction at every micro count."""
     for m in (4, 8, 16):
         flat = flat_1f1b_bubble(4, m)
         b2 = build_schedule(4, 2, m).stats["bubble_frac"]
@@ -416,39 +413,3 @@ def test_pipe_comm_plan(cfg4):
     assert "all-to-all" not in moe2.pipe_comm(
         c2.replace(num_experts=4), global_batch=8, seq=SEQ
     )
-
-
-def _gain_records():
-    rungs = [
-        {"virtual_stages": 1, "bubble_frac": 0.43},
-        {"virtual_stages": 2, "bubble_frac": 0.16},
-        {"virtual_stages": 4, "bubble_frac": 0.09},
-    ]
-    return [{"pipe_interleave": {
-        "stages": 4, "bubble_table": bubble_table(4), "rungs": rungs,
-    }}]
-
-
-def test_min_bubble_gain_gate():
-    from tools.report import check_min_bubble_gain
-
-    ok, msg = check_min_bubble_gain(_gain_records(), 0.5)
-    assert ok, msg
-    # threshold above the real cut -> FAIL with the worst M named
-    ok, msg = check_min_bubble_gain(_gain_records(), 0.99)
-    assert not ok and "min relative bubble cut" in msg
-    # no record -> FAIL (anti-vacuous)
-    ok, msg = check_min_bubble_gain([{"kind": "metric"}], 0.1)
-    assert not ok and "no pipe_interleave record" in msg
-    # an errored timed rung fails even though the grid math is fine
-    recs = _gain_records()
-    recs[0]["pipe_interleave"]["rungs"].append(
-        {"virtual_stages": 4, "error": "XlaRuntimeError('boom')"}
-    )
-    ok, msg = check_min_bubble_gain(recs, 0.1)
-    assert not ok and "errored timed rung" in msg
-    # a non-monotone grid fails regardless of the headline cut
-    recs = _gain_records()
-    recs[0]["pipe_interleave"]["bubble_table"][1]["bubble_frac"] = 0.99
-    ok, msg = check_min_bubble_gain(recs, 0.1)
-    assert not ok and "strictly decreasing" in msg

@@ -226,12 +226,12 @@ def _backend_knows_pinned_host() -> bool:
 @pytest.mark.skipif(
     not _backend_knows_pinned_host(),
     reason="backend has no pinned_host memory space (jax < 0.5 CPU); the "
-    "real offload path runs in the TPU dryrun/bench",
+    "real offload path runs in the TPU dryrun",
 )
 def test_fsdp_offload_memory_kind_rule(cfg):
     """On TPU-like backends the offload shardings pin params to host memory;
     assert the rule by faking backend support (the real pinned_host path runs
-    in the TPU dryrun/bench)."""
+    in the TPU dryrun)."""
     strategy = FSDP(create_mesh({"data": 8}), cpu_offload=True)
     strategy._offload_supported = lambda: True
     opt = make_optimizer(1e-3)

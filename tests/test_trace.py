@@ -10,7 +10,7 @@ Contracts pinned here:
     from the trace alone (every trace has exactly one finish event);
   - tracing is an OBSERVER: output tokens are bit-identical with the
     tracer on vs off, and `TraceRecorder.emit` is cheap (bounded ring,
-    O(1) append — the <1% serving-overhead budget bench.py measures);
+    O(1) append — the serving-overhead budget);
   - the serve/fleet summaries carry per-phase p50/p99, trace_complete
     and the dispatch-vs-device split, and the window/summary wall split
     surfaces its residual as an explicit `other_s` >= 0;

@@ -109,7 +109,6 @@ SCAN_GLOBS = (
     "tpukit/**/*.py",
     "tools/*.py",
     "main-*.py",
-    "bench.py",
     "__graft_entry__.py",
 )
 

@@ -19,58 +19,41 @@ checkpoints), "preempt" (graceful SIGTERM/SIGINT checkpoint-and-exit),
 "retry" (transient host-I/O attempts absorbed by backoff), and "chaos"
 (the fault-injection audit trail). Round-10 expert parallelism adds an
 all-to-all dispatch audit line to the "xla" section (the strategy's
-closed-form payload vs the compiled HLO's) and renders bench.py's
-`moe_ep_comm` record when pointed at a bench JSON; round 11 renders the
-`moe_dispatch_ladder` record (xla vs a2a vs pallas at e8 top-1/top-2,
-active-FLOPs-normalized MFU — ROADMAP #3). Round 12 adds the quantized
+closed-form payload vs the compiled HLO's). Round 12 adds the quantized
 grad-collective audit line to the "xla" section (--comm_dtype: the
 closed-form compressed payload vs the compiled HLO, dtype-aware so it is
-exact on CPU too) and renders bench.py's `quant_comm` record with the
-bytes-on-the-wire headline. Round-13 elastic resize adds "resize"
+exact on CPU too). Round-13 elastic resize adds "resize"
 (reshard-on-restore: the topology change, bytes read, stale files swept)
-and "ckpt_prune" (--keep_checkpoints retention) to the recovery section,
-plus bench.py's `elastic_restore` record. Round-14 serving adds "serve"
-(per-window continuous-batching telemetry: tokens/s, slot occupancy,
-admit/evict counts, prefill/decode/sync wall split, latency percentiles)
-and "serve_summary" (whole-run serving headline) rendered as a
-"== serving ==" section, bench.py's `serving` record (continuous
-batching vs serial per-request decode on the same stream), and the
-`--min_serve_tps` CI gate. Round-17 speculative decoding adds the spec
-block on serve windows/summaries (acceptance rate, accepted-tokens
-histogram, draft/verify wall split) and the `--min_accept_rate` gate.
-Round-20 request tracing adds "trace_event"/"trace" rows (raw span events
-and per-request span trees — rendered in depth by tools/traceview.py),
-per-phase p50/p99 + dispatch-vs-device attribution on serve/fleet
-summaries, and the `--min_trace_complete` completeness-invariant gate.
-Round-21 fused decode adds bench.py's `decode_fused` record (a one-tick
-window against the per-step program, and the dispatch-amortization win,
-rendered separately) and the `--min_decode_speedup` gate on the
-amortization ratio, the number that transfers from CPU loopback.
-Round-22 metrics plane adds "slo" rows (per-window compliance +
-error-budget burn per `--slo` target) and "metrics" epilogues (compact
-per-series summaries from tpukit/obs/metrics.py), rendered as
-"== slo ==" / "== metrics ==" sections; `--compare baseline.jsonl`
-diffs two runs' metric summaries (per-histogram p50/p99 deltas plus the
-tokens/s headline); the `--min_slo_compliance` and
-`--max_regression_pct` gates CI them; bench.py's `metrics_overhead`
-record (pure-observer proof: token parity + <1% throughput) renders
-too. Round-25 interleaved pipelines add bench.py's `pipe_interleave`
-record (the tick-table bubble grid for V virtual stages per device plus
-wall cross-checks) and `pipe_moe` (pipeline x pallas-dispatch MoE loss
-parity), rendered as "== pipeline ==" sections and gated by
-`--min_bubble_gain` — the grid is deterministic schedule accounting, so
-the gate transfers from CPU. The accreted per-gate argparse/dispatch
-boilerplate is
-consolidated into the declarative GATES table below — one row per gate,
-checker functions unchanged. This tool needs NOTHING but
-the file — no jax import, so it runs anywhere the log was copied to.
+and "ckpt_prune" (--keep_checkpoints retention) to the recovery section.
+Round-14 serving adds "serve" (per-window continuous-batching telemetry:
+tokens/s, slot occupancy, admit/evict counts, prefill/decode/sync wall
+split, latency percentiles) and "serve_summary" (whole-run serving
+headline) rendered as a "== serving ==" section. Round-17 speculative
+decoding adds the spec block on serve windows/summaries (acceptance rate,
+accepted-tokens histogram, draft/verify wall split) and the
+`--min_accept_rate` gate. Round-20 request tracing adds
+"trace_event"/"trace" rows (raw span events and per-request span trees —
+rendered in depth by tools/traceview.py), per-phase p50/p99 +
+dispatch-vs-device attribution on serve/fleet summaries, and the
+`--min_trace_complete` completeness-invariant gate. Round-22 metrics
+plane adds "slo" rows (per-window compliance + error-budget burn per
+`--slo` target) and "metrics" epilogues (compact per-series summaries
+from tpukit/obs/metrics.py), rendered as "== slo ==" / "== metrics =="
+sections; `--compare baseline.jsonl` diffs two runs' metric summaries
+(per-histogram p50/p99 deltas plus the tokens/s headline); the
+`--min_slo_compliance` and `--max_regression_pct` gates CI them. Every
+section renders what `fit()`, `ServeEngine`, the fleet and the metrics
+plane write themselves; a rate printed here is the run's own wall clock
+on whatever machine wrote the log — the chip's rates are the benchmark's
+(`benchmark/run.py`, `PERF_LEDGER.jsonl`). The per-gate argparse/dispatch
+boilerplate is the declarative GATES table below — one row per gate.
+This tool needs NOTHING but the file — no jax import, so it runs anywhere
+the log was copied to.
 
 Usage: python tools/report.py run.jsonl [--min_goodput 0.8]
-                                        [--min_serve_tps 100]
                                         [--min_accept_rate 0.3]
+                                        [--max_deadline_miss_pct 0]
                                         [--min_trace_complete 1.0]
-                                        [--min_decode_speedup 1.0]
-                                        [--min_bubble_gain 0.5]
                                         [--min_slo_compliance 0.99]
                                         [--compare baseline.jsonl]
                                         [--max_regression_pct 10]
@@ -722,372 +705,6 @@ def summarize(records: list[dict]) -> str:
           + (f"hits {hits}  misses {misses}  "
              if hits is not None else "")
           + f"entries {r.get('entries', '-')} (+{r.get('new_entries', 0)} this run)")
-    # bench.py output is itself one JSON line, so `python tools/report.py
-    # bench.json` renders it too; the round-10 moe_ep_comm record is the
-    # EP dispatch audit (expected vs measured all-to-all, remat warnings).
-    for r in records:
-        moe = r.get("moe_ep_comm")
-        if not isinstance(moe, dict):
-            continue
-        w("== moe ep comm (bench) ==")
-        mesh = moe.get("mesh") or {}
-        w(f"  mesh {mesh}  dispatch {moe.get('dispatch', '?')}   "
-          f"tokens/sec/chip {human_count(moe.get('tokens_per_sec_per_chip'))}")
-        exp, meas = moe.get("expected_a2a") or {}, moe.get("measured_a2a") or {}
-        w(f"  all-to-all: measured x{meas.get('count', 0)} "
-          f"{human_bytes(meas.get('bytes', 0))} vs expected "
-          f"x{exp.get('count', 0)} {human_bytes(exp.get('bytes', 0))}"
-          + ("  OK" if moe.get("bytes_match") else "  <- MISMATCH"))
-        warns = moe.get("involuntary_remat_warnings")
-        if warns is not None:
-            w(f"  involuntary-remat warnings at compile: {warns}"
-              + ("" if warns == 0 else "  <- GSPMD replicate-repartition!"))
-    # round-12 quantized collectives (ROADMAP #2): f32 vs bf16 vs int8
-    # --comm_dtype per strategy rung, with the bytes-on-the-wire cut as
-    # the headline and the loss delta as the tolerance-gate number.
-    for r in records:
-        qc = r.get("quant_comm")
-        if not isinstance(qc, list) or not qc:
-            continue
-        w("== quantized collectives (bench, --comm_dtype) ==")
-        int8_ratios = []
-        for row in qc:
-            if "error" in row:
-                w(f"  {row.get('strategy', '?'):<5} "
-                  f"{row.get('comm_dtype', '?'):<5} ERROR {row['error']}")
-                continue
-            ratio = row.get("wire_ratio_vs_f32")
-            delta = row.get("loss_delta_vs_f32")
-            match = row.get("bytes_match")
-            warns = row.get("involuntary_remat_warnings")
-            w(f"  {row['strategy']:<5} {row['comm_dtype']:<5} "
-              f"wire {human_bytes(row.get('wire_bytes'))}"
-              + (f" ({ratio * 100:.1f}% of f32)" if ratio is not None else "")
-              + f"   {human_count(row.get('tokens_per_sec_per_chip'))} tok/s/chip"
-              + (f"   dloss vs f32 {delta:+.4g}" if delta is not None else "")
-              + ("" if match is None
-                 else ("   audit OK" if match else "   audit <- MISMATCH"))
-              + ("" if not warns else f"   remat warnings {warns}!"))
-            if row["comm_dtype"] == "int8" and ratio:
-                int8_ratios.append(ratio)
-        if int8_ratios:
-            cut = 1.0 / (sum(int8_ratios) / len(int8_ratios))
-            w(f"  headline: int8 payloads move ~{cut:.1f}x fewer bytes on "
-              f"the wire than f32 (mean over strategy rungs)")
-    # round-18 overlap schedule (ROADMAP #5): f32 vs int8 vs int8+buckets
-    # per strategy — the wire cut and the overlap win separately visible;
-    # overlap_frac is the gated schedule property (--min_overlap_frac),
-    # step time the wall-clock observable.
-    for r in records:
-        co = r.get("comm_overlap")
-        if not isinstance(co, list) or not co:
-            continue
-        w("== overlap-scheduled collectives (bench, --grad_buckets) ==")
-        for row in co:
-            if "error" in row:
-                w(f"  {row.get('strategy', '?'):<5} "
-                  f"{row.get('comm_dtype', '?'):<5} "
-                  f"b{row.get('grad_buckets', '?')} ERROR {row['error']}")
-                continue
-            label = (f"{row['comm_dtype']}"
-                     + (f"+overlap(b{row['grad_buckets']})"
-                        if row.get("grad_buckets") else ""))
-            ov = row.get("overlap") or {}
-            frac = ov.get("overlap_frac")
-            rel = row.get("step_time_vs_f32")
-            warns = row.get("involuntary_remat_warnings")
-            match = row.get("bytes_match")
-            w(f"  {row['strategy']:<5} {label:<16} "
-              f"step {row.get('step_time_s', 0) * 1e3:.2f}ms"
-              + (f" ({rel * 100:.1f}% of f32)" if rel is not None else "")
-              + f"   {human_count(row.get('tokens_per_sec_per_chip'))} tok/s/chip"
-              + (f"   overlap {ov.get('overlappable', '?')}/"
-                 f"{ov.get('declared', '?')} wires hidden"
-                 + (" OK" if ov.get("gate_ok") else " <- GATE FAIL")
-                 if frac is not None else "")
-              + ("" if match is None
-                 else ("   audit OK" if match else "   audit <- MISMATCH"))
-              + ("" if not warns else f"   remat warnings {warns}!"))
-    # round-25 interleaved pipeline (--virtual_stages): the tick-table
-    # bubble grid (the gated, backend-free numbers) plus the timed rungs'
-    # wall cross-check, and the pipeline x MoE pallas parity rung.
-    for r in records:
-        pi = r.get("pipe_interleave")
-        if not isinstance(pi, dict):
-            continue
-        w("== pipeline (bench, --virtual_stages) ==")
-        if "error" in pi:
-            w(f"  ERROR {pi['error']}")
-            continue
-        w(f"  stages {pi.get('stages', '?')}  microbatches "
-          f"{pi.get('microbatches', '?')}  layers {pi.get('layers', '?')}")
-        by_m: dict = {}
-        for row in pi.get("bubble_table") or []:
-            by_m.setdefault(row.get("micro"), []).append(row)
-        for m, rows_m in sorted(by_m.items()):
-            cells = " -> ".join(
-                f"V{row['virtual_stages']} {row['bubble_frac']:.3f}"
-                for row in sorted(rows_m,
-                                  key=lambda x: x["virtual_stages"]))
-            w(f"  bubble @M={m}: {cells}")
-        for row in pi.get("rungs") or []:
-            if "error" in row:
-                w(f"  V={row.get('virtual_stages', '?')}  ERROR "
-                  f"{row['error']}")
-                continue
-            wall = row.get("wall_ratio_vs_flat")
-            w(f"  V={row['virtual_stages']}  bubble "
-              f"{row.get('bubble_frac', 0):.3f}   predicted "
-              f"{row.get('predicted_ratio_vs_flat', 0) * 100:.1f}% of flat"
-              + (f"   wall {wall * 100:.1f}%" if wall is not None else "")
-              + f"   {human_count(row.get('tokens_per_sec_per_chip'))} "
-              f"tok/s/chip")
-        if pi.get("caveat"):
-            w(f"  caveat: {pi['caveat']}")
-    for r in records:
-        pm = r.get("pipe_moe")
-        if not isinstance(pm, dict):
-            continue
-        w("== pipeline x moe (bench, --moe_dispatch pallas) ==")
-        if "error" in pm:
-            w(f"  ERROR {pm['error']}")
-            continue
-        w(f"  {pm.get('stages', '?')} stages x V={pm.get('virtual_stages', '?')}"
-          f" M={pm.get('microbatches', '?')}, e{pm.get('num_experts', '?')} "
-          f"{pm.get('dispatch', '?')} dispatch: "
-          f"{human_count(pm.get('tokens_per_sec_per_chip'))} tok/s/chip")
-        w(f"  loss parity vs single device: {pm.get('loss', '?')} vs "
-          f"{pm.get('ref_loss', '?')} (delta {pm.get('loss_delta', '?')})"
-          + ("  OK" if pm.get("parity_ok") else "  <- MISMATCH"))
-    # round-13 elastic restore (ROADMAP #5): what a reshard-on-restore
-    # relaunch costs — wall-clock, bytes read, host RSS high-water delta,
-    # and the byte-parity bit vs a direct restore. Rendered under the
-    # recovery banner: a topology change is a recovery event.
-    for r in records:
-        er = r.get("elastic_restore")
-        if not isinstance(er, dict):
-            continue
-        w("== recovery: elastic restore (bench) ==")
-        if "error" in er:
-            w(f"  ERROR {er['error']}")
-            continue
-        fw, tw = er.get("from_world") or {}, er.get("to_world") or {}
-        w(f"  {fw.get('strategy', '?')}@{fw.get('devices', '?')} -> "
-          f"{tw.get('strategy', '?')}@{tw.get('devices', '?')}: "
-          f"{er.get('restore_wall_s', '?')}s   "
-          f"read {human_bytes(er.get('bytes_read'))} in "
-          f"{er.get('blocks_read', '?')} blocks "
-          f"(state {human_bytes(er.get('state_bytes'))})")
-        overhead = er.get("rss_overhead_bytes")
-        w(f"  host RSS high-water delta: "
-          f"{human_bytes(er.get('peak_rss_delta_bytes'))}"
-          + (f" (scratch overhead above resident state: "
-             f"{human_bytes(overhead)})" if overhead is not None else "")
-          + "   parity vs direct restore: "
-          + ("OK" if er.get("parity_ok") else "<- MISMATCH"))
-    # round-14 serving bench (ROADMAP #1): continuous batching vs serial
-    # per-request decode on the SAME seeded synthetic stream — the >= 2x
-    # tokens/s headline plus the latency/occupancy numbers a capacity
-    # planner reads.
-    for r in records:
-        sv = r.get("serving")
-        if not isinstance(sv, dict):
-            continue
-        w("== serving (bench, continuous vs serial) ==")
-        if "error" in sv:
-            w(f"  ERROR {sv['error']}")
-            continue
-        w(f"  stream: {sv.get('requests', '?')} requests, "
-          f"{sv.get('generated_tokens', '?')} generated tokens, "
-          f"{sv.get('slots', '?')} slots, buckets {sv.get('buckets', '?')}")
-        rows = (("continuous", sv.get("continuous")),
-                ("serial", sv.get("serial")),
-                ("serial_cached", sv.get("serial_cached")))
-        for name, row in rows:
-            if not row:
-                continue
-            p50, p99 = row.get("p50_e2e_s"), row.get("p99_e2e_s")
-            w(f"  {name:<14} {human_count(row.get('tokens_per_sec'))} tokens/s"
-              + (f"   e2e p50/p99 {p50 * 1e3:.1f}/{p99 * 1e3:.1f} ms"
-                 if p50 is not None else "")
-              + (f"   occupancy {100 * row['mean_occupancy']:.0f}%"
-                 if row.get("mean_occupancy") is not None else ""))
-        sp = sv.get("speedup")
-        if sp is not None:
-            w(f"  headline: continuous batching {sp:.2f}x serial "
-              f"per-request generate on the same stream"
-              + ("" if sp >= 2.0 else "  <- BELOW the 2x acceptance bar"))
-        spc = sv.get("speedup_vs_cached")
-        if spc is not None:
-            w(f"  vs the strongest serial baseline (forced cached "
-              f"while_loop): {spc:.2f}x")
-    # round-15 paged-KV bench (ROADMAP #2): ring vs paged vs paged+int8 at
-    # EQUAL KV HBM — the >= 2x concurrent-slots bar with int8 pages, the
-    # exact-parity bit, and prefix-hit vs cold admit latency.
-    for r in records:
-        pk = r.get("paged_kv")
-        if not isinstance(pk, dict):
-            continue
-        w("== paged kv (bench, equal KV HBM) ==")
-        if "error" in pk:
-            w(f"  ERROR {pk['error']}")
-            continue
-        w(f"  stream: {pk.get('requests', '?')} requests, buckets "
-          f"{pk.get('buckets', '?')}, page {pk.get('page_size', '?')} tokens")
-        for name in ("ring", "paged", "paged_int8"):
-            row = pk.get(name)
-            if not row:
-                continue
-            w(f"  {name:<11} {human_count(row.get('tokens_per_sec'))} tokens/s"
-              f"   slots {row.get('max_live_slots', '?')}/"
-              f"{row.get('slots', '?')} live   KV "
-              f"{human_bytes(row.get('kv_bytes'))}")
-        ratio = pk.get("slots_at_equal_hbm_ratio")
-        if ratio is not None:
-            w(f"  headline: {ratio:.2f}x concurrent slots at equal KV HBM "
-              f"with int8 pages"
-              + ("" if ratio >= 2.0 else "  <- BELOW the 2x acceptance bar"))
-        w("  paged f32 parity vs ring: "
-          + ("token-exact" if pk.get("parity_ok") else "<- MISMATCH")
-          + (f"   int8 token agreement {100 * pk['int8_token_agreement']:.1f}%"
-             if pk.get("int8_token_agreement") is not None else ""))
-        px = pk.get("prefix") or {}
-        if px.get("hits") is not None:
-            hit_s, cold_s = px.get("admit_latency_hit_s"), px.get("admit_latency_cold_s")
-            w(f"  shared-prefix stream: {px['hits']} hits "
-              f"({100 * (px.get('hit_rate') or 0):.0f}% of admissions), "
-              f"{px.get('pages_reused', 0)} pages of prefill skipped"
-              + (f"   admit latency hit/cold {hit_s * 1e3:.1f}/"
-                 f"{cold_s * 1e3:.1f} ms" if hit_s is not None
-                 and cold_s is not None else ""))
-    # round-21 fused-decode bench (ROADMAP #2/#4): the kernel win and the
-    # dispatch-amortization win rendered SEPARATELY — the bench isolates
-    # them so neither can hide behind the other, and the renderer keeps
-    # them apart for the same reason.
-    for r in records:
-        df = r.get("decode_fused")
-        if not isinstance(df, dict):
-            continue
-        w("== fused decode (bench, --fused_decode) ==")
-        if "error" in df:
-            w(f"  ERROR {df['error']}")
-            continue
-        w(f"  stream: {df.get('requests', '?')} requests, "
-          f"{df.get('slots', '?')} slots, page {df.get('page_size', '?')} "
-          f"tokens, window {df.get('window_quanta', '?')} quanta")
-        for name in ("unfused_q1", "fused_q1", "fused_loop"):
-            row = df.get(name)
-            if not row:
-                continue
-            disp = row.get("mean_dispatch_ms_per_quantum")
-            dev = row.get("mean_device_ms_per_quantum")
-            w(f"  {name:<11} {human_count(row.get('tokens_per_sec'))} "
-              f"tokens/s   {row.get('quanta', '?')} quanta / "
-              f"{row.get('decode_steps', '?')} steps"
-              + (f"   dispatch/device {disp:.2f}/{dev:.2f} ms per quantum"
-                 if disp is not None and dev is not None else "")
-              + (f"   trace {df_tc:.2f}" if (df_tc := row.get(
-                    "trace_complete")) is not None else ""))
-        ks, am = df.get("kernel_speedup"), df.get("amortization_speedup")
-        if ks is not None:
-            w(f"  one-tick window vs per-step program (quantum=1): {ks:.2f}x")
-        if am is not None:
-            w(f"  amortization win (on-device loop vs per-step dispatch): "
-              f"{am:.2f}x  <- the gated, backend-transferable number")
-        w("  token parity across all rungs: "
-          + ("exact" if df.get("parity_ok") else "<- MISMATCH"))
-    # round-22 metrics-overhead bench: the pure-observer proof. Tokens
-    # must be bit-identical with the metrics plane on vs --no_metrics,
-    # and the throughput cost must stay under the 1% budget; the
-    # snapshot-publish wall is the only new I/O and is timed separately.
-    for r in records:
-        mo = r.get("metrics_overhead")
-        if not isinstance(mo, dict):
-            continue
-        w("== metrics overhead (bench, pure-observer proof) ==")
-        if "error" in mo:
-            w(f"  ERROR {mo['error']}")
-            continue
-        off, on = mo.get("tokens_per_sec_off"), mo.get("tokens_per_sec_on")
-        frac = mo.get("overhead_frac")
-        w(f"  {mo.get('requests', '?')} requests: "
-          f"{human_count(off)} tokens/s metrics-off vs {human_count(on)} on"
-          + (f"   overhead {100 * frac:.2f}%"
-             + ("" if frac <= 0.01 else "  <- ABOVE the 1% budget")
-             if frac is not None else ""))
-        w("  token parity on vs off: "
-          + ("bit-identical" if mo.get("tokens_bit_identical")
-             else "<- MISMATCH")
-          + (f"   snapshot publish {mo['snapshot_publish_s'] * 1e3:.2f} ms"
-             if mo.get("snapshot_publish_s") is not None else "")
-          + (f"   ({mo['series']} series)"
-             if mo.get("series") is not None else ""))
-    # round-19 fleet bench (ROADMAP #1): the replica scaling curve at
-    # equal total devices + the disaggregated-prefill admit-latency
-    # comparison, with the CPU-loopback caveat carried in-record.
-    for r in records:
-        fs = r.get("fleet_serving")
-        if not isinstance(fs, dict):
-            continue
-        w("== fleet serving (bench, replicas at equal total devices) ==")
-        if "error" in fs:
-            w(f"  ERROR {fs['error']}")
-            continue
-        w(f"  stream: {fs.get('requests', '?')} requests, "
-          f"{fs.get('slots_per_replica', '?')} slots/replica, "
-          f"{fs.get('total_devices', '?')} total devices"
-          + ("" if fs.get("meshed") else " (meshless rungs)"))
-        for row in fs.get("rungs") or []:
-            if "error" in row:
-                w(f"  {row.get('replicas', '?')}x  ERROR {row['error']}")
-                continue
-            p99 = row.get("p99_e2e_s")
-            w(f"  {row['replicas']}x replicas "
-              f"({row.get('devices_per_replica', 0)} dev each): "
-              f"{human_count(row.get('tokens_per_sec'))} tokens/s"
-              + (f"   e2e p99 {p99 * 1e3:.1f} ms" if p99 is not None else "")
-              + (f"   admit {row['mean_admit_latency_s'] * 1e3:.1f} ms"
-                 if row.get("mean_admit_latency_s") is not None else ""))
-        sc = fs.get("scaling_2x_vs_1")
-        if sc is not None:
-            w(f"  headline: 2 replicas = {sc:.2f}x the 1-replica fleet "
-              f"tokens/s at equal total devices"
-              + ("" if sc > 1.5 else "  <- BELOW the 1.5x acceptance bar"))
-        w("  cross-rung token parity: "
-          + ("OK" if fs.get("parity_ok") else "<- MISMATCH"))
-        dp = fs.get("disagg_prefill")
-        if isinstance(dp, dict):
-            if "error" in dp:
-                w(f"  disagg prefill probe ERROR {dp['error']}")
-            else:
-                ca, da = (dp.get("colocated_admit_latency_s"),
-                          dp.get("disagg_admit_latency_s"))
-                w(f"  prefill: colocated admit "
-                  f"{(ca or 0) * 1e3:.1f} ms vs disaggregated "
-                  f"{(da or 0) * 1e3:.1f} ms   ({dp.get('handoffs', '?')} "
-                  f"handoffs, {dp.get('worker_prefix_hits', '?')} worker "
-                  f"prefix hits)")
-        if fs.get("caveat"):
-            w(f"  caveat: {fs['caveat']}")
-    # round-11 dispatch ladder (ROADMAP #3): the three MoE dataflows side
-    # by side at e8 top-1/top-2, MFU normalized by ACTIVE FLOPs (top_k
-    # experts + router per token) so padding/dispatch waste reads as lost
-    # MFU rather than inflating the FLOP count.
-    for r in records:
-        ladder = r.get("moe_dispatch_ladder")
-        if not isinstance(ladder, list) or not ladder:
-            continue
-        w("== moe dispatch ladder (bench, active-FLOPs MFU) ==")
-        for row in ladder:
-            if "error" in row:
-                w(f"  {row.get('dispatch', '?'):<7} top{row.get('top_k', '?')}"
-                  f"  ERROR {row['error']}")
-                continue
-            mfu_a = row.get("mfu_active")
-            w(f"  {row['dispatch']:<7} top{row['top_k']}  "
-              f"{human_count(row.get('tokens_per_sec_per_chip'))} tok/s/chip"
-              + (f"   active-FLOPs MFU {mfu_a * 100:.1f}%"
-                 if mfu_a is not None else ""))
     return "\n".join(out)
 
 
@@ -1105,23 +722,6 @@ def check_min_goodput(records: list[dict], threshold: float) -> tuple[bool, str]
     return mean_gp >= threshold, (
         f"--min_goodput {verdict}: mean goodput {mean_gp:.3f} over "
         f"{len(gp)} windows (threshold {threshold:.3f})"
-    )
-
-
-def check_min_serve_tps(records: list[dict], threshold: float) -> tuple[bool, str]:
-    """Serving-throughput CI gate (`--min_serve_tps`): the run's
-    `kind="serve_summary"` tokens/s must reach `threshold`. Returns
-    (ok, message) — missing summary fails, a serving regression must not
-    hide behind an empty log."""
-    sums = [r for r in _rows(records, "serve_summary")
-            if r.get("tokens_per_sec") is not None]
-    if not sums:
-        return False, "--min_serve_tps: no serve_summary record in the log"
-    tps = sums[-1]["tokens_per_sec"]
-    verdict = "OK" if tps >= threshold else "FAIL"
-    return tps >= threshold, (
-        f"--min_serve_tps {verdict}: {tps:.1f} tokens/s "
-        f"(threshold {threshold:.1f})"
     )
 
 
@@ -1145,34 +745,6 @@ def check_min_accept_rate(records: list[dict], threshold: float) -> tuple[bool, 
         f"({sp.get('accepted', 0)}/{sp.get('proposed', 0)} draft tokens, "
         f"{sp.get('draft', '?')} k={sp.get('k', '?')}; "
         f"threshold {threshold:.3f})"
-    )
-
-
-def check_min_fleet_tps(records: list[dict], threshold: float) -> tuple[bool, str]:
-    """Fleet-throughput CI gate (`--min_fleet_tps`, round 19): the run's
-    `kind="fleet_summary"` tokens/s must reach `threshold`, AND the
-    exactly-once invariant must hold (zero duplicate completions — a
-    killed replica's requests must re-queue, not double-emit). Returns
-    (ok, message) — a log without a fleet summary fails, so the gate
-    can't pass vacuously when someone drops `--replicas` from the smoke
-    invocation (the `--min_accept_rate` discipline)."""
-    sums = [r for r in _rows(records, "fleet_summary")
-            if r.get("tokens_per_sec") is not None]
-    if not sums:
-        return False, ("--min_fleet_tps: no fleet_summary record in the "
-                       "log (was the run --replicas'ed?)")
-    s = sums[-1]
-    tps = s["tokens_per_sec"]
-    dups = s.get("duplicate_completions", 0)
-    ok = tps >= threshold and not dups
-    verdict = "OK" if ok else "FAIL"
-    return ok, (
-        f"--min_fleet_tps {verdict}: {tps:.1f} fleet tokens/s over "
-        f"{s.get('replicas_peak', '?')} peak replica(s), "
-        f"{s.get('requeued', 0)} re-queued, {dups} duplicate completion(s) "
-        f"(threshold {threshold:.1f}"
-        + ("" if not dups else "; duplicates violate exactly-once")
-        + ")"
     )
 
 
@@ -1227,146 +799,6 @@ def check_min_trace_complete(records: list[dict], threshold: float) -> tuple[boo
         f"trees complete ({frac:.3f}; {n_open} open; threshold "
         f"{threshold:.3f})"
     )
-
-
-def check_min_overlap_frac(records: list[dict], threshold: float) -> tuple[bool, str]:
-    """Overlap-schedule gate (`--min_overlap_frac`, round 18): every
-    bucketed rung of the bench `comm_overlap` record must have
-    overlap_frac (overlappable / declared bucket wires, from the
-    promoted hlolint `overlap` rule) >= `threshold`. Returns
-    (ok, message) — a log without any overlap rung fails, so the gate
-    can't pass vacuously when someone drops the bucketed rungs from the
-    bench invocation. The fraction is the static schedule property: on
-    CPU virtual devices wall-clock overlap is noise, the structure is
-    what CI pins."""
-    fracs, broken = [], []
-    for r in records:
-        co = r.get("comm_overlap")
-        if not isinstance(co, list):
-            continue
-        for row in co:
-            if not isinstance(row, dict) or not row.get("grad_buckets"):
-                continue
-            # every BUCKETED rung must carry a verdict: an errored rung
-            # or one missing its overlap block is a gate failure, not a
-            # skipped sample — else a crashed strategy passes silently
-            name = f"{row.get('strategy', '?')}/b{row.get('grad_buckets')}"
-            ov = row.get("overlap")
-            if "error" in row or not isinstance(ov, dict) \
-                    or ov.get("overlap_frac") is None:
-                broken.append(name)
-                continue
-            if ov.get("gate_ok") is False:
-                broken.append(name + " (gate FAIL)")
-            fracs.append((name, ov["overlap_frac"]))
-    if not fracs and not broken:
-        return False, ("--min_overlap_frac: no comm_overlap rung with an "
-                       "overlap verdict in the log (did the bench run the "
-                       "--grad_buckets rungs?)")
-    if broken:
-        return False, (
-            f"--min_overlap_frac FAIL: bucketed rung(s) without a passing "
-            f"overlap verdict: {', '.join(broken)}"
-        )
-    worst_name, worst = min(fracs, key=lambda sf: sf[1])
-    ok = worst >= threshold
-    verdict = "OK" if ok else "FAIL"
-    return ok, (
-        f"--min_overlap_frac {verdict}: min overlap_frac {worst:.3f} "
-        f"({worst_name}) over {len(fracs)} bucketed rungs "
-        f"(threshold {threshold:.3f})"
-    )
-
-
-def check_min_decode_speedup(records: list[dict],
-                             threshold: float) -> tuple[bool, str]:
-    """Fused-decode gate (`--min_decode_speedup`, round 21): the bench
-    `decode_fused` record's AMORTIZATION speedup (on-device while-loop
-    window vs per-step dispatch, the same read of the pool both sides) must
-    be >= `threshold`, with token parity intact across all three rungs.
-    The kernel_speedup (a one-tick window against the per-step program)
-    stays informational. A log without the fused record fails — dropping the rung
-    from the bench invocation must not pass the gate vacuously."""
-    for r in records:
-        df = r.get("decode_fused")
-        if not isinstance(df, dict):
-            continue
-        if "error" in df:
-            return False, f"--min_decode_speedup FAIL: rung errored: {df['error']}"
-        if not df.get("parity_ok"):
-            return False, ("--min_decode_speedup FAIL: fused rungs are not "
-                           "token-identical to the unfused engine")
-        am = df.get("amortization_speedup")
-        if am is None:
-            return False, ("--min_decode_speedup FAIL: decode_fused record "
-                           "carries no amortization_speedup")
-        ok = am >= threshold
-        verdict = "OK" if ok else "FAIL"
-        ks = df.get("kernel_speedup")
-        return ok, (
-            f"--min_decode_speedup {verdict}: amortization "
-            f"{am:.2f}x (threshold {threshold:.2f}"
-            + (f"; kernel {ks:.2f}x informational" if ks is not None else "")
-            + ")"
-        )
-    return False, ("--min_decode_speedup: no decode_fused record in the log "
-                   "(did the bench run the fused rungs?)")
-
-
-def check_min_bubble_gain(records: list[dict],
-                          threshold: float) -> tuple[bool, str]:
-    """Interleaved-pipeline gate (`--min_bubble_gain`, round 25): the
-    bench `pipe_interleave` record's bubble grid must show, at EVERY
-    micro-batch count, (a) a strictly decreasing bubble fraction as
-    virtual stages grow (1 -> 2 -> 4) and (b) a relative bubble cut
-    (1 - bubble[max V]/bubble[V=1]) >= `threshold`. The grid is
-    tick-table accounting, deterministic on any backend — the wall
-    numbers stay informational (CPU loopback, the --min_overlap_frac
-    discipline) — but every TIMED rung must also have run without
-    error, so a machine that stopped compiling cannot pass on pure
-    math. A log without the record fails: dropping the rung from the
-    bench invocation must not pass the gate vacuously."""
-    for r in records:
-        pi = r.get("pipe_interleave")
-        if not isinstance(pi, dict):
-            continue
-        if "error" in pi:
-            return False, f"--min_bubble_gain FAIL: record errored: {pi['error']}"
-        broken = [
-            f"V={row.get('virtual_stages', '?')}: {row['error']}"
-            for row in pi.get("rungs") or [] if "error" in row
-        ]
-        if broken:
-            return False, ("--min_bubble_gain FAIL: errored timed rung(s): "
-                           + "; ".join(broken))
-        by_m: dict = {}
-        for row in pi.get("bubble_table") or []:
-            by_m.setdefault(row.get("micro"), []).append(row)
-        if not by_m:
-            return False, ("--min_bubble_gain FAIL: record carries no "
-                           "bubble_table grid")
-        worst = None  # (gain, micro, fracs)
-        for m, rows_m in sorted(by_m.items()):
-            rows_m = sorted(rows_m, key=lambda x: x["virtual_stages"])
-            fracs = [row["bubble_frac"] for row in rows_m]
-            if any(b >= a for a, b in zip(fracs, fracs[1:])):
-                return False, (
-                    f"--min_bubble_gain FAIL: bubble fraction not strictly "
-                    f"decreasing at M={m}: "
-                    + " -> ".join(f"{f:.4f}" for f in fracs))
-            gain = 1.0 - fracs[-1] / fracs[0]
-            if worst is None or gain < worst[0]:
-                worst = (gain, m, fracs)
-        ok = worst[0] >= threshold
-        verdict = "OK" if ok else "FAIL"
-        return ok, (
-            f"--min_bubble_gain {verdict}: min relative bubble cut "
-            f"{worst[0]:.3f} at M={worst[1]} "
-            f"({worst[2][0]:.4f} -> {worst[2][-1]:.4f}) over "
-            f"{len(by_m)} micro counts (threshold {threshold:.3f})"
-        )
-    return False, ("--min_bubble_gain: no pipe_interleave record in the log "
-                   "(did the bench run the interleave rungs?)")
 
 
 # ---- round-22 cross-run comparison (--compare baseline.jsonl) ------------
@@ -1565,17 +997,10 @@ GATES: tuple = (
     ("min_goodput", "FRACTION", check_min_goodput,
      "assert mean train-window goodput >= FRACTION (exit 2 below "
      "it) — a cheap perf regression gate for CI"),
-    ("min_serve_tps", "TOKENS_PER_SEC", check_min_serve_tps,
-     "assert the serve_summary tokens/s >= this (exit 2 below it) "
-     "— the serving-throughput regression gate for CI"),
     ("min_accept_rate", "FRACTION", check_min_accept_rate,
      "assert the serve_summary speculative-decoding acceptance "
      "rate >= FRACTION (exit 2 below it, or when the log has no spec "
      "summary) — the draft-health regression gate for CI"),
-    ("min_fleet_tps", "TOKENS_PER_SEC", check_min_fleet_tps,
-     "assert the fleet_summary tokens/s >= this with zero "
-     "duplicate completions (exit 2 below it, or when the log has no "
-     "fleet summary) — the fleet-serving regression gate for CI"),
     ("max_deadline_miss_pct", "PERCENT", check_max_deadline_miss_pct,
      "assert the fleet_summary's deadline_misses <= PERCENT of served "
      "requests (exit 2 above it, or when the log has no fleet summary "
@@ -1586,22 +1011,6 @@ GATES: tuple = (
      "(kind=\"trace\" rows: closed AND phase walls summing to e2e "
      "within 1e-3 s) >= FRACTION (exit 2 below it, or when the log "
      "has no trace rows) — the tracing-integrity gate for CI"),
-    ("min_overlap_frac", "FRACTION", check_min_overlap_frac,
-     "assert every bucketed comm_overlap bench rung's "
-     "overlap_frac (hlolint-measured hidden-wires fraction) >= "
-     "FRACTION (exit 2 below it, or when the log has no overlap "
-     "rung) — the overlap-schedule regression gate for CI"),
-    ("min_decode_speedup", "RATIO", check_min_decode_speedup,
-     "assert the decode_fused bench record's amortization_speedup "
-     "(on-device scheduler loop vs per-step dispatch) >= RATIO with "
-     "token parity intact (exit 2 below it, or when the log has no "
-     "decode_fused record) — the round-21 fused-decode regression gate"),
-    ("min_bubble_gain", "FRACTION", check_min_bubble_gain,
-     "assert the pipe_interleave bench record's relative bubble cut "
-     "(1 - bubble[max V]/bubble[V=1], tick-table accounting) >= FRACTION "
-     "at EVERY micro count, strictly decreasing in V, with no errored "
-     "timed rung (exit 2 otherwise, or when the log has no "
-     "pipe_interleave record) — the round-25 interleaved-pipeline gate"),
     ("min_slo_compliance", "FRACTION", check_min_slo_compliance,
      "assert the run's cumulative SLO compliance (worst target in the "
      "last kind=\"slo\" record) >= FRACTION (exit 2 below it, or when "

@@ -4,8 +4,8 @@ Rounds 10–15 accumulated four closed-form comm predictions, each with its
 own shape and its own comparison loop: `Strategy.grad_comm` (quantized
 DDP/FSDP grad wire), `ExpertParallel.dispatch_comm` (the MoE a2a
 exchange), `serve.decode.decode_step_comm` (the TP decode step) and
-`moe_dispatch.expected_a2a` under them. The dryrun, fit()'s xla record,
-bench probes and four test files each re-spelled "fetch the expectation,
+`moe_dispatch.expected_a2a` under them. The dryrun, fit()'s xla record
+and four test files each re-spelled "fetch the expectation,
 index the measured dict, compare count and bytes". A CommPlan is that
 expectation normalized once: {op: {count, bytes}} plus, where the
 formula knows it, the wire element dtype each op's payload must travel
@@ -216,7 +216,7 @@ def ring_wire_bytes(collectives: dict[str, dict], world: int) -> int:
       collective-permute     R                      (one hop)
 
     This is the denominator-normalizer for the quantized-collective
-    headline (bench.py's quant_comm record, tests): "int8 moves <= 30% of
+    headline (tests/test_quant_comm.py): "int8 moves <= 30% of
     the f32 wire bytes" compares ring-model wire, not raw result sizes."""
     if world <= 1:
         return 0

@@ -3,7 +3,7 @@
 JAX ships a content-addressed on-disk cache of compiled executables; with
 it enabled, a repeat run of the same program skips XLA compilation — tens
 of seconds per shape at GPT-small on a TPU. Every entry point that compiles
-(`fit()`, main-serve.py, bench.py, chip_smoke.py, the test harness) calls
+(`fit()`, main-serve.py, chip_smoke.py, the test harness) calls
 `enable_compilation_cache`, so the recipes cache by default.
 
 Where the cache lives — ONE rule (`enable_compilation_cache`):
@@ -22,7 +22,7 @@ once per hit, so `misses = requests - hits`; `compile_s` sums the wall
 seconds jax spent compiling (or, on a hit, deserializing). One listener is
 installed at most once per process; `enable_compilation_cache` returns a
 stats handle that reports deltas since it was created, so nested scopes
-(bench probes, repeated fit calls, chip_smoke phases) each see their own
+(repeated fit calls, chip_smoke phases) each see their own
 counts.
 """
 

@@ -224,8 +224,8 @@ _FIXTURE_VALIDATION_SIZE = 256
 def _fixture_corpus() -> tuple[list[str], list[str]]:
     """Memoized (round-7 host-pipeline hygiene): the corpus is deterministic
     and BOTH get_dataset and get_tokenizer rebuild it on every fit() —
-    ~1.3s of pure host regeneration per run that repeat callers (bench
-    probes, the test suite's ~35 fits) were paying each time. Callers treat
+    ~1.3s of pure host regeneration per run that repeat callers (the
+    test suite's ~35 fits) were paying each time. Callers treat
     the lists as read-only."""
     return (
         synthetic_stories(_FIXTURE_TRAIN_SIZE, seed=0),

@@ -437,9 +437,9 @@ def add_serve_flags(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                         "proposer matches (falls back through shorter "
                         "suffixes down to 1)")
     # Request-scoped tracing (round 20, tpukit/obs/trace.py): ON by
-    # default — the ring is bounded and the emit cost is inside the
-    # recorder's <1% budget (bench.py obs_overhead serving rung), with
-    # token streams bit-identical either way (tests/test_trace.py).
+    # default — the ring is bounded, an emit is one dict and a deque
+    # append, and token streams are bit-identical either way
+    # (tests/test_trace.py).
     parser.add_argument("--no_trace", action="store_true",
                         help="disable request-scoped span tracing "
                         "(kind=\"trace_event\"/\"trace\" JSONL rows, "
@@ -453,7 +453,7 @@ def add_serve_flags(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
     # counters/gauges/log-bucket histograms DERIVED from completions,
     # trace trees and quantum walls at window boundaries (the decode hot
     # path is untouched), token streams bit-identical either way
-    # (tests/test_metrics.py) and <1% throughput (bench metrics_overhead).
+    # (tests/test_metrics.py).
     parser.add_argument("--no_metrics", action="store_true",
                         help="disable the metrics plane (mergeable "
                         "latency histograms, kind=\"metrics\"/\"slo\" "

@@ -59,7 +59,6 @@ from tpukit.obs.meter import (  # noqa: F401
     MFUMeter,
     StepLogger,
     matmul_param_count,
-    moe_active_flops_per_token,
     peak_flops_per_chip,
     profiler_trace,
     train_flops_per_token,

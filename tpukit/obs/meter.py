@@ -75,29 +75,6 @@ def train_flops_per_token(cfg: GPTConfig, seq_len: int) -> float:
     return 3 * (2 * matmul_param_count(cfg) + attn)
 
 
-def moe_active_flops_per_token(cfg: GPTConfig, seq_len: int) -> float:
-    """Training FLOPs per token counting only the ACTIVE expert parameters
-    — the `top_k` routed experts plus the router — the dropless-MoE
-    normalization ROADMAP #3's dispatch ladder uses. Capacity padding and
-    one-hot dispatch/combine einsums are *implementation* FLOPs, not model
-    FLOPs: normalizing MFU by this number makes the dispatch ladder
-    comparable — a dataflow that burns FLOPs on padding rows shows a LOWER
-    MFU at equal tokens/s instead of hiding inside a bigger FLOP budget.
-    For dense configs this is exactly `train_flops_per_token`."""
-    if cfg.num_experts <= 0:
-        return train_flops_per_token(cfg, seq_len)
-    inner = cfg.inner_dim
-    ffn = 2 * cfg.dim * (cfg.dim * cfg.ffn_mult)  # up + down kernels
-    per_layer = (
-        3 * cfg.dim * inner + inner * cfg.dim          # qkv + attn out
-        + cfg.router_top_k * ffn                       # active experts
-        + cfg.dim * cfg.num_experts                    # router
-    )
-    params = cfg.num_layers * per_layer + cfg.dim * cfg.padded_vocab_size
-    attn = 4 * seq_len * inner * cfg.num_layers
-    return 3 * (2 * params + attn)
-
-
 class MFUMeter:
     """Rolling tokens/sec + MFU over recent steps. `update()` once per step
     with the number of (real, global) tokens processed."""

@@ -6,9 +6,8 @@ ring buffer (collections.deque with maxlen) of small host-side records —
 step dispatches, window metrics, sentinel events, checkpoint saves,
 divergence checksums — that is ALWAYS on: a record is one dict allocation
 plus a deque append under a lock (sub-microsecond next to any real train
-step, the <1% budget bench.py's `obs_overhead` record audits), and memory
-is bounded by construction — the ring evicts the oldest record at
-capacity, so a month-long run holds exactly `capacity` records.
+step), and memory is bounded by construction — the ring evicts the oldest
+record at capacity, so a month-long run holds exactly `capacity` records.
 
 Nothing reads the ring on the happy path. Its one consumer is the
 diagnostics bundle (tpukit/obs/watchdog.py): when the hang watchdog or a

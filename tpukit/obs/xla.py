@@ -193,9 +193,9 @@ def capture_compiler_stderr(check: bool = False):
     discipline: hand-placed collectives must compile warning-free).
 
     Used to audit a compile for involuntary-remat warnings (the dryrun's
-    EP world, bench.py's moe_ep_comm probe, tests). Note: a compile served
-    from the persistent compilation cache emits no warnings either way —
-    the audit is meaningful on cold compiles.
+    EP world, tests). Note: a compile served from the persistent
+    compilation cache emits no warnings either way — the audit is
+    meaningful on cold compiles.
     """
     sys.stderr.flush()
     holder = {"text": "", "involuntary_remat": 0}

@@ -16,10 +16,10 @@ the per-device work and the warm-up/cool-down shrinks toward
 
 This module is the schedule AUTHORITY: a pure-Python greedy list
 scheduler that emits the per-tick, per-device job tables the tick
-machine unrolls, plus the idle-work accounting bench.py reports and
-tools/report.py gates (`--min_bubble_gain`). Keeping it jax-free means
-the CI lane's fast step and the bench bubble table run without devices,
-and the machine, the bench and the comm plan all read ONE table — the
+machine unrolls, plus the idle-work accounting (`bubble_table`) the
+tests hold strictly decreasing in V. Keeping it jax-free means the CI
+lane's fast step runs without devices, and the machine and the comm
+plan read ONE table — the
 collective-permute count in the compiled HLO is exactly
 `sum(t.ship_fwd) + sum(t.ship_bwd)` because the machine emits one
 ppermute per shipping tick and nothing else.
@@ -335,7 +335,7 @@ def cached_schedule(num_stages: int, virtual: int, num_micro: int,
 
 
 def bubble_table(num_stages: int, virtuals=(1, 2, 4), micros=(4, 8, 16)):
-    """The measured bubble-fraction table the bench record carries:
+    """The bubble-fraction table, counted from the tick tables:
     one row per (V, M). V=1 rows price the EXISTING flat machine
     (pipeline.py's scan — that is what `--virtual_stages 1` runs);
     V > 1 rows come from the generated tick tables."""

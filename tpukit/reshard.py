@@ -271,8 +271,7 @@ def _reshard_sharded(base: Path, template, sharding_tree, info: dict):
     # layout), and re-reading it from the zip once per buffer would
     # multiply restore I/O by the target shard count. The cache lives for
     # one leaf's assembly and is dropped with it, so the host-memory bound
-    # stays one leaf — and each byte is read exactly once (bench.py's
-    # elastic_restore record asserts bytes_read against that invariant).
+    # stays one leaf — and each byte is read exactly once.
     block_cache: dict[tuple, np.ndarray] = {}
 
     def read_block(reader, key):
@@ -378,7 +377,7 @@ def reshard_restore(path, template, sharding_tree=None):
     shardings, resharding as needed. Returns `(state, info)` where state's
     leaves are placed at `sharding_tree` (host arrays when None) and info
     records `{format, bytes_read, blocks_read, wall_s}` for the resize
-    JSONL record and bench.py's `elastic_restore` probe.
+    JSONL record.
 
     The target shardings need not match the ones the checkpoint was
     written under in world size, strategy, or both — resharding is pure

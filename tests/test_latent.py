@@ -342,3 +342,36 @@ def test_gpt_serve_programs_are_byte_equal_to_the_parents(kv, program, steps):
     else:
         lowered = serve_decode.decode_loop.lower(params, cfg, state[0], z((n,), jnp.int32), 8, 96)
     assert hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16] == GPT_PROGRAM_HASHES[kv, program, steps]
+
+
+# The latent family's own serve programs at `tiny_config()`, recorded on the
+# parent of PR 33 (commit 81c4036) before `latent.py` was touched: the second
+# published form of the family (grouped differential heads, streams, PolyNorm)
+# is switched by the config alone and leaves this one's programs byte-equal.
+LATENT_PROGRAM_HASHES = {
+    ("f32", "decode_step", 1): "11ebc88fcb39c891", ("f32", "decode_step", 4): "e87790ae704f5433",
+    ("f32", "prefill_chunk_paged", 0): "fac06f97e645181f",
+    ("bf16", "decode_step", 1): "de0e8312eb18f8b4", ("bf16", "decode_step", 4): "8edb8c5b30c78f38",
+    ("bf16", "prefill_chunk_paged", 0): "b07a56753706ffb8",
+}
+
+
+@pytest.mark.parametrize("kv,program,steps", sorted(LATENT_PROGRAM_HASHES))
+def test_latent_serve_programs_are_byte_equal_to_the_parents(kv, program, steps):
+    import hashlib
+
+    cfg = latent.tiny_config()
+    params = latent.init_params(jax.random.PRNGKey(0), cfg)
+    n, per = 4, 6
+    z = lambda shape, dt: jnp.zeros(shape, dt)  # noqa: E731
+    ring = latent.page_kinds(cfg, PAGE, kv)[1].ring_pages
+    cache = latent.init_paged_cache(cfg, {"bt": n * per + 1, "bt_w": n * ring + 1}, PAGE, per, n, kv)
+    state = (z((n, per * PAGE), jnp.int32), cache, z((n,), jnp.int32), z((n,), bool), z((n,), jnp.int32),
+             z((n, 2), jnp.uint32))
+    if program == "decode_step":
+        lowered = serve_decode.decode_step.lower(params, cfg, *state, 96, 0.0, 0, None, steps=steps)
+    else:
+        lowered = serve_decode.prefill_chunk_paged.lower(
+            params, cfg, *state, z((2,), jnp.int32), z((2, CHUNK), jnp.int32), z((2,), jnp.int32), z((2,), bool),
+            z((2,), jnp.int32), z((2,), jnp.int32), z((2, 2), jnp.uint32))
+    assert hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16] == LATENT_PROGRAM_HASHES[kv, program, steps]

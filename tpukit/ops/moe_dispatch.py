@@ -402,34 +402,48 @@ def expected_a2a(cfg, data_size: int, expert_size: int, global_batch: int,
 
 
 @jax.named_scope("router")
-def sigmoid_topk_route(x, router_kernel, select_bias, top_k: int):
+def sigmoid_topk_route(x, router_kernel, select_bias, top_k: int, scale: float = 1.0):
     """Sigmoid scores over ALL experts, the `top_k` of largest `score + bias`
-    (the bias steers the choice only, `noaux_tc`), gates = the chosen scores
-    normalised over the chosen (`norm_topk_prob`), whoever holds them.
+    (the bias steers the choice only, `noaux_tc`; `None`: the scores alone
+    choose), gates = the chosen scores normalised over the chosen
+    (`norm_topk_prob`), whoever holds them, times `scale`.
     x `[T, D]`; returns `(idx [T, k] int32, gates [T, k] float32)`. Scores are
     float32 at full matmul precision: a bf16 pass reorders near-ties at the
     k-th place."""
     logits = jnp.matmul(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+    _, idx = jax.lax.top_k(scores if select_bias is None else scores + select_bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
-    return idx.astype(jnp.int32), chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    gates = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), gates if scale == 1.0 else scale * gates
 
 
 @jax.named_scope("experts")
-def held_experts_ffn(x, idx, gates, experts, expert_lo: int, compute_dtype, row_mask=None):
+def held_experts_ffn(x, idx, gates, experts, expert_lo: int, compute_dtype, row_mask=None, activation=None,
+                     every_row: bool = False):
     """`sum over chosen AND held experts of gate x E(x)` for `x [T, D]`, with
-    `E(x) = (silu(x Wg) * x Wu) Wd` and `experts = {gate, up [E, D, F], down
-    [E, F, D]}` the held range `[expert_lo, expert_lo + E)`. Dropless: the
+    `E(x) = (act(x Wg) * x Wu) Wd` and `experts = {gate, up [E, D, F], down
+    [E, F, D]}` the held range `[expert_lo, expert_lo + E)`. `act` is SiLU, or
+    `activation(z [R, F] float32, expert [R] int32)` over the sorted rows,
+    `expert[r]` the held expert row `r` was given to (`E` for a row of none):
+    an activation that reduces over the expert's width and has weights of its
+    expert's own runs between the grouped matmuls. Dropless: the
     (row, choice) pairs are sorted by held expert (pairs of absent experts
     last) and the three products run as grouped matmuls (`lax.ragged_dot`)
     over exactly the rows each expert was given; the static row count is the
     worst case `T x k`, the computed one is what was routed here. Rows with
     `row_mask` False (a frozen decode lane) are given to no expert.
 
+    `every_row`: every held expert computes EVERY row and the gates keep what
+    was routed (`activation`'s `expert` is then `[E, 1]` against `z [E, T,
+    F]`). For the handful of rows of a decode tick the cost is the read of
+    the experts' weights either way; the grouped matmul skips an expert that
+    got no row, so its time follows how the rows fell, and this form's does
+    not. The same sum, every term rounded as the grouped form rounds it.
+
     Returns `(y [T, D] float32, rows [E] int32)`, `rows[e]` the rows expert
-    `expert_lo + e` computed."""
+    `expert_lo + e` computed (was routed, under `every_row`)."""
     t, d = x.shape
     k = idx.shape[1]
     e = experts["gate"].shape[0]
@@ -437,13 +451,25 @@ def held_experts_ffn(x, idx, gates, experts, expert_lo: int, compute_dtype, row_
     held = (local >= 0) & (local < e)
     if row_mask is not None:
         held = held & row_mask[:, None]
+    if every_row:
+        xc = x.astype(compute_dtype)
+        each = lambda spec, a, w, out: jnp.einsum(spec, a, w.astype(compute_dtype), preferred_element_type=out)  # noqa: E731
+        z = each("td,edf->etf", xc, experts["gate"], jnp.float32)
+        act = jax.nn.silu(z) if activation is None else activation(z, jnp.arange(e, dtype=jnp.int32)[:, None])
+        act = (act * each("td,edf->etf", xc, experts["up"], jnp.float32)).astype(compute_dtype)
+        out = each("etf,efd->etd", act, experts["down"], compute_dtype)
+        chose = held[:, :, None] & (local[:, :, None] == jnp.arange(e))  # [T, k, E]: row t's choice j is held expert e
+        weight = jnp.sum(jnp.where(chose, gates[:, :, None], 0.0), axis=1)  # [T, E]: its gate, 0 where it chose none
+        y = jnp.sum((out * weight.T[:, :, None]).astype(compute_dtype).astype(jnp.float32), axis=0)
+        return y, jnp.sum(chose, axis=(0, 1)).astype(jnp.int32)
     key = jnp.where(held, local, e).reshape(-1)  # [T*k]; `e` sorts the rest last
     order = jnp.argsort(key, stable=True)
     rows = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
     xs = x.astype(compute_dtype)[order // k]  # [T*k, D], sorted by held expert
     dot = lambda a, w, out: jax.lax.ragged_dot(  # noqa: E731
         a, w.astype(compute_dtype), rows, preferred_element_type=out)
-    act = (jax.nn.silu(dot(xs, experts["gate"], jnp.float32))
+    z = dot(xs, experts["gate"], jnp.float32)
+    act = ((jax.nn.silu(z) if activation is None else activation(z, key[order]))
            * dot(xs, experts["up"], jnp.float32)).astype(compute_dtype)
     out = dot(act, experts["down"], compute_dtype)  # accumulated in float32, rounded once on the way out
     # rows past the routed ones belong to no group: whatever the kernel left there is dropped

@@ -1127,14 +1127,19 @@ class ServeEngine:
                 self._quantum["steps"] = ran
         else:
             # whatever counters the model keeps in its cache ride the same
-            # round trip (none for a model that keeps none)
+            # round trip (none for a model that keeps none): an integer
+            # array counts since the cache was made and the quantum gets the
+            # difference, a float array holds gauges, reported as fetched
             names, counters = self._model.counters(self.cache)
             cur, act, *counters = map(np.asarray, jax.device_get(
                 (self.cursors, self.active, *counters)))
             if names:
-                seen = np.concatenate(counters).astype(np.int64)
+                gauge = np.concatenate([np.full(c.size, c.dtype.kind == "f") for c in counters])
+                seen = np.concatenate([c.ravel().astype(np.float64) for c in counters])
                 if self._quantum is not None:
-                    self._quantum.update(zip(names, (seen - self._counters).tolist()))
+                    since = np.where(gauge, seen, seen - self._counters)
+                    self._quantum.update(
+                        (k, float(v) if g else int(v)) for k, v, g in zip(names, since, gauge))
                 self._counters = seen
         self._drain_spec()
         return cur, act

@@ -168,20 +168,21 @@ def test_v5e_compiles(v5e, monkeypatch, name):
 def _small_cfg(**kw):
     from tpukit.model import gpt
 
-    return gpt.GPTConfig(dim=DIM, heads=HEADS, head_dim=HEAD_DIM, num_layers=2,
+    kw.setdefault("num_layers", 2)
+    return gpt.GPTConfig(dim=DIM, heads=HEADS, head_dim=HEAD_DIM,
                          vocab_size=VOCAB, max_position_embeddings=1024,
                          compute_dtype=BF16, vocab_pad_multiple=128, **kw)
 
 
-def _scoped_train_step(devices):
-    """The trainer's own step (two GPT-small layers, flash + fused head+CE)
-    lowered for one described chip: every kernel sits under the `loss` scope
-    and a model scope."""
+def _scoped_train_step(devices, num_layers=2):
+    """The trainer's own step (GPT-small layers, unrolled, flash + fused
+    head+CE) lowered for one described chip: every kernel sits under the
+    `loss` scope and a model scope."""
     from tpukit import shardings
     from tpukit.mesh import create_mesh
     from tpukit.train import create_train_state, make_optimizer, make_step_fns
 
-    cfg = _small_cfg(attention_impl="flash")
+    cfg = _small_cfg(attention_impl="flash", num_layers=num_layers)
     strategy = shardings.SingleDevice(create_mesh(None, devices=devices[:1]))
     opt = make_optimizer(3e-4)
     shapes = jax.eval_shape(lambda: create_train_state(jax.random.PRNGKey(0), cfg, opt, strategy))
@@ -332,6 +333,40 @@ def test_v5e_named_scopes_leave_kernel_names_alone(v5e, monkeypatch, name, lower
         assert len(calls) == expect[kernel] and set(calls.values()) == {scope}, calls
     if name == "train_step":
         assert {"optimizer", "loss/embed", "loss/ln", "loss/ffn"} <= set(scopes.values())
+
+
+def test_v5e_unrolled_step_lowers_each_flash_kernel_once(v5e, monkeypatch):
+    """Set-up is held by a count, never by a wall time: a four-layer
+    UNROLLED step traces each flash kernel body once and lowers each
+    per-shard program into ONE private function that every layer calls
+    (lowered anew per layer, the walked kernels doubled a 24-layer step's
+    set-up), while the compiled module still holds one `flash_fwd` and one
+    `flash_bwd` custom call a layer under their names."""
+    import collections
+    import re
+
+    layers = 4
+    monkeypatch.setattr(pallas_attention, "on_tpu_backend", lambda: True)
+    traced = collections.Counter()
+    for name in ("_fwd_kernel", "_bwd_kernel"):
+        body = getattr(pallas_attention, name)
+
+        def counted(*refs, _body=body, _name=name, **static):
+            traced[_name] += 1
+            return _body(*refs, **static)
+
+        monkeypatch.setattr(pallas_attention, name, counted)
+    for program in (pallas_attention._fwd4_impl, pallas_attention._bwd4_impl):
+        program.clear_cache()  # an earlier test's trace of these shapes would read 0
+    lowered = _scoped_train_step(v5e, num_layers=layers)
+    assert traced == {"_fwd_kernel": 1, "_bwd_kernel": 1}
+    text = lowered.as_text()
+    for program in ("_fwd4_impl", "_bwd4_impl"):
+        assert len(re.findall(rf"func\.func private @{program}\b", text)) == 1, program
+        assert len(re.findall(rf"call @{program}\b", text)) == layers, program
+    assert text.count("tpu_custom_call") == 4  # flash_fwd, flash_bwd, head_ce_fwd, head_ce_bwd: once each
+    kernels = kernel_calls(lowered.compile().as_text())
+    assert kernels == {"flash_fwd": layers, "flash_bwd": layers, "head_ce_fwd": 1, "head_ce_bwd": 1}
 
 
 # ---------------------------------------------------------------------------

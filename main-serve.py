@@ -139,17 +139,20 @@ def parse_serve_flags(argv=None):
     ap.add_argument("--moe_dispatch", choices=("xla", "pallas"), default="xla",
                     help="meshless decode dataflow for MoE checkpoints; "
                     "'pallas' (dropless) makes the cached decode exact")
-    ap.add_argument("--model", choices=("gpt", "latent"), default="gpt",
+    ap.add_argument("--model", choices=("gpt", "latent", "hybrid"), default="gpt",
                     help="block family: 'gpt' (tpukit/model/gpt.py, the flags "
-                    "above) or 'latent' (tpukit/model/latent.py: latent "
+                    "above), 'latent' (tpukit/model/latent.py: latent "
                     "attention with a learned key selection, window layers, a "
-                    "sigmoid-routed expert share; served only, paged cache "
-                    "only, fresh seeded weights on one device)")
+                    "sigmoid-routed expert share) or 'hybrid' "
+                    "(tpukit/model/hybrid.py: Mamba-1 selective-state layers "
+                    "beside attention layers of one K/V head); the last two "
+                    "served only, paged cache only, fresh seeded weights on "
+                    "one device")
     ap.add_argument("--model_config", type=str, default="",
-                    help="--model latent: a configuration file with the "
-                    "published config.json keys (benchmark/configs/"
-                    "dots3-note-prev.json); empty = the tiny preset at the "
-                    "tokenizer's vocabulary")
+                    help="--model latent / hybrid: a configuration file with "
+                    "the published config.json keys (benchmark/configs/"
+                    "dots3-note-prev.json, jamba2-3b.json); empty = the tiny "
+                    "preset at the tokenizer's vocabulary")
     # checkpoint
     ap.add_argument("--checkpoint", type=str, default="",
                     help="path or 'latest'; empty serves fresh seeded params "
@@ -255,9 +258,9 @@ def main(argv=None):
     )
     buckets = tuple(sorted({int(b) for b in flags.buckets.split(",") if b}))
 
-    # ---- the latent family (--model latent): served only, one device -----
-    if flags.model == "latent":
-        cfg, params = _latent_model(flags, tokenizer)
+    # ---- the served-only families (--model latent / hybrid): one device ---
+    if flags.model != "gpt":
+        cfg, params = _served_only_model(flags, tokenizer)
         return _serve_stream(flags, cfg, params, None, tokenizer, buckets,
                              StepLogger(flags.metrics_log), FlightRecorder())
 
@@ -530,36 +533,36 @@ def _serve_stream(flags, cfg, params, mesh, tokenizer, buckets, logger, recorder
     return completions
 
 
-def _latent_model(flags, tokenizer):
-    """`--model latent`: the config (a configuration file's published keys,
-    or the tiny preset at the tokenizer's vocabulary) and fresh seeded
-    weights. The family has no training path, so there is no checkpoint to
-    restore, no strategy and no mesh; speculation and the fleet speak of the
-    GPT block's cache and are refused by name."""
+def _served_only_model(flags, tokenizer):
+    """`--model latent` / `--model hybrid`: the config (a configuration
+    file's published keys, or the tiny preset at the tokenizer's vocabulary)
+    and fresh seeded weights. Neither family has a training path, so there
+    is no checkpoint to restore, no strategy and no mesh; speculation and
+    the fleet speak of the GPT block's cache and are refused by name."""
     import json
 
     import jax
     import jax.numpy as jnp
 
-    from tpukit.model import ServedOnlyError, latent
+    from tpukit.model import ServedOnlyError, hybrid, latent
 
+    module = {"latent": latent, "hybrid": hybrid}[flags.model]
     for flag, why in (("checkpoint", "it is served only: nothing trains it, so nothing saves one"),
                       ("draft", "speculative decoding verifies on the ring cache"),
                       ("replicas", "the fleet's page handoff copies the GPT block's K and V pools")):
         if getattr(flags, flag):
-            raise ServedOnlyError(f"--model latent takes no --{flag}: {why}")
+            raise ServedOnlyError(f"--model {flags.model} takes no --{flag}: {why}")
     if not flags.page_size:
-        raise ServedOnlyError("--model latent needs the paged cache (--page_size > 0): "
-                              "its window layers keep a ring of pages")
+        raise ServedOnlyError(f"--model {flags.model} needs the paged cache (--page_size > 0): "
+                              "its window layers keep a ring of pages, its Mamba layers a state beside them")
     dtype = jnp.float32 if flags.disable_amp else jnp.bfloat16
     if flags.model_config:
         with open(flags.model_config) as f:
-            cfg = latent.config_from_hf(json.load(f), compute_dtype=dtype, param_dtype=dtype)
+            cfg = module.config_from_hf(json.load(f), compute_dtype=dtype, param_dtype=dtype)
     else:
-        cfg = latent.tiny_config(vocab_size=tokenizer.vocab_size, compute_dtype=dtype, param_dtype=dtype)
-    params = jax.jit(lambda r: latent.init_params(r, cfg))(jax.random.PRNGKey(flags.seed))
-    print(f"serving fresh seeded params of the latent family "
-          f"({cfg.num_layers} layers, {cfg.experts_held} of {cfg.n_experts} experts held)")
+        cfg = module.tiny_config(vocab_size=tokenizer.vocab_size, compute_dtype=dtype, param_dtype=dtype)
+    params = jax.jit(lambda r: module.init_params(r, cfg))(jax.random.PRNGKey(flags.seed))
+    print(f"serving fresh seeded params of the {flags.model} family ({cfg.num_layers} layers)")
     return cfg, params
 
 

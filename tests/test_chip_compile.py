@@ -309,6 +309,41 @@ def test_v5e_latent_family_decode_quantum_and_prefill_chunk_compile_at_published
         assert m.argument_size_in_bytes > 6e9 and total < 15.75e9, (name, total)  # the weights are real, and it fits
 
 
+def test_v5e_hybrid_family_serve_programs_compile_at_published_widths_and_128_slots(v5e):
+    """The hybrid family's serve programs at the jamba2-3b configuration's
+    real size and the cell's engine (128 slots of 16,384 tokens, chunks of
+    256, quantum 4), for one described chip: the 28 layers' weights, the MQA
+    pages and every slot's recurrent state, held twice by the un-donated
+    programs, and the largest admit batch's prefill (its lanes a group at a
+    time) all fit, and the scan and the key-block walk compile."""
+    import json
+
+    from tpukit.model import hybrid
+    from tpukit.serve import decode
+
+    with open(os.path.join(REPO, "benchmark", "configs", "jamba2-3b.json")) as f:
+        cfg = hybrid.config_from_hf(json.load(f))
+    one = SingleDeviceSharding(v5e[0])
+    on = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    n, page, per_slot, chunk = 128, 16, 1024, 256
+    params = on(jax.eval_shape(lambda: hybrid.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on(jax.eval_shape(lambda: hybrid.init_paged_cache(cfg, n * per_slot + 1, page, per_slot, n, "bf16")))
+    state = (sds((n, page * per_slot), I32), cache, sds((n,), I32), sds((n,), jnp.bool_), sds((n,), I32),
+             sds((n, 2), jnp.uint32))
+    programs = {
+        "decode": decode.decode_step.lower(params, cfg, *state, 0, 0.0, 0, None, steps=4),
+        "prefill": decode.prefill_chunk_paged.lower(
+            params, cfg, *state, sds((n,), I32), sds((n, chunk), I32), sds((n,), I32), sds((n,), jnp.bool_),
+            sds((n,), I32), sds((n,), I32), sds((n, 2), jnp.uint32)),
+    }
+    for name, lowered in programs.items():
+        m = lowered.compile().memory_analysis()
+        total = m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+        assert m.argument_size_in_bytes > 9e9 and total < 15.75e9, (name, total)  # weights, pages and state are real
+
+
 @pytest.mark.parametrize("name,lower,expect,scope_of", [
     ("train_step", _scoped_train_step,
      {"flash_fwd": 2, "flash_bwd": 2, "head_ce_fwd": 1, "head_ce_bwd": 1},

@@ -296,3 +296,38 @@ def test_a_ticks_rows_through_every_held_expert_give_the_grouped_sum():
     a, _ = latent._expert_layer(moe, cfg, x, None)
     b, _ = latent._expert_layer(moe, cfg.replace(tick_experts_every_row=False), x, None)
     assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+# This form's own serve programs at `tiny_diff_config()`, recorded on the
+# parent of PR 36 (commit 651dcf7) before the key-block walk of its full
+# layer was shared with the hybrid family's attention layers
+# (tpukit/model/hybrid.py): sharing the walk leaves them byte-equal.
+DIFF_PROGRAM_HASHES = {
+    ("f32", "decode_step", 1): "994533e5d39fd31b", ("f32", "decode_step", 4): "2792178625561b0b",
+    ("f32", "prefill_chunk_paged", 0): "30768157d3d56d3c",
+    ("bf16", "decode_step", 1): "0ca8c040bd668bdb", ("bf16", "decode_step", 4): "6774c3bf090c83b6",
+    ("bf16", "prefill_chunk_paged", 0): "0b0fe4d3b5a6183e",
+}
+
+
+@pytest.mark.parametrize("kv,program,steps", sorted(DIFF_PROGRAM_HASHES))
+def test_diff_form_serve_programs_are_byte_equal_to_the_parents(kv, program, steps):
+    import hashlib
+
+    from tpukit.serve import decode as serve_decode
+
+    cfg = latent.tiny_diff_config()
+    params = latent.init_params(jax.random.PRNGKey(0), cfg)
+    n, per = 4, 6
+    z = lambda shape, dt: jnp.zeros(shape, dt)  # noqa: E731
+    ring = latent.page_kinds(cfg, PAGE, kv)[1].ring_pages
+    cache = latent.init_paged_cache(cfg, {"bt": n * per + 1, "bt_w": n * ring + 1}, PAGE, per, n, kv)
+    state = (z((n, per * PAGE), jnp.int32), cache, z((n,), jnp.int32), z((n,), bool), z((n,), jnp.int32),
+             z((n, 2), jnp.uint32))
+    if program == "decode_step":
+        lowered = serve_decode.decode_step.lower(params, cfg, *state, 96, 0.0, 0, None, steps=steps)
+    else:
+        lowered = serve_decode.prefill_chunk_paged.lower(
+            params, cfg, *state, z((2,), jnp.int32), z((2, CHUNK), jnp.int32), z((2,), jnp.int32), z((2,), bool),
+            z((2,), jnp.int32), z((2,), jnp.int32), z((2, 2), jnp.uint32))
+    assert hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16] == DIFF_PROGRAM_HASHES[kv, program, steps]

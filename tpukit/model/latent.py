@@ -665,33 +665,46 @@ def _diff_values(o_lat, lam, kv_b, spec: AttnSpec, dtype):
 
 def _attend_paged(q_cat, pool, li: int, bt, q_pos, spec: AttnSpec, dtype):
     """Absorbed queries `q_cat [B, Q, H, kv_rank + rope]` at positions `q_pos
-    [B, Q]` over EVERY earlier key of their lane, read where it lies: the
-    lane's pages of layer `li` of `pool [L, NP, P, row]`, `KEY_BLOCK` keys (a
-    stretch of the block table `bt [B, MP]`) at a time, folded under a running
-    max and sum. The walk ends at the last block any of these queries can
-    see. Returns the weighted latents `[B, Q, H, kv_rank]` float32."""
-    b, nq, h, _ = q_cat.shape
+    [B, Q]` over EVERY earlier key of their lane (`walk_key_blocks`): a row's
+    whole width is its key, its latent the value. Returns the weighted
+    latents `[B, Q, H, kv_rank]` float32."""
+    return walk_key_blocks(q_cat, pool, li, bt, q_pos, dtype, 1.0 / math.sqrt(spec.nope + spec.rope),
+                           values=slice(None, spec.kv_rank))
+
+
+def walk_key_blocks(q, pool, li: int, bt, q_pos, dtype, scale: float, values: slice, keys: slice | None = None):
+    """Queries `q [B, Q, H, K]` at positions `q_pos [B, Q]` over EVERY earlier
+    key of their lane, read where it lies: the lane's pages of layer `li` of
+    `pool [L, NP, P, row]`, `KEY_BLOCK` keys (a stretch of the block table `bt
+    [B, MP]`) at a time, folded under a running max and sum. A row's columns
+    `keys` (all of them: None) are the key a query's score reads, its columns
+    `values` the value it gives. The walk ends at the last block any of these
+    queries can see. Returns `[B, Q, H, width of values]` float32. Shared by
+    the latent family's full layers and the hybrid family's attention
+    layers (tpukit/model/hybrid.py)."""
+    b, nq, h, _ = q.shape
     p = pool.shape[2]
     pages = math.gcd(bt.shape[1], max(1, KEY_BLOCK // p))
     kb = pages * p
-    scale = 1.0 / math.sqrt(spec.nope + spec.rope)
     offs = jnp.arange(kb, dtype=jnp.int32)
+    width = len(range(pool.shape[3])[values])
 
     def block(j, carry):
         m, l, acc = carry
         pids = jax.lax.dynamic_slice_in_dim(bt, j * pages, pages, axis=1)
-        keys = pool[li, pids].reshape(b, kb, -1).astype(dtype)
-        scores = jnp.einsum("bqhr,bkr->bqhk", q_cat, keys, preferred_element_type=jnp.float32) * scale
+        rows = pool[li, pids].reshape(b, kb, -1).astype(dtype)
+        k = rows if keys is None else rows[..., keys]
+        scores = jnp.einsum("bqhr,bkr->bqhk", q, k, preferred_element_type=jnp.float32) * scale
         seen = (j * kb + offs)[None, None, :] <= q_pos[:, :, None]
         # key 0 is every query's, so from the first block on the running max is finite and a block
         # with no key of some query adds exp(-inf) = 0 to it
         m, l, fix, e = online_softmax_update(m, l, jnp.where(seen[:, :, None, :], scores, -jnp.inf))
-        acc = acc * fix + jnp.einsum("bqhk,bkc->bqhc", e.astype(dtype), keys[..., : spec.kv_rank],
+        acc = acc * fix + jnp.einsum("bqhk,bkc->bqhc", e.astype(dtype), rows[..., values],
                                      preferred_element_type=jnp.float32)
         return m, l, acc
 
     init = (jnp.full((b, nq, h, 1), -jnp.inf, jnp.float32), jnp.zeros((b, nq, h, 1), jnp.float32),
-            jnp.zeros((b, nq, h, spec.kv_rank), jnp.float32))
+            jnp.zeros((b, nq, h, width), jnp.float32))
     _, l, acc = jax.lax.fori_loop(0, jnp.max(q_pos) // kb + 1, block, init)
     return acc / l
 

@@ -536,6 +536,24 @@ class ServeEngine:
         self._top_k = min(int(serve.top_k), cfg.padded_vocab_size)
         n, w = serve.slots, serve.padded_width
 
+        # a recurrent state a slot beside the pages (the model's
+        # `slot_state_bytes`, where it offers one): constant whatever the
+        # context, counted for every live lane, and cut at no page, so nothing
+        # that shares or rolls back pages can hold it
+        self._slot_state = (model.slot_state_bytes(cfg)
+                            if hasattr(model, "slot_state_bytes") else 0)
+        if self._slot_state and serve.draft:
+            raise ValueError(
+                f"{type(cfg).__name__} keeps a recurrent state a slot: "
+                f"speculation (draft={serve.draft!r}) would have to roll it "
+                f"back past rejected tokens, which a state cannot do"
+            )
+        if self._slot_state and not serve.paged:
+            raise ValueError(
+                f"{type(cfg).__name__} keeps a recurrent state a slot beside "
+                f"its pages: the contiguous ring (page_size=0) has no room for "
+                f"it"
+            )
         if serve.paged:
             from tpukit.serve import paged as paged_lib
 
@@ -627,7 +645,7 @@ class ServeEngine:
             }
             self.allocator = self.allocators[kinds[0].table]
             self.kv_bytes = paged_lib.pool_bytes(
-                cfg, pool_pages, serve.page_size, serve.kv_dtype
+                cfg, pool_pages, serve.page_size, serve.kv_dtype, slots=n
             )
             self._tables = {
                 k.table: np.zeros(tree[k.table].shape, np.int32) for k in kinds
@@ -813,10 +831,11 @@ class ServeEngine:
         limit = min(plen + req.max_new_tokens, self.serve.width)
         total = -(-limit // p)
         # a shared prefix skips its chunks' prefill, which only a model whose
-        # every layer keeps the whole context can do: a window layer's ring
-        # would miss the tokens before the suffix
+        # every layer keeps the whole context in pages can do: a window
+        # layer's ring would miss the tokens before the suffix, and a
+        # recurrent state is no page at all
         matched = (self.allocator.lookup_prefix(req.ids, (plen - 1) // p)
-                   if len(self._kinds) == 1 else [])
+                   if len(self._kinds) == 1 and not self._slot_state else [])
         s_tokens = (len(matched) * p // c) * c
         shared = matched[: s_tokens // p]
         self.allocator.claim(shared)
@@ -935,8 +954,9 @@ class ServeEngine:
             if is_last:
                 lane.phase = "decode"
                 lane.active_s = now
-                reg = (lane.prompt_len - 1) // self.serve.page_size
-                self.allocator.register(lane.req.ids, lane.pages[:reg])
+                if not self._slot_state:  # pages a state model's prefix can never be read back from
+                    reg = (lane.prompt_len - 1) // self.serve.page_size
+                    self.allocator.register(lane.req.ids, lane.pages[:reg])
 
     def _refresh_bt(self) -> None:
         """Push the host block tables to the device copy the programs
@@ -964,10 +984,11 @@ class ServeEngine:
             free_pages=(self.allocator.available_pages
                         if self.serve.paged else None),
             # what the decoding lanes have behind them (as of the last
-            # sync) and what the page pools hold for every live lane
+            # sync) and what the page pools and the recurrent state hold for
+            # every live lane
             ctx_tokens=sum(l.prompt_len + l.delivered for l in decoding),
             kv_bytes=(sum(k.layers * k.page_bytes * self.allocators[k.table].live_pages
-                          for k in self._kinds)
+                          for k in self._kinds) + self._slot_state * len(self._lanes)
                       if self.serve.paged else self.kv_bytes),
         )
 
@@ -1763,6 +1784,12 @@ class ServeEngine:
                 f"the prefill handoff copies one kind of page; "
                 f"{type(self.cfg).__name__} keeps "
                 f"{[k.table for k in self._kinds]}"
+            )
+        if self._slot_state:
+            raise NotImplementedError(
+                f"the prefill handoff shares and copies pages; "
+                f"{type(self.cfg).__name__}'s recurrent state is no page: "
+                f"no prefix of it can be handed off or shared"
             )
         plen = len(req.ids)
         slot = self._free.popleft()

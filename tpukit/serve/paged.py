@@ -166,17 +166,21 @@ def init_paged_cache(cfg, num_pages: int, page_size: int, pages_per_slot: int,
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt), "bt": bt}
 
 
-def pool_bytes(cfg, num_pages, page_size: int, kv_dtype: str) -> int:
+def pool_bytes(cfg, num_pages, page_size: int, kv_dtype: str, slots: int = 0) -> int:
     """Closed-form HBM bytes of every page pool `cfg`'s model keeps, by its
     own statement of its page kinds (`tpukit.model.family(cfg).page_kinds`):
     layers x pages x page_size x bytes a token, summed over the kinds. For
     the GPT block that is the K+V pools (the equal-HBM bench math: int8 pays
     1 byte per element plus the 4-byte-per-block f32 scale sidecar, i.e.
     `packed_bytes` per (page, head) row). `num_pages` is one count for a
-    model with one kind, else `{block-table key: pages}`."""
+    model with one kind, else `{block-table key: pages}`. A model that keeps
+    a recurrent state a slot beside its pages (`slot_state_bytes`, the hybrid
+    family) adds that state for each of `slots`."""
     from tpukit.model import family  # lazy: tpukit.model imports this module's users
 
-    kinds = family(cfg).page_kinds(cfg, page_size, kv_dtype)
+    model = family(cfg)
+    state = slots * model.slot_state_bytes(cfg) if hasattr(model, "slot_state_bytes") else 0
+    kinds = model.page_kinds(cfg, page_size, kv_dtype)
     if not isinstance(num_pages, dict):
         if len(kinds) != 1:
             raise ValueError(
@@ -184,7 +188,7 @@ def pool_bytes(cfg, num_pages, page_size: int, kv_dtype: str) -> int:
                 f"({[k.table for k in kinds]}): give pages per kind as a dict"
             )
         num_pages = {kinds[0].table: num_pages}
-    return sum(k.layers * num_pages[k.table] * k.page_bytes for k in kinds)
+    return state + sum(k.layers * num_pages[k.table] * k.page_bytes for k in kinds)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -283,3 +283,37 @@ def test_family_dispatch_and_the_published_config():
         hybrid.config_from_hf(dict(config, num_key_value_heads=8))
     with pytest.raises(ValueError, match="Jamba"):
         hybrid.config_from_hf(dict(config, model_type="mamba"))
+
+
+# The family's serve programs at `tiny_config()`, recorded on the tree at
+# commit adc8f13, before the key-block walk it shares with the latent family
+# (`latent.walk_key_blocks`) took its selection operand: a walk with no
+# selection lowers to the text it lowered to then.
+HYBRID_PROGRAM_HASHES = {
+    ("f32", "decode_step", 1): "979e5ef23f3d0c74", ("f32", "decode_step", 4): "6f8a5c235d1b5d86",
+    ("f32", "prefill_chunk_paged", 0): "9ccc804635ab37ac",
+    ("bf16", "decode_step", 1): "18d3065435b86a90", ("bf16", "decode_step", 4): "67c8640feef06255",
+    ("bf16", "prefill_chunk_paged", 0): "0cd6dd28d795550f",
+}
+
+
+@pytest.mark.parametrize("kv,program,steps", sorted(HYBRID_PROGRAM_HASHES))
+def test_hybrid_serve_programs_are_byte_equal_to_the_parents(kv, program, steps):
+    import hashlib
+
+    from tpukit.serve import decode as serve_decode
+
+    cfg = hybrid.tiny_config()
+    params = hybrid.init_params(jax.random.PRNGKey(0), cfg)
+    n, per = 4, 6
+    z = lambda shape, dt: jnp.zeros(shape, dt)  # noqa: E731
+    cache = hybrid.init_paged_cache(cfg, n * per + 1, PAGE, per, n, kv)
+    state = (z((n, per * PAGE), jnp.int32), cache, z((n,), jnp.int32), z((n,), bool), z((n,), jnp.int32),
+             z((n, 2), jnp.uint32))
+    if program == "decode_step":
+        lowered = serve_decode.decode_step.lower(params, cfg, *state, 96, 0.0, 0, None, steps=steps)
+    else:
+        lowered = serve_decode.prefill_chunk_paged.lower(
+            params, cfg, *state, z((2,), jnp.int32), z((2, CHUNK), jnp.int32), z((2,), jnp.int32), z((2,), bool),
+            z((2,), jnp.int32), z((2,), jnp.int32), z((2, 2), jnp.uint32))
+    assert hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16] == HYBRID_PROGRAM_HASHES[kv, program, steps]

@@ -104,6 +104,120 @@ def test_chunked_prefill_then_decode_through_the_cache_matches_the_reference(mod
     assert int(cache["moe_rows"][0]) > 0 and int(cache["moe_rows"][1]) <= int(cache["moe_rows"][0])
 
 
+def stable_sort_mask(score, k: int):
+    """The keys a stable sort of `-score` keeps in its first `k` places, as a
+    mask (a `-inf` key is no key)."""
+    pos = jnp.broadcast_to(jnp.arange(score.shape[-1], dtype=jnp.int32), score.shape)
+    _, order = jax.lax.sort((-score, pos), dimension=score.ndim - 1, num_keys=1, is_stable=True)
+    return (jnp.argsort(order, axis=-1) < k) & (score > -jnp.inf)
+
+
+def _score_rows(case: str, rows: int = 6, width: int = 96):
+    """`(score [rows, width] float32, k)` for one case of the selection test."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    x = rng.standard_normal((rows, width)).astype(np.float32)
+    k = 24
+    if case == "ties_straddle_the_kth":  # few distinct values: every row's k-th place falls inside a run of ties
+        x = np.round(x * 2.0) / 2.0
+        x[:, ::7] = np.sort(x[0])[-k]
+    elif case == "fewer_visible_than_k":
+        x[:, 40:] = -np.inf
+        x[1, 10:] = -np.inf
+    elif case == "all_but_one_minus_inf":
+        x[:] = -np.inf
+        x[np.arange(rows), np.arange(rows) * 11] = rng.standard_normal(rows)
+    elif case == "k_is_the_width":
+        k = width
+        x[2, 50:] = -np.inf
+    elif case == "signed_zeros":
+        x = rng.choice(np.asarray([0.0, -0.0, 0.5, -0.5, np.inf, -np.inf], np.float32), size=(rows, width))
+        x[x == np.inf] = 1.0
+        k = 40  # past the ones and halves (~32 a row): the k-th place falls among the zeros of both signs
+    return jnp.asarray(x), k
+
+
+@pytest.mark.parametrize("case", ["random", "ties_straddle_the_kth", "fewer_visible_than_k", "all_but_one_minus_inf",
+                                  "k_is_the_width", "signed_zeros"])
+def test_the_threshold_keeps_the_stable_sorts_set(case):
+    """`select_top_keys` (the k-th largest score on its bits, the ties by
+    position) keeps exactly what the stable sort it replaced kept."""
+    score, k = _score_rows(case)
+    got = np.asarray(jax.jit(latent.select_top_keys, static_argnums=1)(score, k))
+    want = np.asarray(stable_sort_mask(score, k))
+    np.testing.assert_array_equal(got, want)
+    visible = np.asarray(score > -jnp.inf).sum(-1)
+    np.testing.assert_array_equal(got.sum(-1), np.minimum(visible, k))
+    if case in ("ties_straddle_the_kth", "signed_zeros"):  # the tie-break by position was needed
+        kth = np.sort(np.asarray(score), axis=-1)[:, -k]
+        assert (np.asarray(score) >= kth[:, None]).sum(-1).max() > k
+
+
+@pytest.mark.parametrize("t", [CHUNK, 1], ids=["prefill_chunk", "decode_tick"])
+def test_a_full_layer_hands_out_the_stable_sorts_positions(t, monkeypatch):
+    """`_full_attention` at `tiny_config()` (top-16) over two lanes of 30 and
+    45 keys: the positions `taps` hands out are the set the stable sort
+    keeps, and the layer's output is what it gives under the sort's mask."""
+    cfg = latent.tiny_config()
+    a = latent.init_params(jax.random.PRNGKey(4), cfg)["layers"][0]["attn"]
+    cache = latent.init_paged_cache(cfg, {"bt": 33, "bt_w": 9}, PAGE, 16, 2, "f32")
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(5), 3)
+    lat = jax.random.normal(k1, cache["lat"].shape, jnp.float32)
+    idx = jax.random.normal(k2, cache["idx"].shape, jnp.float32)
+    bt = 1 + jnp.arange(32, dtype=jnp.int32).reshape(2, 16)
+    start = jnp.asarray([24, 40] if t > 1 else [29, 44], jnp.int32)
+    pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+    h = jax.random.normal(k3, (2, t, cfg.dim), jnp.float32)
+
+    def layer():
+        taps: list = []
+        out, *_ = latent._full_attention(a, cfg, h, pos, lat, idx, 0, bt, start, jnp.ones((2,), bool), taps)
+        return np.asarray(out), np.asarray(taps[0])
+
+    out, sel = layer()
+    monkeypatch.setattr(latent, "select_top_keys", stable_sort_mask)
+    sorted_out, sorted_sel = layer()
+    for lane in range(2):
+        for q in range(t):
+            kept = set(sel[lane, q][sel[lane, q] >= 0].tolist())
+            assert kept == set(sorted_sel[lane, q][sorted_sel[lane, q] >= 0].tolist()), (lane, q)
+            assert len(kept) == min(cfg.index_topk, int(pos[lane, q]) + 1) and max(kept) <= int(pos[lane, q])
+    np.testing.assert_allclose(out, sorted_out, atol=1e-6)
+
+
+def test_the_decode_ticks_count_the_keys_selected_and_seen():
+    """`select_kept` / `select_visible` grow by `min(top-k, ctx)` and `ctx`
+    a full layer a live lane a tick (a masked lane adds nothing; prefill
+    chunks add nothing), and the form without selection keeps its tuple."""
+    cfg = latent.tiny_config()
+    params = latent.init_params(jax.random.PRNGKey(0), cfg)
+    slots, per = 3, 16
+    ring = latent.page_kinds(cfg, PAGE, "f32")[1].ring_pages
+    cache = latent.init_paged_cache(cfg, {"bt": slots * per + 1, "bt_w": slots * ring + 1}, PAGE, per, slots, "f32")
+    cache = dict(cache, bt=1 + jnp.arange(slots * per, dtype=jnp.int32).reshape(slots, per),
+                 bt_w=1 + jnp.arange(slots * ring, dtype=jnp.int32).reshape(slots, ring))
+    assert latent.counters(cache)[0] == ("expert_rows", "expert_rows_max", "select_kept", "select_visible")
+    step = jax.jit(lambda c, i, p, s, m: latent.forward_cached(params, cfg, i, p, c, s, m))
+    ids = jnp.asarray(np.random.default_rng(1).integers(0, cfg.vocab_size, (slots, CHUNK)), jnp.int32)
+    prompt = jnp.broadcast_to(jnp.arange(CHUNK, dtype=jnp.int32), (slots, CHUNK))
+    _, cache = step(dict(cache, valid=jnp.full((slots,), CHUNK, jnp.int32)), ids, prompt,
+                    jnp.zeros((slots,), jnp.int32), jnp.ones((slots,), bool))
+    cache.pop("valid")
+    assert np.asarray(cache["sel_keys"]).tolist() == [0, 0]  # a prefill chunk is no tick
+    kept = visible = 0
+    ctx = np.asarray([3, 14, 20])
+    live = np.asarray([True, False, True])
+    for tick in range(4):
+        read = jnp.asarray(ctx - 1 + tick, jnp.int32)
+        _, cache = step(cache, ids[:, :1], read[:, None], read, jnp.asarray(live))
+        seen = ctx + tick
+        kept += 2 * int(np.minimum(seen, cfg.index_topk)[live].sum())
+        visible += 2 * int(seen[live].sum())
+        assert np.asarray(cache["sel_keys"]).tolist() == [kept, visible], tick
+    diff = latent.tiny_diff_config()
+    names, arrays = latent.counters(latent.init_paged_cache(diff, {"bt": 9, "bt_w": 9}, PAGE, 4, 2, "f32"))
+    assert names == ("expert_rows", "expert_rows_max", "mhc_row_err_max", "diff_lambda_mean") and len(arrays) == 2
+
+
 def test_the_shares_routed_parts_and_the_shared_expert_once_equal_the_uncut_layer(ref):
     """Eight chips of two experts each: their routed parts, plus the shared
     expert counted once, add up to what the uncut layer (all 16 experts on
@@ -198,6 +312,18 @@ def test_quantum_and_prefill_events_carry_the_new_counters(served):
     prefills = [e for e in events if e["ev"] == "prefill"]
     assert sum(e["tokens"] for e in prefills) == 5 + 21 + 30 + 13 + 32 + 9 + 17
     assert all(0 < e["tokens"] <= CHUNK for e in prefills)
+
+
+def test_the_quanta_carry_the_selection_counters_and_traceview_shows_them(served):
+    import importlib
+
+    cfg, _, _, _, events, _ = served
+    quanta = [e for e in events if e["ev"] == "quantum"]
+    assert all(0 <= q["select_kept"] <= q["select_visible"] for q in quanta)
+    kept, visible = sum(q["select_kept"] for q in quanta), sum(q["select_visible"] for q in quanta)
+    assert 0 < kept < visible  # contexts passed the top-k (16): the selection dropped keys
+    line = importlib.import_module("tools.traceview").selection_line(events)
+    assert line.startswith(f"key selection: {kept} of the {visible} keys") and f"({kept / visible:.3f})" in line
 
 
 def test_pool_bytes_counts_every_page_kind():
@@ -344,15 +470,17 @@ def test_gpt_serve_programs_are_byte_equal_to_the_parents(kv, program, steps):
     assert hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16] == GPT_PROGRAM_HASHES[kv, program, steps]
 
 
-# The latent family's own serve programs at `tiny_config()`, recorded on the
-# parent of PR 33 (commit 81c4036) before `latent.py` was touched: the second
-# published form of the family (grouped differential heads, streams, PolyNorm)
-# is switched by the config alone and leaves this one's programs byte-equal.
+# The latent family's own serve programs at `tiny_config()`, re-recorded when
+# the full layers' key selection became a mask over the lane's keys attended
+# by the key-block walk (the change on top of commit adc8f13; first recorded on
+# commit 81c4036): the second published form of the family (grouped
+# differential heads, streams, PolyNorm) is switched by the config alone and
+# leaves this one's programs byte-equal.
 LATENT_PROGRAM_HASHES = {
-    ("f32", "decode_step", 1): "11ebc88fcb39c891", ("f32", "decode_step", 4): "e87790ae704f5433",
-    ("f32", "prefill_chunk_paged", 0): "fac06f97e645181f",
-    ("bf16", "decode_step", 1): "de0e8312eb18f8b4", ("bf16", "decode_step", 4): "8edb8c5b30c78f38",
-    ("bf16", "prefill_chunk_paged", 0): "b07a56753706ffb8",
+    ("f32", "decode_step", 1): "e32408e8350e4512", ("f32", "decode_step", 4): "ef2270706ccb6c80",
+    ("f32", "prefill_chunk_paged", 0): "d789a1c78ef010af",
+    ("bf16", "decode_step", 1): "787a2dcebe157198", ("bf16", "decode_step", 4): "521b4e7b648e7961",
+    ("bf16", "prefill_chunk_paged", 0): "824daa940b1573e8",
 }
 
 

@@ -85,6 +85,19 @@ def render(trees: list[dict], trace_lib) -> str:
     return "\n".join(out)
 
 
+def selection_line(events: list[dict]) -> str | None:
+    """The share of the context a latent model's key selection kept in the
+    decode ticks (the quanta's `select_kept` / `select_visible`); None for a
+    model that selects no keys."""
+    quanta = [e for e in events if e.get("ev") == "quantum" and "select_visible" in e]
+    visible = sum(e["select_visible"] for e in quanta)
+    if not visible:
+        return None
+    kept = sum(e["select_kept"] for e in quanta)
+    return (f"key selection: {kept} of the {visible} keys the decode ticks' queries "
+            f"could see kept ({kept / visible:.3f}), {len(quanta)} quanta")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("log", help="metrics JSONL from a --metrics_log run")
@@ -120,6 +133,9 @@ def main(argv=None) -> int:
             return 1
 
     print(render(trees, trace_lib))
+    selected = selection_line(events)
+    if selected:
+        print(selected)
     if args.out:
         chrome = trace_lib.to_chrome(events)
         with open(args.out, "w") as f:

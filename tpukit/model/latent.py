@@ -93,6 +93,8 @@ FULL, WINDOW = "full_attention", "sliding_attention"
 ATTEND_BLOCK = 256  # queries attended at a time: bounds the gathered latents and the scores
 INDEX_KEY_BLOCK = 2048  # keys scored at a time by the indexer: bounds [queries, heads, keys]
 KEY_BLOCK = 512  # keys a full layer without selection attends at a time, whole pages of one block-table stretch
+SELECT_KEY_BLOCK = 256  # the same for a full layer that selects its keys (fastest on a v5e of 128, 256, 512)
+SELECT_RADIX_BITS = 2  # bits of the k-th largest indexer score settled a pass (fastest on a v5e of 2, 4, 8)
 
 
 class ServedOnlyError(NotImplementedError):
@@ -456,8 +458,9 @@ def init_paged_cache(cfg: LatentConfig, num_pages: dict, page_size: int, pages_p
     and `moe_rows`, two counters the decode ticks add to (rows the held
     experts computed; the fullest expert's, summed over layers and ticks).
     `num_pages`: pages of each pool, by block-table key. Without key selection
-    there is no `idx`; with hyper-connections there is `health`, two float32
-    gauges of the decode ticks (`counters`)."""
+    there is no `idx` and no `sel_keys` (two counters of the decode ticks'
+    selection, `counters`); with hyper-connections there is `health`, two
+    float32 gauges of the decode ticks."""
     from tpukit.serve import paged as paged_lib
 
     if kv_dtype == "int8":
@@ -472,6 +475,7 @@ def init_paged_cache(cfg: LatentConfig, num_pages: dict, page_size: int, pages_p
     }
     if cfg.index_topk:
         cache["idx"] = paged_lib.init_row_pool(full.layers, num_pages["bt"], page_size, cfg.index_dim, kv_dtype)
+        cache["sel_keys"] = jnp.zeros((2,), jnp.int32)
     if cfg.streams > 1:
         cache["health"] = jnp.zeros((2,), jnp.float32)
     return cache
@@ -501,10 +505,15 @@ def counters(cache: dict) -> tuple:
     `mhc_row_err_max`, the largest `|row sum - 1|` of a stream-mixing matrix
     after its Sinkhorn iterations in any decode tick and layer so far, and
     `diff_lambda_mean`, the mean lambda over live lanes, layers and signal
-    heads of the last tick that had a live lane."""
+    heads of the last tick that had a live lane. Where keys are selected,
+    `select_kept` and `select_visible`: the keys the full layers' queries of
+    the live lanes kept and could see, summed over layers and ticks (their
+    ratio is the share of the context the selection keeps)."""
     if "health" in cache:
         return (("expert_rows", "expert_rows_max", "mhc_row_err_max", "diff_lambda_mean"),
                 (cache["moe_rows"], cache["health"]))
+    if "sel_keys" in cache:
+        return ("expert_rows", "expert_rows_max", "select_kept", "select_visible"), (cache["moe_rows"], cache["sel_keys"])
     return ("expert_rows", "expert_rows_max"), (cache["moe_rows"],)
 
 
@@ -606,33 +615,29 @@ def _latent_qkv(a, cfg: LatentConfig, spec: AttnSpec, h, pos):
     return c_q, q, row
 
 
-def _softmax_latents(q_cat, keys, mask, spec: AttnSpec, dtype, per_query_keys: bool = False):
-    """Absorbed queries `q_cat [Q, H, kv_rank + rope]` over latent rows `keys`
-    (`[Q, K, R]` a set per query, or `[K, R]` shared) under `mask [Q, K]`: the
-    softmax-weighted latents `[Q, H, kv_rank]` float32. The softmax is
-    float32; a query with no key yet (never read) gives 0, not NaN."""
-    ks = "qkr" if per_query_keys else "kr"
-    scores = jnp.einsum(f"qhr,{ks}->qhk", q_cat, keys, preferred_element_type=jnp.float32)
+def _softmax_latents(q_cat, keys, mask, spec: AttnSpec, dtype):
+    """Absorbed queries `q_cat [Q, H, kv_rank + rope]` over the latent rows
+    `keys [K, R]` they share, under `mask [Q, K]`: the softmax-weighted
+    latents `[Q, H, kv_rank]` float32. The softmax is float32; a query with
+    no key yet (never read) gives 0, not NaN."""
+    scores = jnp.einsum("qhr,kr->qhk", q_cat, keys, preferred_element_type=jnp.float32)
     scores = scores * (1.0 / math.sqrt(spec.nope + spec.rope))
     scores = jnp.where(mask[:, None, :], scores, -jnp.inf)
     top = jnp.max(scores, axis=-1, keepdims=True)
     e = jnp.exp(scores - jnp.where(jnp.isfinite(top), top, 0.0))
     probs = (e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)).astype(dtype)
-    vs = "qkc" if per_query_keys else "kc"
-    return jnp.einsum(f"qhk,{vs}->qhc", probs, keys[..., : spec.kv_rank], preferred_element_type=jnp.float32)
+    return jnp.einsum("qhk,kc->qhc", probs, keys[..., : spec.kv_rank], preferred_element_type=jnp.float32)
 
 
-def _attend(q, kv_b, keys, mask, spec: AttnSpec, dtype, per_query_keys: bool):
-    """Absorbed attention of the queries `q [Q, H, nope + rope]` over latent
-    rows `keys` (`[Q, K, R]` a set per query, or `[K, R]` shared) under `mask
-    [Q, K]`, one KV head a query head. `q_n` is carried into the latent space
-    through `kv_b`'s key half (`q_n . (c W_k) = (q_n W_k^T) . c`) and the
-    weighted latents come back through `kv_b`'s value half: `[Q, H, v]`. Done
-    a block of queries at a time, so the `[Q, H, kv_rank]` intermediates never
-    exist for a whole chunk."""
+def _attend(q, kv_b, keys, mask, spec: AttnSpec, dtype):
+    """Absorbed attention of the queries `q [Q, H, nope + rope]` over the
+    latent rows `keys [K, R]` they share, under `mask [Q, K]`, one KV head a
+    query head. `q_n` is carried into the latent space through `kv_b`'s key
+    half (`q_n . (c W_k) = (q_n W_k^T) . c`) and the weighted latents come
+    back through `kv_b`'s value half: `[Q, H, v]`."""
     q_abs = _mm(q[..., : spec.nope], kv_b[..., : spec.nope], dtype, "qhd,chd->qhc")
     q_cat = jnp.concatenate([q_abs.astype(dtype), q[..., spec.nope:]], axis=-1)
-    o_heads = _softmax_latents(q_cat, keys, mask, spec, dtype, per_query_keys)
+    o_heads = _softmax_latents(q_cat, keys, mask, spec, dtype)
     return _mm(o_heads, kv_b[..., spec.nope:], dtype, "qhc,chv->qhv").astype(dtype)
 
 
@@ -663,28 +668,34 @@ def _diff_values(o_lat, lam, kv_b, spec: AttnSpec, dtype):
     return out.reshape(lead + (spec.out_heads, spec.v)).astype(dtype)
 
 
-def _attend_paged(q_cat, pool, li: int, bt, q_pos, spec: AttnSpec, dtype):
+def _attend_paged(q_cat, pool, li: int, bt, q_pos, spec: AttnSpec, dtype, select=None):
     """Absorbed queries `q_cat [B, Q, H, kv_rank + rope]` at positions `q_pos
-    [B, Q]` over EVERY earlier key of their lane (`walk_key_blocks`): a row's
-    whole width is its key, its latent the value. Returns the weighted
-    latents `[B, Q, H, kv_rank]` float32."""
+    [B, Q]` over EVERY earlier key of their lane (`KEY_BLOCK` keys at a time),
+    or over those `select [B, Q, MP * P]` marks (`SELECT_KEY_BLOCK` at a
+    time), by `walk_key_blocks`: a row's whole width is its key, its latent
+    the value. Returns the weighted latents `[B, Q, H, kv_rank]` float32."""
     return walk_key_blocks(q_cat, pool, li, bt, q_pos, dtype, 1.0 / math.sqrt(spec.nope + spec.rope),
-                           values=slice(None, spec.kv_rank))
+                           values=slice(None, spec.kv_rank), select=select,
+                           key_block=KEY_BLOCK if select is None else SELECT_KEY_BLOCK)
 
 
-def walk_key_blocks(q, pool, li: int, bt, q_pos, dtype, scale: float, values: slice, keys: slice | None = None):
+def walk_key_blocks(q, pool, li: int, bt, q_pos, dtype, scale: float, values: slice, keys: slice | None = None,
+                    select=None, key_block: int = KEY_BLOCK):
     """Queries `q [B, Q, H, K]` at positions `q_pos [B, Q]` over EVERY earlier
     key of their lane, read where it lies: the lane's pages of layer `li` of
-    `pool [L, NP, P, row]`, `KEY_BLOCK` keys (a stretch of the block table `bt
+    `pool [L, NP, P, row]`, `key_block` keys (a stretch of the block table `bt
     [B, MP]`) at a time, folded under a running max and sum. A row's columns
     `keys` (all of them: None) are the key a query's score reads, its columns
-    `values` the value it gives. The walk ends at the last block any of these
-    queries can see. Returns `[B, Q, H, width of values]` float32. Shared by
-    the latent family's full layers and the hybrid family's attention
-    layers (tpukit/model/hybrid.py)."""
+    `values` the value it gives. `select [B, Q, MP * P]` (bool, by position in
+    the lane), where given, marks the keys each query attends in place of all
+    it can see: a selected key is a seen one. The walk ends at the last block
+    any of these queries can see. Returns `[B, Q, H, width of values]`
+    float32. Shared by the latent family's full layers, with and without key
+    selection, and the hybrid family's attention layers
+    (tpukit/model/hybrid.py)."""
     b, nq, h, _ = q.shape
     p = pool.shape[2]
-    pages = math.gcd(bt.shape[1], max(1, KEY_BLOCK // p))
+    pages = math.gcd(bt.shape[1], max(1, key_block // p))
     kb = pages * p
     offs = jnp.arange(kb, dtype=jnp.int32)
     width = len(range(pool.shape[3])[values])
@@ -695,15 +706,21 @@ def walk_key_blocks(q, pool, li: int, bt, q_pos, dtype, scale: float, values: sl
         rows = pool[li, pids].reshape(b, kb, -1).astype(dtype)
         k = rows if keys is None else rows[..., keys]
         scores = jnp.einsum("bqhr,bkr->bqhk", q, k, preferred_element_type=jnp.float32) * scale
-        seen = (j * kb + offs)[None, None, :] <= q_pos[:, :, None]
+        if select is None:
+            seen = (j * kb + offs)[None, None, :] <= q_pos[:, :, None]
+        else:
+            seen = jax.lax.dynamic_slice_in_dim(select, j * kb, kb, axis=2)
         # key 0 is every query's, so from the first block on the running max is finite and a block
-        # with no key of some query adds exp(-inf) = 0 to it
+        # with no key of some query adds exp(-inf) = 0 to it; a query's first SELECTED key may lie in
+        # a later block, so under a selection the running max starts at the least finite float: a
+        # block with none of its keys then rescales by exp(0) = 1 and adds exp(-inf) = 0
         m, l, fix, e = online_softmax_update(m, l, jnp.where(seen[:, :, None, :], scores, -jnp.inf))
         acc = acc * fix + jnp.einsum("bqhk,bkc->bqhc", e.astype(dtype), rows[..., values],
                                      preferred_element_type=jnp.float32)
         return m, l, acc
 
-    init = (jnp.full((b, nq, h, 1), -jnp.inf, jnp.float32), jnp.zeros((b, nq, h, 1), jnp.float32),
+    m0 = -jnp.inf if select is None else jnp.finfo(jnp.float32).min
+    init = (jnp.full((b, nq, h, 1), m0, jnp.float32), jnp.zeros((b, nq, h, 1), jnp.float32),
             jnp.zeros((b, nq, h, width), jnp.float32))
     _, l, acc = jax.lax.fori_loop(0, jnp.max(q_pos) // kb + 1, block, init)
     return acc / l
@@ -719,12 +736,13 @@ def _finish_attention(a, cfg: LatentConfig, spec: AttnSpec, h, o):
     return _mm(o, a["o"], dt, "bthv,hvd->btd")
 
 
-def _blocks(t: int) -> tuple[int, int]:
+def _blocks(b: int, t: int) -> tuple[int, int]:
     """Queries of one lane attended at a time, and how many (lane, block)
     pairs run side by side: a decode tick (t = 1) takes every lane at once, a
-    prefill chunk one block of one lane after the other."""
-    qb = math.gcd(t, ATTEND_BLOCK)
-    return qb, max(1, ATTEND_BLOCK // qb)
+    prefill chunk one block of one lane after the other (each walk then ends
+    at its own lane's last key: two lanes side by side took 1.3 times as long
+    on a v5e)."""
+    return math.gcd(t, ATTEND_BLOCK), b if t == 1 else 1
 
 
 def index_scores(q_idx, w_idx, keys, q_pos):
@@ -742,6 +760,41 @@ def index_scores(q_idx, w_idx, keys, q_pos):
     score = jax.lax.map(block, keys.reshape(s // kb, kb, -1))  # [S/kb, Q, kb]
     score = jnp.moveaxis(score, 0, 1).reshape(q_idx.shape[0], s)
     return jnp.where(jnp.arange(s)[None, :] <= q_pos[:, None], score, -jnp.inf)
+
+
+def _order_key(score):
+    """float32 -> uint32 in the same order, the two zeros made one (as the
+    stable sort's comparator has them); `-inf` is the least key."""
+    bits = jax.lax.bitcast_convert_type(score, jnp.uint32)
+    bits = jnp.where(score == 0, jnp.uint32(0), bits)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def select_top_keys(score, k: int):
+    """Which keys a query attends: a mask `[..., S]` of the `k` of largest
+    `score [..., S]` (float32, `-inf` for a key it cannot see), exactly the
+    set that a stable sort of `-score` keeps in its first `k` places. The
+    k-th largest score is found on its order-preserving bits, a digit of
+    `SELECT_RADIX_BITS` bits a pass over the row: the pass counts the keys at
+    or above each candidate value of the digit, and the digit is the number
+    of candidates that still have `k`. The keys above it are kept, and of
+    those equal to it the first by position, as many as there is room for.
+    No `-inf` key is ever kept: with fewer than `k` keys to see, a query
+    keeps all of them."""
+    u = _order_key(score)
+    radix = 1 << SELECT_RADIX_BITS
+    digits = jnp.arange(1, radix, dtype=jnp.uint32)
+    kth = jnp.zeros(score.shape[:-1] + (1,), jnp.uint32)
+    for shift in range(32 - SELECT_RADIX_BITS, -1, -SELECT_RADIX_BITS):
+        at_least = jnp.sum(u[..., None, :] >= (kth | (digits << shift))[..., :, None], axis=-1)  # [..., radix - 1]
+        kth = kth | (jnp.sum(at_least >= k, axis=-1, keepdims=True).astype(jnp.uint32) << shift)
+    above = u > kth
+    tied = (u == kth) & (score > -jnp.inf)
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    # ties straddle the k-th place only on exactly equal scores; then the stable sort's rule, by position
+    first = jax.lax.cond(jnp.any(jnp.sum(tied, axis=-1, keepdims=True) > room),
+                         lambda: jnp.cumsum(tied, axis=-1) <= room, lambda: jnp.ones_like(tied))
+    return above | (tied & first)
 
 
 def _page_ids(bt, start, write_mask, p: int, t: int):
@@ -767,9 +820,14 @@ def _write_rows(pool, li, pids, start, rows):
 def _full_attention(a, cfg: LatentConfig, h, pos, lat, idx, li, bt, start, write_mask, taps):
     """A full layer over the paged cache: write this chunk's latent rows and
     indexer keys (a token, or whole pages), then every query scores its
-    lane's keys, selects, gathers the selected latents from the pool and
-    attends them. `lat` / `idx` are the stacked pools, `li` this layer's
-    index among the full layers. Returns `(out, lat, idx)`."""
+    lane's keys, selects them as a mask (`select_top_keys`) and attends the
+    selected latents where they lie (`_attend_paged`), the (lane, query
+    block) pairs of `_blocks` (a prefill chunk one lane's block a step, a
+    tick every lane at once), each walk reading its lane's pages once for
+    all its queries. `lat` / `idx` are the stacked pools, `li` this layer's
+    index among the full layers. Returns `(out, lat, idx, seen)`: `seen`, in
+    a decode tick, `[kept, visible]`, the keys the live lanes' queries
+    selected and could see; None in a prefill chunk."""
     spec, dt = cfg.full, cfg.compute_dtype
     b, t = h.shape[0], h.shape[1]
     p = lat.shape[2]
@@ -787,38 +845,39 @@ def _full_attention(a, cfg: LatentConfig, h, pos, lat, idx, li, bt, start, write
 
     s_max = bt.shape[1] * p
     topk = min(cfg.index_topk, s_max)
-    qb, side = _blocks(t)
+    qb, side = _blocks(b, t)
+    pairs = b * (t // qb)
 
-    rows_of_pool = lat.reshape(-1, lat.shape[-1])  # [L * NP * P, row]: a token's row by one flat index
-    key_pos = jnp.arange(s_max, dtype=jnp.int32)
-
-    def block(xs):
-        bt_row, q_i, w_i, q_c, q_pos = xs
+    def block(xs):  # `side` (lane, query block) pairs
+        bt_rows, q_i, w_i, q_c, q_pos = xs
         with jax.named_scope("indexer"):
-            keys = idx[li, bt_row].reshape(s_max, -1)
-            score = index_scores(q_i, w_i, keys, q_pos)
+            keys = idx[li, bt_rows].reshape(side, s_max, -1)
+            score = jax.vmap(index_scores)(q_i, w_i, keys, q_pos)
         with jax.named_scope("select"):
-            # the top-k as a sort that carries, with each key's position, the pool row it lives in:
-            # looking 2,048 positions a query up in the block table afterwards costs more than the sort
-            row_of_key = (li * lat.shape[1] + jnp.repeat(bt_row, p)) * p + key_pos % p
-            carried = (row_of_key,) if taps is None else (row_of_key, key_pos)  # the positions only for who asks
-            worst_first, rows, *sel = jax.lax.sort(
-                (-score, *(jnp.broadcast_to(v, score.shape) for v in carried)),
-                dimension=1, num_keys=1, is_stable=True)
-            chosen = worst_first[:, :topk] < jnp.inf  # fewer than top-k keys so far: the rest are no keys
-        with jax.named_scope("latent_gather"):
-            got = rows_of_pool[rows[:, :topk]]  # [qb, topk, row]
+            chosen = select_top_keys(score, topk)  # [side, qb, s_max]
         with jax.named_scope("attend"):
-            out = _attend(q_c, a["kv_b"], got, chosen, spec, dt, per_query_keys=True)
-        return out, [jnp.where(chosen, s[:, :topk], -1) for s in sel]
+            q_abs = _mm(q_c[..., : spec.nope], a["kv_b"][..., : spec.nope], dt, "bqhd,chd->bqhc")
+            q_cat = jnp.concatenate([q_abs.astype(dt), q_c[..., spec.nope:]], axis=-1)
+            o_lat = _attend_paged(q_cat, lat, li, bt_rows, q_pos, spec, dt, select=chosen)
+            out = _mm(o_lat, a["kv_b"][..., spec.nope:], dt, "bqhc,chv->bqhv").astype(dt)
+        extra = {}
+        if t == 1:
+            extra["kept"] = jnp.sum(chosen, axis=(1, 2))
+        if taps is not None:  # the kept positions, largest first, -1 past the last; only for who asks
+            extra["sel"] = jax.lax.top_k(jnp.where(chosen, jnp.arange(s_max, dtype=jnp.int32), -1), topk)[0]
+        return out, extra
 
-    per_block = lambda z: z.reshape((b * (t // qb), qb) + z.shape[2:])  # noqa: E731
-    o_heads, sel = jax.lax.map(
-        block, (jnp.repeat(bt, t // qb, axis=0), per_block(q_idx), per_block(w_idx),
-                per_block(q), per_block(pos)), batch_size=side)
+    group = lambda z: z.reshape((pairs // side, side, qb) + z.shape[2:])  # noqa: E731
+    o_heads, extra = jax.lax.map(
+        block, (jnp.repeat(bt, t // qb, axis=0).reshape(pairs // side, side, -1), group(q_idx), group(w_idx),
+                group(q), group(pos)))
     if taps is not None:
-        taps.append(sel[0].reshape(b, t, topk))
-    return _finish_attention(a, cfg, spec, h, o_heads.reshape(b, t, spec.heads, spec.v)), lat, idx
+        taps.append(extra["sel"].reshape(b, t, topk))
+    seen = None
+    if t == 1:
+        seen = jnp.stack([jnp.sum(jnp.where(write_mask, extra["kept"].reshape(b), 0)),
+                          jnp.sum(jnp.where(write_mask, pos[:, 0] + 1, 0))])
+    return _finish_attention(a, cfg, spec, h, o_heads.reshape(b, t, spec.heads, spec.v)), lat, idx, seen
 
 
 def _lambda(a, cfg: LatentConfig, spec: AttnSpec, h, health):
@@ -894,7 +953,7 @@ def _window_attention(a, cfg: LatentConfig, h, pos, win, lw, bt_w, start, write_
                 & (kpos[None, :] >= 0))
         with jax.named_scope("window_attend"):
             if not spec.kv_heads:
-                return _attend(q_c, a["kv_b"], keys, mask, spec, dt, per_query_keys=False)
+                return _attend(q_c, a["kv_b"], keys, mask, spec, dt)
             o_lat = _softmax_latents(_absorb_grouped(q_c, a["kv_b"], spec, dt), keys.astype(dt), mask, spec, dt)
             return _diff_values(o_lat, lam_c[0] if lam_c else None, a["kv_b"], spec, dt)
 
@@ -977,6 +1036,7 @@ def _forward_cached(params: Params, cfg: LatentConfig, input_ids, position_ids, 
     if write_mask is None:
         write_mask = jnp.ones((b,), bool)
     lat, idx, win, rows_seen = cache["lat"], cache.get("idx"), cache["win"], cache["moe_rows"]
+    keys_seen = cache.get("sel_keys")
     valid = cache.get("valid")
     # the decode ticks' gauges, where the cache keeps them: what the sublayers hand out
     health = {"row_err": [], "lambda": []} if t == 1 and "health" in cache else None
@@ -995,8 +1055,10 @@ def _forward_cached(params: Params, cfg: LatentConfig, input_ids, position_ids, 
                                           cache["bt_w"], start, write_mask, valid, health)
             lw += 1
         elif cfg.index_topk:
-            attn, lat, idx = _full_attention(layer["attn"], cfg, h, position_ids, lat, idx, lf,
-                                             cache["bt"], start, write_mask, taps)
+            attn, lat, idx, seen = _full_attention(layer["attn"], cfg, h, position_ids, lat, idx, lf,
+                                                   cache["bt"], start, write_mask, taps)
+            if seen is not None:  # the decode ticks' account of the selection
+                keys_seen = keys_seen + seen
             lf += 1
         else:
             attn, lat = _dense_attention(layer["attn"], cfg, h, position_ids, lat, lf,
@@ -1024,7 +1086,7 @@ def _forward_cached(params: Params, cfg: LatentConfig, input_ids, position_ids, 
         logits = _mm(x, params["lm_head"], cfg.compute_dtype).astype(cfg.compute_dtype)
     out = dict(cache, lat=lat, win=win, moe_rows=rows_seen)
     if idx is not None:
-        out["idx"] = idx
+        out["idx"], out["sel_keys"] = idx, keys_seen
     if health is not None:
         out["health"] = _health(cache["health"], health, write_mask)
     return logits, out

@@ -311,9 +311,10 @@ def flush_to_logger(tracer: TraceRecorder, logger, trees=()) -> None:
 
 
 # what a quantum's dispatch bar shows when clicked: who rode it, what the
-# host did before it, and the slot counters
+# host did before it, the slot counters, and a latent model's key selection
 _QUANTUM_ARGS = ("lanes", "host", "decoding", "prefilling", "pending",
-                 "free_pages", "delivered", "finished")
+                 "free_pages", "delivered", "finished", "select_kept",
+                 "select_visible")
 
 
 def to_chrome(events: list[dict]) -> dict:

@@ -1158,7 +1158,8 @@ class ServeEngine:
                 gauge = np.concatenate([np.full(c.size, c.dtype.kind == "f") for c in counters])
                 seen = np.concatenate([c.ravel().astype(np.float64) for c in counters])
                 if self._quantum is not None:
-                    since = np.where(gauge, seen, seen - self._counters)
+                    # a 32-bit count wraps in a long run: its difference modulo 2**32
+                    since = np.where(gauge, seen, (seen - self._counters) % 2**32)
                     self._quantum.update(
                         (k, float(v) if g else int(v)) for k, v, g in zip(names, since, gauge))
                 self._counters = seen

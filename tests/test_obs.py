@@ -159,6 +159,63 @@ def test_lap_returns_the_walls_since_the_last_lap():
     assert "admit" not in tl.window()["seconds"]
 
 
+def _busy(seconds):
+    """Spin until this thread has run `seconds` on a CPU."""
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+@pytest.mark.parametrize("body", ["sleep", "busy"])
+def test_a_span_reads_the_threads_cpu_beside_its_wall(body):
+    """A span that sleeps 50 ms is a wait: its CPU is below a tenth of its
+    wall. A span that spins is work: its CPU is at least four fifths of its
+    wall (in one of three tries: a loaded machine can take the CPU away from
+    a spinning thread too). The lap's CPU has the walls' keys, and `other`
+    holds the CPU between the two lap points outside every span."""
+    shares = []
+    for _ in range(3):
+        tl = SpanTimeline(cpu=True)
+        with tl.span("phase") as sp:
+            (time.sleep if body == "sleep" else _busy)(0.05)
+        _busy(0.01)  # outside every span: `other`
+        walls, cpu = tl.lap(), tl.lap_cpu()
+        wall = sp.t1 - sp.t0
+        assert walls["phase"] == pytest.approx(wall) and cpu["phase"] == sp.cpu
+        assert set(cpu) == {"phase", "other"} and 0.0 <= cpu["phase"] <= wall
+        assert cpu["other"] >= 0.01
+        assert tl.lap() == {} and set(tl.lap_cpu()) == {"other"}  # a new lap starts empty
+        shares.append(cpu["phase"] / wall)
+    if body == "sleep":
+        assert max(shares) < 0.1
+    else:
+        assert max(shares) >= 0.8 and min(shares) > 0.0
+
+
+def test_nested_spans_charge_their_cpu_to_the_outer_one_only():
+    tl = SpanTimeline(cpu=True)
+    with tl.span("sync") as outer:
+        with tl.span("sync_wait") as inner:
+            _busy(0.02)
+    tl.lap()
+    cpu = tl.lap_cpu()
+    assert "sync_wait" not in cpu and cpu["sync"] == outer.cpu >= inner.cpu >= 0.02
+    assert outer.cpu <= outer.t1 - outer.t0 and inner.cpu <= inner.t1 - inner.t0
+
+
+def test_a_timeline_without_cpu_reads_no_thread_clock(monkeypatch):
+    def refuse():
+        raise AssertionError("thread_time read on a timeline without cpu=True")
+
+    monkeypatch.setattr(time, "thread_time", refuse)
+    tl = SpanTimeline()
+    with tl.span("admit") as sp:
+        with tl.span("inner"):
+            pass
+    assert set(tl.lap()) == {"admit"} and tl.lap_cpu() == {} and sp.cpu == 0.0
+    tl.epoch()
+
+
 def test_prefetcher_worker_runs_process_inside_the_span_it_was_given():
     from tpukit.prefetch import HostPrefetcher
 

@@ -119,9 +119,11 @@ def test_summary_carries_phase_stats_and_attribution(tok, cfg, params):
     assert s["other_s"] >= 0.0
     named = s["prefill_s"] + s["decode_s"] + s["sync_s"] + s["other_s"]
     assert named == pytest.approx(s["wall_s"], rel=0.05)
-    # dispatch-vs-device attribution present and sane
-    assert s["dispatch_overhead_s"] > 0 and s["device_s"] >= 0
-    assert s["device_s"] == s["sync_s"]
+    # the host's work apart from its waits: every quantum's host walls come
+    # with their CPU, and its sync is split where the device's answer was ready
+    quanta = [e for e in tracer.snapshot() if e["ev"] == "quantum"]
+    assert quanta and all(set(q["host_cpu"]) == set(q["host"]) for q in quanta)
+    assert all(q["s0"] <= q["s_ready"] <= q["s1"] for q in quanta)
 
 
 def test_window_records_carry_attribution(tok, cfg, params, tmp_path):
@@ -134,15 +136,17 @@ def test_window_records_carry_attribution(tok, cfg, params, tmp_path):
     assert wins
     for w in wins:
         assert w["other_s"] >= 0.0
-        assert w["dispatch_overhead_s"] >= 0.0
-        assert w["device_s"] == pytest.approx(w["seconds"].get("sync", 0.0))
+    # the quanta's CPU readings and ready stamps reach the JSONL's trace rows
+    quanta = [r for r in recs if r["kind"] == "trace_event" and r["ev"] == "quantum"]
+    assert quanta and all(0.0 <= sum(q["host_cpu"].values()) <= sum(q["host"].values()) + 1e-4 for q in quanta)
+    assert all(q["s0"] <= q["s_ready"] <= q["s1"] for q in quanta)
 
 
 # ---------------------------------------------------------------------------
 # The quantum's host account, slot counters and token-delivery stamps.
 # ---------------------------------------------------------------------------
 
-HOST_SPANS = {"admit", "place", "prefill", "decode", "retire", "window", "idle", "other"}
+HOST_SPANS = {"admit", "place", "prefill_batch", "prefill", "decode", "retire", "window", "idle", "other"}
 PAGED = dict(page_size=8, prefill_chunk=8)
 
 
@@ -166,7 +170,13 @@ def test_quantum_host_walls_counters_and_deliveries(tok, cfg, params, serve_kw, 
         assert sum(q["host"].values()) == pytest.approx(q["s0"] - s1_prev, abs=1e-6)
         # the dispatch wall is one of the host walls, read from the same span
         assert q["host"]["decode"] == pytest.approx(q["t1"] - q["t0"], abs=1e-9)
-        assert s1_prev <= q["t0"] <= q["t1"] <= q["s0"] <= q["s1"]
+        assert s1_prev <= q["t0"] <= q["t1"] <= q["s0"] <= q["s_ready"] <= q["s1"]
+        # each wall's thread-CPU: the same keys, never more than the wall
+        assert set(q["host_cpu"]) == set(q["host"])
+        assert all(0.0 <= q["host_cpu"][k] <= q["host"][k] + 1e-4 for k in q["host"])
+        assert set(q["sync_cpu"]) == {"sync_wait", "sync_fetch"}
+        assert q["sync_cpu"]["sync_wait"] <= q["s_ready"] - q["s0"] + 1e-4
+        assert q["sync_cpu"]["sync_fetch"] <= q["s1"] - q["s_ready"] + 1e-4
         s1_prev = q["s1"]
         # counters read at the dispatch
         assert q["decoding"] == len(q["lanes"]) >= 1
@@ -181,6 +191,10 @@ def test_quantum_host_walls_counters_and_deliveries(tok, cfg, params, serve_kw, 
         assert any(q["host"].get("admit", 0) > 0 for q in quanta)
         assert any(q["host"].get("place", 0) > 0 for q in quanta)
         assert any(q["host"].get("prefill", 0) > 0 for q in quanta)
+        assert any(q["host"].get("prefill_batch", 0) > 0 for q in quanta)
+    # a prefill event carries the CPU of the dispatch span its wall covers
+    prefills = [e for e in tracer.snapshot() if e["ev"] == "prefill"]
+    assert prefills and all(0.0 <= e["cpu"] <= e["t1"] - e["t0"] + 1e-4 for e in prefills)
     assert any(q["host"].get("retire", 0) > 0 for q in quanta)
     assert any(q["host"].get("window", 0) > 0 for q in quanta)
     if qps:  # an open loop's sleeping is named, so it cannot read as host work
@@ -199,10 +213,17 @@ def test_quantum_host_walls_counters_and_deliveries(tok, cfg, params, serve_kw, 
         assert finish_t[c.rid] == c.last_token_s
 
 
-def test_untraced_engine_keeps_no_ring_but_stamps_deliveries(tok, cfg, params):
+def test_untraced_engine_keeps_no_ring_but_stamps_deliveries(tok, cfg, params, monkeypatch):
+    import time
+
     serve = ServeConfig(slots=2, buckets=(8, 16), max_new_tokens=MAX_NEW, **PAGED)
     reqs = synthetic_request_stream(tok, 4, seed=3, max_new_tokens=MAX_NEW, buckets=(8, 16))
     eng = ServeEngine(params, cfg, serve, eos_id=int(tok.eos_token_id))
+
+    def refuse():
+        raise AssertionError("an untraced engine read the thread's CPU clock")
+
+    monkeypatch.setattr(time, "thread_time", refuse)  # its spans read the wall alone
     comps = eng.run(list(reqs), max_wall_s=300)
     assert all(sum(n for _, n in c.deliveries) == c.generated for c in comps)
     assert eng._quantum is None and 0 < len(eng._periods) <= 64
@@ -251,12 +272,21 @@ def test_trees_and_chrome_export_hold_on_the_extended_events(tok, cfg, params):
     chrome = trace_lib.to_chrome(events)
     json.dumps(chrome)  # every field is serialisable
     bars = [e for e in chrome["traceEvents"] if e.get("cat") == "quantum" and e["name"].startswith("dispatch")]
-    assert bars and all({"lanes", "host", "delivered", "finished"} <= set(b["args"]) for b in bars)
-    # events written before this PR (no host, no counters) still export
+    assert bars and all({"lanes", "host", "host_cpu", "s_ready", "delivered", "finished"} <= set(b["args"])
+                        for b in bars)
+    # the sync is drawn as two bars: the wait for the device, then the fetch
+    waits = [e for e in chrome["traceEvents"] if e.get("name") == "sync wait"]
+    fetches = [e for e in chrome["traceEvents"] if e.get("name") == "sync fetch"]
+    assert len(waits) == len(fetches) == len(bars)
+    assert all(w["ts"] + w["dur"] == pytest.approx(f["ts"], abs=1.0) for w, f in zip(waits, fetches))
+    prefill_bars = [e for e in chrome["traceEvents"] if e.get("cat") == "prefill"]
+    assert prefill_bars and all({"rid", "cpu"} <= set(b["args"]) for b in prefill_bars)
+    # events written before the sync was split (no host, no counters, no
+    # ready stamp) still export, each sync as one bar
     old = [{k: v for k, v in e.items() if k in ("ev", "trace", "t0", "t1", "s0", "s1", "steps", "lanes", "replica")}
            if e["ev"] == "quantum" else e for e in events]
     assert trace_lib.completeness(trace_lib.build_trees(old)) == 1.0
-    assert len(trace_lib.to_chrome(old)["traceEvents"]) == len(chrome["traceEvents"])
+    assert len(trace_lib.to_chrome(old)["traceEvents"]) == len(chrome["traceEvents"]) - len(fetches)
 
 
 # ---------------------------------------------------------------------------
@@ -460,7 +490,7 @@ def test_jsonl_rows_and_report_gate(tok, cfg, params, tmp_path):
     text = report.summarize(recs)
     assert "request phases p50/p99" in text
     assert "100% complete span trees" in text
-    assert "dispatch vs device" in text
+    assert "/ sync " in text and "/ other " in text  # the summary's wall split
     # exit-2 wiring
     assert report.main([str(log), "--min_trace_complete", "1.0"]) == 0
     assert report.main([str(log), "--min_trace_complete", "1.1"]) == 2
@@ -473,6 +503,7 @@ def test_traceview_renders_and_exports(tok, cfg, params, tmp_path, capsys):
     assert traceview.main([str(log), "--out", str(out)]) == 0
     text = capsys.readouterr().out
     assert "== request traces ==" in text and "100% complete" in text
+    assert "host per quantum (ms wall/cpu/wait" in text and "sync per quantum (ms wall/cpu): wait " in text
     chrome = json.loads(out.read_text())
     assert chrome["traceEvents"]
     # one closed phase-bar set per completed request in the export

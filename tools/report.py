@@ -494,14 +494,6 @@ def summarize(records: list[dict]) -> str:
              else "")
           + f")   evicted: "
           f"{r.get('evicted_eos', 0)} eos, {r.get('evicted_length', 0)} length")
-        # round-20 dispatch-vs-device attribution: the decode loop's
-        # async-dispatch wall vs the wall spent at the per-quantum sync
-        disp, dev = r.get("dispatch_overhead_s"), r.get("device_s")
-        if disp is not None and dev is not None:
-            tot = max(disp + dev, 1e-12)
-            w(f"  dispatch vs device: {disp:.2f}s dispatch "
-              f"({100 * disp / tot:.0f}%) / {dev:.2f}s device sync "
-              f"({100 * dev / tot:.0f}%)")
         for ln in _phase_lines(r):
             w(ln)
         # round-15 paged KV: pool pressure + the prefill work prefix

@@ -98,6 +98,29 @@ def selection_line(events: list[dict]) -> str | None:
             f"could see kept ({kept / visible:.3f}), {len(quanta)} quanta")
 
 
+def host_lines(events: list[dict]) -> list[str]:
+    """The engine's mean host account a quantum: each phase's wall, the
+    thread's CPU inside it and the rest (the host waiting), then the sync
+    split at the instant the device's answer was ready into the wait and
+    the fetch after it. Empty for events without the CPU readings."""
+    quanta = [e for e in events if e.get("ev") == "quantum" and "host_cpu" in e]
+    if not quanta:
+        return []
+    n = len(quanta)
+    mean = lambda f: 1e3 * sum(map(f, quanta)) / n  # noqa: E731
+    phases = []
+    for k in sorted({k for q in quanta for k in q["host"]}):
+        wall = mean(lambda q: q["host"].get(k, 0.0))
+        cpu = mean(lambda q: q["host_cpu"].get(k, 0.0))
+        phases.append(f"{k} {wall:.2f}/{cpu:.2f}/{wall - cpu:.2f}")
+    cpu = lambda part: mean(lambda q: q["sync_cpu"][part])  # noqa: E731
+    return [
+        f"host per quantum (ms wall/cpu/wait, {n} quanta): " + "  ".join(phases),
+        f"sync per quantum (ms wall/cpu): wait {mean(lambda q: q['s_ready'] - q['s0']):.2f}/"
+        f"{cpu('sync_wait'):.2f}  fetch {mean(lambda q: q['s1'] - q['s_ready']):.2f}/{cpu('sync_fetch'):.2f}",
+    ]
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("log", help="metrics JSONL from a --metrics_log run")
@@ -136,6 +159,8 @@ def main(argv=None) -> int:
     selected = selection_line(events)
     if selected:
         print(selected)
+    for line in host_lines(events):
+        print(line)
     if args.out:
         chrome = trace_lib.to_chrome(events)
         with open(args.out, "w") as f:

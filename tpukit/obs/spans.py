@@ -57,15 +57,17 @@ class Span:
     the `with` block `t0` and `t1` hold its two readings of the run clock
     (seconds since the timeline's epoch), so a caller that also records the
     span elsewhere (the engine's `TraceRecorder` events) stamps it with the
-    SAME readings the sums were built from."""
+    SAME readings the sums were built from. On a timeline built with
+    `cpu=True`, `cpu` holds the thread's CPU seconds inside the span, read
+    inside `[t0, t1]`, so it never exceeds the wall; nested spans too."""
 
-    __slots__ = ("_timeline", "_scopes", "name", "t0", "t1")
+    __slots__ = ("_timeline", "_scopes", "name", "t0", "t1", "_c0", "cpu")
 
     def __init__(self, timeline: "SpanTimeline", name: str, scopes: tuple):
         self._timeline = timeline
         self._scopes = scopes
         self.name = name
-        self.t0 = self.t1 = 0.0
+        self.t0 = self.t1 = self._c0 = self.cpu = 0.0
 
     def __enter__(self) -> "Span":
         tl = self._timeline
@@ -73,16 +75,22 @@ class Span:
             scope.__enter__()
         tl._depth += 1
         self.t0 = time.perf_counter() - tl._epoch
+        if tl._cpu:
+            self._c0 = time.thread_time()
         return self
 
     def __exit__(self, *exc) -> None:
         tl = self._timeline
+        if tl._cpu:
+            self.cpu = time.thread_time() - self._c0
         self.t1 = time.perf_counter() - tl._epoch
         tl._depth -= 1
         if not tl._depth:  # nested: time already attributed to the outer span
             dt = self.t1 - self.t0
             for acc in (tl._window_acc, tl._epoch_acc, tl._lap_acc):
                 acc[self.name] = acc.get(self.name, 0.0) + dt
+            if tl._cpu:
+                tl._lap_cpu_acc[self.name] = tl._lap_cpu_acc.get(self.name, 0.0) + self.cpu
         for scope in reversed(self._scopes):
             scope.__exit__(*exc)
 
@@ -107,9 +115,15 @@ class SpanTimeline:
 
     `step_annotation` (`jax.profiler.StepTraceAnnotation` with its name
     bound) is entered as well by a span that passes `step_num=`.
+
+    `cpu=True` also reads the thread's CPU clock (`time.thread_time`) at each
+    span's entry and exit, so that a wall can be told apart into the host's
+    own work and its waiting (on the runtime, a lock, the device): the
+    outermost spans' CPU seconds are summed by phase for `lap_cpu()`. With
+    `cpu=False` no clock but the wall is read.
     """
 
-    def __init__(self, annotation=None, step_annotation=None):
+    def __init__(self, annotation=None, step_annotation=None, cpu: bool = False):
         now = time.perf_counter()
         self._epoch = now
         self._window_start = now
@@ -120,6 +134,10 @@ class SpanTimeline:
         self._depth = 0
         self._annotation = annotation
         self._step_annotation = step_annotation
+        self._cpu = cpu
+        self._lap_cpu_acc: dict[str, float] = {}
+        self._lap_cpu: dict[str, float] = {}
+        self._lap_cpu_from = time.thread_time() if cpu else 0.0
 
     def set_epoch(self, t0: float) -> None:
         """Pin the run clock: spans read `perf_counter() - t0`. A run loop
@@ -148,7 +166,18 @@ class SpanTimeline:
         reset: the per-iteration account a loop reads once a turn (the serve
         engine's per-quantum `host` walls)."""
         out, self._lap_acc = self._lap_acc, {}
+        if self._cpu:
+            now = time.thread_time()
+            cpu, self._lap_cpu_acc = self._lap_cpu_acc, {}
+            cpu["other"] = max(now - self._lap_cpu_from - sum(cpu.values()), 0.0)
+            self._lap_cpu, self._lap_cpu_from = cpu, now
         return out
+
+    def lap_cpu(self) -> dict[str, float]:
+        """The thread's CPU seconds of the lap the last `lap()` closed, by
+        the same phases, with `other` the CPU between the two lap points
+        outside every span; `{}` on a timeline without `cpu=True`."""
+        return self._lap_cpu
 
     def window(self) -> dict:
         """Close the current window: breakdown since the last `window()` (or
@@ -170,6 +199,9 @@ class SpanTimeline:
         self._window_acc = {}
         self._window_start = now
         self._lap_acc = {}
+        if self._cpu:
+            self._lap_cpu_acc, self._lap_cpu = {}, {}
+            self._lap_cpu_from = time.thread_time()
         return out
 
 

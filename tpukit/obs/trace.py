@@ -23,18 +23,23 @@ origin, and builds its events from those readings):
     enqueue      t=arrival_s          request visible to the scheduler
     route        t, dst               router assignment (fleet only)
     admit        t, slot              lane created on `replica`
-    prefill      t0, t1, chunk        one (batched) prefill dispatch wall
+    prefill      t0, t1, cpu, chunk   one (batched) prefill dispatch wall
+                                      and its thread-CPU seconds
     prefill_done t                    lane armed for decode
     handoff      t0, t1, claim_s, copy_s, dst   disagg page handoff
     adopt        t                    decode-side lane armed (disagg)
     quantum      t0, t1, s0, s1, steps, lanes   ONE event per decode
                  dispatch+sync pair; `lanes` lists the participating
                  trace ids, [t0,t1] the async-dispatch wall, [s0,s1] the
-                 wall-to-sync (device) wall — the per-quantum
-                 dispatch-vs-device attribution ROADMAP #3 wants. Also
+                 wall-to-sync wall, split at `s_ready` (the dispatched
+                 program's output ready) into the wait for the device
+                 and the fetch after it. Also
                  `host` {span name: seconds, ..., "other"}: the walls of
                  the engine's spans between the previous quantum's s1
                  and this s0, summing to that gap (serial host time);
+                 `host_cpu`, the same keys: the thread's CPU seconds of
+                 each (what of the wall was the host's own work);
+                 `sync_cpu` {"sync_wait", "sync_fetch"}: the sync's CPU;
                  counters read at dispatch: `decoding`, `prefilling`
                  (lanes in each phase), `pending` (queue depth),
                  `free_pages` (None on the ring); and at the sync:
@@ -311,10 +316,11 @@ def flush_to_logger(tracer: TraceRecorder, logger, trees=()) -> None:
 
 
 # what a quantum's dispatch bar shows when clicked: who rode it, what the
-# host did before it, the slot counters, and a latent model's key selection
-_QUANTUM_ARGS = ("lanes", "host", "decoding", "prefilling", "pending",
-                 "free_pages", "delivered", "finished", "select_kept",
-                 "select_visible")
+# host did before it (walls and their CPU), the slot counters, and a latent
+# model's key selection
+_QUANTUM_ARGS = ("lanes", "host", "host_cpu", "s_ready", "decoding",
+                 "prefilling", "pending", "free_pages", "delivered",
+                 "finished", "select_kept", "select_visible")
 
 
 def to_chrome(events: list[dict]) -> dict:
@@ -345,12 +351,23 @@ def to_chrome(events: list[dict]) -> dict:
                 "args": {k: ev[k] for k in _QUANTUM_ARGS if k in ev},
             })
             if "s1" in ev:
+                # the wait for the device, then the fetch after its
+                # answer was ready (one bar where the event has no split)
+                ready = ev.get("s_ready", ev["s1"])
                 out.append({
-                    "name": "sync", "ph": "X", "cat": "quantum",
+                    "name": "sync wait" if "s_ready" in ev else "sync",
+                    "ph": "X", "cat": "quantum",
                     "pid": pid, "tid": 0, "ts": us(ev["s0"]),
-                    "dur": max(us(ev["s1"] - ev["s0"]), 1),
+                    "dur": max(us(ready - ev["s0"]), 1),
                     "args": {"lanes": ev.get("lanes", [])},
                 })
+                if "s_ready" in ev:
+                    out.append({
+                        "name": "sync fetch", "ph": "X", "cat": "quantum",
+                        "pid": pid, "tid": 0, "ts": us(ready),
+                        "dur": max(us(ev["s1"] - ready), 1),
+                        "args": {"cpu": ev.get("sync_cpu", {})},
+                    })
         elif "t0" in ev:  # prefill / handoff spans
             label = name
             if ev.get("chunk") is not None:
@@ -359,7 +376,7 @@ def to_chrome(events: list[dict]) -> dict:
                 "name": label, "ph": "X", "cat": name, "pid": pid,
                 "tid": int(ev.get("trace", 0)) + 1, "ts": us(ev["t0"]),
                 "dur": max(us(ev["t1"] - ev["t0"]), 1),
-                "args": {"rid": ev.get("rid")},
+                "args": {k: ev[k] for k in ("rid", "cpu") if k in ev},
             })
         else:  # point milestones
             args = {k: v for k, v in ev.items()

@@ -24,11 +24,13 @@ the serving telemetry — in the shape real TPU serving engines take:
     (spans -> JSONL -> flight recorder -> tools/report.py): per-window
     `kind="serve"` records (tokens/s, occupancy, admit/evict counts,
     prefill/decode/sync wall split + explicit `other_s` residual,
-    per-window dispatch-vs-device attribution, per-token + end-to-end
-    latency percentiles) and one final `kind="serve_summary"`. With a
-    `tracer` (round 20, tpukit/obs/trace.py) the step primitives also
-    emit per-request span events — enqueue/admit/prefill/quantum/finish
-    — merged into span trees with per-phase p50/p99 in the summary.
+    per-token + end-to-end latency percentiles) and one final
+    `kind="serve_summary"`. With a `tracer` (round 20,
+    tpukit/obs/trace.py) the step primitives also emit per-request span
+    events — enqueue/admit/prefill/quantum/finish — merged into span
+    trees with per-phase p50/p99 in the summary, and each quantum's host
+    walls come with their thread-CPU seconds and the sync's wait apart
+    from its fetch.
 
 Sharded serving: pass `mesh` (and params placed at their training
 shardings) and the engine places the KV ring `[L, N, H, W, D]` as
@@ -690,8 +692,11 @@ class ServeEngine:
         self.completions: list[Completion] = []
         # THE host-span primitive (tpukit/obs/spans.py): phase sums, the
         # profiler's host line (`tpukit:<name>`) and the run-clock stamps the
-        # tracer's events are built from, all from one `with`
-        self.spans = SpanTimeline(annotation=jax.profiler.TraceAnnotation)
+        # tracer's events are built from, all from one `with`; a traced
+        # engine also reads the thread's CPU clock in each span, which tells
+        # the host's own work from its waits (`host_cpu` on the quanta)
+        self.spans = SpanTimeline(annotation=jax.profiler.TraceAnnotation,
+                                  cpu=self.tracer is not None)
         self.buckets_used: set[int] = set()
         self.steps = 0
         self.admitted = 0
@@ -800,7 +805,7 @@ class ServeEngine:
                     tr.emit("admit", tid, rid=req.rid, t=now, slot=slot,
                             replica=self.replica)
                     tr.emit("prefill", tid, rid=req.rid, t0=sp.t0, t1=sp.t1,
-                            chunk=0, replica=self.replica)
+                            cpu=sp.cpu, chunk=0, replica=self.replica)
                     tr.emit("prefill_done", tid, rid=req.rid, t=sp.t1,
                             replica=self.replica)
         self.max_live = max(self.max_live, len(self._lanes))
@@ -902,11 +907,10 @@ class ServeEngine:
         if not prefilling:
             return
         c = self.serve.chunk
-        # two `prefill` spans with `place` between them (spans never nest
-        # here: nested time would go to the outer one): the first is the
-        # host assembling the chunk batch, the second the dispatch whose
-        # wall the tracer's `prefill` events carry
-        with self.spans.span("prefill"):
+        # `prefill_batch` (the host assembling the chunk batch), `place`, then
+        # `prefill` (the dispatch whose wall the tracer's `prefill` events
+        # carry); spans never nest here: nested time would go to the outer one
+        with self.spans.span("prefill_batch"):
             entries = []
             for slot, lane in prefilling:
                 start = lane.next_chunk
@@ -946,7 +950,7 @@ class ServeEngine:
             if tr is not None:
                 tid = trace_id(lane.req)
                 tr.emit("prefill", tid, rid=lane.req.rid, t0=sp.t0, t1=sp.t1,
-                        chunk=start // c, replica=self.replica,
+                        cpu=sp.cpu, chunk=start // c, replica=self.replica,
                         tokens=min(start + c, lane.prompt_len) - start)
                 if is_last:
                     tr.emit("prefill_done", tid, rid=lane.req.rid, t=sp.t1,
@@ -1125,46 +1129,60 @@ class ServeEngine:
         """The per-quantum D2H: cursors + active flags, with whatever device
         counters the last dispatch left pending coalesced into the same
         round trip. One small fetch per quantum — the price of host-side EOS
-        detection. Returns host `(cursors, active)`."""
-        if self._pending_spec is not None:
+        detection. The copies are queued first, behind the dispatched
+        program, as one `device_get` queues them; then `sync_wait` takes the
+        program's cursors and `sync_fetch` the other copies and the
+        conversions, so the wait's end (`s_ready`) is the instant the host
+        learned the quantum's outputs were ready. Returns host
+        `(cursors, active)` and the two spans."""
+        spec, ticks = self._pending_spec, self._pending_ticks
+        if spec is not None:
             # dlen is a device array on the fused ngram path, host numpy on
             # the model path — device_get passes the latter through untouched
-            live, dlen, acc, napp = self._pending_spec
-            cur, act, dlen, acc, napp = map(np.asarray, jax.device_get(
-                (self.cursors, self.active, dlen, acc, napp)))
-            self._pending_spec = (live, dlen, acc, napp)
-        elif self._pending_ticks is not None:
+            arrays = (self.cursors, self.active, *spec[1:])
+        elif ticks is not None:
             # fused window (round 21): the actual tick count rides the
             # same D2H round-trip as the cursors — the loop may have
             # exited early, so steps are accounted HERE, from the
             # device's answer, never assumed from the quantum
-            cur, act, ticks = map(np.asarray, jax.device_get(
-                (self.cursors, self.active, self._pending_ticks)))
-            self._pending_ticks = None
-            ran = int(ticks)
-            self.steps += ran
-            self._win["steps"] += ran
-            if self._quantum is not None:
-                self._quantum["steps"] = ran
+            arrays = (self.cursors, self.active, ticks)
         else:
             # whatever counters the model keeps in its cache ride the same
             # round trip (none for a model that keeps none): an integer
             # array counts since the cache was made and the quantum gets the
             # difference, a float array holds gauges, reported as fetched
             names, counters = self._model.counters(self.cache)
-            cur, act, *counters = map(np.asarray, jax.device_get(
-                (self.cursors, self.active, *counters)))
-            if names:
-                gauge = np.concatenate([np.full(c.size, c.dtype.kind == "f") for c in counters])
-                seen = np.concatenate([c.ravel().astype(np.float64) for c in counters])
+            arrays = (self.cursors, self.active, *counters)
+        for a in arrays:
+            if isinstance(a, jax.Array):
+                a.copy_to_host_async()
+        # the cursors first, as `device_get` takes them: waiting on the
+        # array itself (`block_until_ready`) instead returned ~0.6 ms later
+        # than its queued copy on a v5e, device idle the split would add
+        with self.spans.span("sync_wait") as wait:
+            cur = np.asarray(jax.device_get(self.cursors))
+        with self.spans.span("sync_fetch") as fetch:
+            act, *rest = map(np.asarray, jax.device_get(arrays[1:]))
+            if spec is not None:
+                self._pending_spec = (spec[0], *rest)
+            elif ticks is not None:
+                self._pending_ticks = None
+                ran = int(rest[0])
+                self.steps += ran
+                self._win["steps"] += ran
+                if self._quantum is not None:
+                    self._quantum["steps"] = ran
+            elif names:
+                gauge = np.concatenate([np.full(c.size, c.dtype.kind == "f") for c in rest])
+                seen = np.concatenate([c.ravel().astype(np.float64) for c in rest])
                 if self._quantum is not None:
                     # a 32-bit count wraps in a long run: its difference modulo 2**32
                     since = np.where(gauge, seen, (seen - self._counters) % 2**32)
                     self._quantum.update(
                         (k, float(v) if g else int(v)) for k, v, g in zip(names, since, gauge))
                 self._counters = seen
-        self._drain_spec()
-        return cur, act
+            self._drain_spec()
+        return cur, act, wait, fetch
 
     def _retire(self, now: float, cur, act, fetched_s: float) -> tuple[int, int]:
         """After the fetch returned (run clock `fetched_s`): stamp the tokens
@@ -1307,14 +1325,20 @@ class ServeEngine:
         return len(over)
 
     def _close_quantum(self, host: dict, s0: float, s1: float,
-                       delivered: int, finished: int) -> None:
+                       delivered: int, finished: int, *, host_cpu: dict,
+                       s_ready: float, sync_cpu: dict) -> None:
         """Complete the dispatch+sync pair `_open_quantum` started: `[s0, s1]`
-        is the wall-to-sync (device) wall, `host` the walls of the spans that
-        ran between the previous sync's return and `s0`, by name, with
-        `other` so that they sum to that gap — the serial host time no
-        device work hides except an in-flight prefill chunk. Says so on
-        `logging` when the period is far beyond the recent median, traced
-        or not, and emits the quantum event when traced."""
+        is the sync's wall, `s_ready` the instant inside it the dispatched
+        program's output was ready (before it the wait, after it the fetch),
+        `host` the walls of the spans that ran between the previous sync's
+        return and `s0`, by name, with `other` so that they sum to that gap
+        — the serial host time no device work hides except an in-flight
+        prefill chunk. `host_cpu` holds the thread's CPU seconds of the same
+        phases (the part of each wall that is the host's own work, not a
+        wait) and `sync_cpu` those of the sync's wait and fetch; both are
+        read on a traced engine only. Says so on `logging` when the period
+        is far beyond the recent median, traced or not, and emits the
+        quantum event when traced."""
         q, self._quantum = self._quantum, None
         since, self._host_from = self._host_from, s1
         if q is None:  # a sync with nothing dispatched (step primitives driven by hand)
@@ -1338,7 +1362,8 @@ class ServeEngine:
         self._periods.append(period)
         self._quanta += 1
         if self.tracer is not None:
-            self.tracer.emit("quantum", -1, s0=s0, s1=s1, host=host,
+            self.tracer.emit("quantum", -1, s0=s0, s1=s1, s_ready=s_ready,
+                             host=host, host_cpu=host_cpu, sync_cpu=sync_cpu,
                              delivered=delivered, finished=finished,
                              replica=self.replica, **q)
 
@@ -1373,13 +1398,6 @@ class ServeEngine:
             # the window's named spans + other_s sum to window_s exactly
             # — drift can't silently vanish
             other_s=b["seconds"].get("other", 0.0),
-            # per-window dispatch-vs-device attribution (ROADMAP #3):
-            # decode/draft/verify spans ARE the async dispatch calls;
-            # the device's compute wall surfaces as the sync span
-            dispatch_overhead_s=(b["seconds"].get("decode", 0.0)
-                                 + b["seconds"].get("draft", 0.0)
-                                 + b["seconds"].get("verify", 0.0)),
-            device_s=b["seconds"].get("sync", 0.0),
         )
         if self.serve.paged:
             # the paged health triple (round 15): pool pressure, how much
@@ -1544,10 +1562,6 @@ class ServeEngine:
                  + ep["seconds"].get("draft", 0.0)
                  + ep["seconds"].get("verify", 0.0))
         rec["other_s"] = max(wall_s - named, 0.0)
-        rec["dispatch_overhead_s"] = (rec["decode_s"]
-                                      + ep["seconds"].get("draft", 0.0)
-                                      + ep["seconds"].get("verify", 0.0))
-        rec["device_s"] = rec["sync_s"]
         rec["max_live_slots"] = self.max_live
         rec["kv_bytes"] = self.kv_bytes
         if self.serve.draft:
@@ -1674,13 +1688,16 @@ class ServeEngine:
         evict deadline-expired ones (`retire`), close the quantum's record,
         and emit a `kind="serve"` window when one is due (`window`)."""
         host = self.spans.lap()  # what the host did since the last sync returned
+        host_cpu = self.spans.lap_cpu()
         with self.spans.span("sync") as sp:
-            cur, act = self._fetch_cursors()
+            cur, act, wait, fetch = self._fetch_cursors()
         self.spans.lap()  # the wait is no host work: the next account starts at s1
         with self.spans.span("retire"):
             delivered, finished = self._retire(now, cur, act, sp.t1)
             finished += self._evict_deadlines(now, sp.t1)
-        self._close_quantum(host, sp.t0, sp.t1, delivered, finished)
+        self._close_quantum(host, sp.t0, sp.t1, delivered, finished,
+                            host_cpu=host_cpu, s_ready=wait.t1,
+                            sync_cpu={"sync_wait": wait.cpu, "sync_fetch": fetch.cpu})
         if self._win["steps"] >= self.serve.window_steps:
             with self.spans.span("window"):
                 self._emit_window()

@@ -28,7 +28,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from tpukit.obs.xla import collective_bytes, kernel_calls
-from tpukit.ops import pallas_attention
+from tpukit.ops import pallas_attention, selective_scan
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
@@ -142,6 +142,11 @@ def _cases():
             pool(BF16) + slot, ("paged_attend",)),
         "paged_attend_int8": (lambda mesh: _paged, pool(I8) + scales + slot,
                               ("paged_attend",)),
+        # jamba2-3b's widths (E 5,120, N 16), a group of 16 lanes of a chunk of 256
+        "selective_scan": (lambda mesh: selective_scan.scan_chunk, [(shape, dt, P()) for shape, dt in (
+            ((16, 16, 5120), F32), ((16, 256, 5120), F32), ((16, 256, 5120), F32), ((16, 256, 16), F32),
+            ((16, 256, 16), F32), ((16, 5120), F32), ((5120,), F32), ((16, 256), jnp.bool_))],
+            ("selective_scan",)),
     }
 
 
@@ -309,18 +314,24 @@ def test_v5e_latent_family_decode_quantum_and_prefill_chunk_compile_at_published
         assert m.argument_size_in_bytes > 6e9 and total < 15.75e9, (name, total)  # the weights are real, and it fits
 
 
-def test_v5e_hybrid_family_serve_programs_compile_at_published_widths_and_128_slots(v5e):
+def test_v5e_hybrid_family_serve_programs_compile_at_published_widths_and_128_slots(v5e, monkeypatch):
     """The hybrid family's serve programs at the jamba2-3b configuration's
     real size and the cell's engine (128 slots of 16,384 tokens, chunks of
     256, quantum 4), for one described chip: the 28 layers' weights, the MQA
     pages and every slot's recurrent state, held twice by the un-donated
     programs, and the largest admit batch's prefill (its lanes a group at a
-    time) all fit, and the scan and the key-block walk compile."""
+    time) all fit, and the scan and the key-block walk compile. The prefill
+    program lowers the `selective_scan` kernel ONCE, into one private
+    function that each run of Mamba layers (three, between the two attention
+    layers) calls from its loop; a decode tick has no kernel."""
+    import itertools
     import json
+    import re
 
     from tpukit.model import hybrid
     from tpukit.serve import decode
 
+    monkeypatch.setattr(pallas_attention, "on_tpu_backend", lambda: True)
     with open(os.path.join(REPO, "benchmark", "configs", "jamba2-3b.json")) as f:
         cfg = hybrid.config_from_hf(json.load(f))
     one = SingleDeviceSharding(v5e[0])
@@ -338,10 +349,15 @@ def test_v5e_hybrid_family_serve_programs_compile_at_published_widths_and_128_sl
             params, cfg, *state, sds((n,), I32), sds((n, chunk), I32), sds((n,), I32), sds((n,), jnp.bool_),
             sds((n,), I32), sds((n,), I32), sds((n, 2), jnp.uint32)),
     }
+    runs = sum(kind == hybrid.MAMBA for kind, _ in itertools.groupby(cfg.layer_types))
     for name, lowered in programs.items():
-        m = lowered.compile().memory_analysis()
+        compiled = lowered.compile()
+        m = compiled.memory_analysis()
         total = m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
         assert m.argument_size_in_bytes > 9e9 and total < 15.75e9, (name, total)  # weights, pages and state are real
+        assert kernel_calls(compiled.as_text()) == ({"selective_scan": runs} if name == "prefill" else {}), name
+        if name == "prefill":
+            assert runs == 3 and len(re.findall(r"func\.func private @_selective_scan\b", lowered.as_text())) == 1
 
 
 @pytest.mark.parametrize("name,lower,expect,scope_of", [

@@ -285,15 +285,18 @@ def test_family_dispatch_and_the_published_config():
         hybrid.config_from_hf(dict(config, model_type="mamba"))
 
 
-# The family's serve programs at `tiny_config()`, recorded on the tree at
-# commit adc8f13, before the key-block walk it shares with the latent family
-# (`latent.walk_key_blocks`) took its selection operand: a walk with no
-# selection lowers to the text it lowered to then.
+# The family's serve programs at `tiny_config()`. The decode ticks' were
+# recorded on the tree at commit adc8f13, before the key-block walk it shares
+# with the latent family (`latent.walk_key_blocks`) took its selection
+# operand: a walk with no selection lowers to the text it lowered to then.
+# The prefill chunks' were recorded anew when the chunk's scan became the
+# `selective_scan` kernel (interpret mode here); a tick's one-token step does
+# not run it.
 HYBRID_PROGRAM_HASHES = {
     ("f32", "decode_step", 1): "979e5ef23f3d0c74", ("f32", "decode_step", 4): "6f8a5c235d1b5d86",
-    ("f32", "prefill_chunk_paged", 0): "9ccc804635ab37ac",
+    ("f32", "prefill_chunk_paged", 0): "edc4fa5728349f16",
     ("bf16", "decode_step", 1): "18d3065435b86a90", ("bf16", "decode_step", 4): "67c8640feef06255",
-    ("bf16", "prefill_chunk_paged", 0): "0cd6dd28d795550f",
+    ("bf16", "prefill_chunk_paged", 0): "2e9744580f09afe5",
 }
 
 

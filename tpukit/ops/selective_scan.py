@@ -1,7 +1,7 @@
 """The selective state-space scan of a Mamba-1 mixer (Gu & Dao,
-arXiv:2312.00752), in plain JAX with a float32 state: the causal depthwise
-convolution before it, the scan over a prefill chunk, and the one-token
-update of a decode tick. No Pallas kernel (ROADMAP R4).
+arXiv:2312.00752) with a float32 state: the causal depthwise convolution
+before it, the scan over a prefill chunk (a Pallas TPU kernel,
+`selective_scan`), and the one-token update of a decode tick.
 
 Per channel `e` of the inner width E and state index `n` of N, with `A [N, E]`
 negative, the recurrence is
@@ -18,12 +18,36 @@ A chunk's positions that are not `keep` (its padding, and a prompt's last
 token, which the engine's first decode tick forwards again) take no part in
 the state or the convolution's state: their outputs are computed, and the
 state is left where the last kept token put it, whatever the padding.
+
+The chunk's scan (`scan_chunk`) is one kernel call. Grid `(lanes, channel
+blocks, token blocks)`: a step runs one lane's 1,024 channels through a
+block of up to 256 tokens, the tokens in order, and the state stays in VMEM
+from the chunk's first token to its last. `u` and `delta` come in, and `y`
+goes out, as whole (8, 128) tiles of eight tokens; in VMEM a token's 1,024
+channels become one `[8, 128]` tile, as does each state row `h[n]` (16 tiles,
+the token loop's carry, beside the 16 of `A`), so every step of the
+recurrence is whole-tile vector work. `B_t[n]`, `C_t[n]` and
+`keep` are scalars read from SMEM. `reference_scan_chunk`, a `lax.scan`
+over the tokens with the state in its carry, is the reference the tests
+hold the kernel to; nothing else calls it. On the CPU the kernel runs in
+Pallas interpret mode (`pallas_attention._interpret`).
 """
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from tpukit.ops.pallas_attention import _interpret, tpu_compiler_params
+
+SUB = 8  # sublanes of an f32 tile
+CHANNEL_BLOCK = 1024  # channels a grid step: one f32 tile a token
+TOKEN_BLOCK = 256  # at most this many tokens a grid step
 
 
 @jax.named_scope("mamba_conv")
@@ -60,13 +84,114 @@ def _read(h, c):
     return jnp.sum(h * c[:, :, None], axis=1)
 
 
+def _scan_kernel(s_ref, state_ref, u_ref, delta_ref, a_ref, y_ref, out_ref, h_ref):
+    """One grid step `(lane, channel block, token block)` of `W` channels:
+    `u_ref`, `delta_ref`, `y_ref [1, TB, W]`, `state_ref`, `out_ref [1, N,
+    W]`, `a_ref [N, W]`; `s_ref` in SMEM holds the block's `B [TB, N]`, `C
+    [TB, N]` and `keep [TB]` (`_scalars`). Inside, a token's `W` channels
+    are one `[8, W / 8]` tile: `h_ref [N, 8, W / 8]` holds the state from one
+    token block to the next."""
+    n_state, tb, w = a_ref.shape[0], u_ref.shape[1], u_ref.shape[2]
+    c_at, keep_at = tb * n_state, 2 * tb * n_state
+    group = math.gcd(tb, SUB)  # tokens moved a trip: whole (8, 128) tiles of the blocks
+    tile = lambda z: z.reshape(z.shape[:-1] + (SUB, w // SUB))  # noqa: E731
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        h_ref[...] = tile(state_ref[0])
+
+    a = tile(a_ref[...])
+
+    def tick(t, u, dt, h):
+        du, y, kept = dt * u, None, s_ref[keep_at + t] != 0
+        out = []
+        for n in range(n_state):
+            new = jnp.exp(dt * a[n]) * h[n] + du * s_ref[t * n_state + n]
+            y_n = new * s_ref[c_at + t * n_state + n]
+            y = y_n if y is None else y + y_n
+            out.append(jnp.where(kept, new, h[n]))
+        return y, tuple(out)
+
+    def trip(i, h):  # Mosaic unrolls a loop whole or not at all: `group` ticks a trip by hand
+        rows = pl.ds(pl.multiple_of(i * group, group), group)
+        u, dt, ys = tile(u_ref[0, rows]), tile(delta_ref[0, rows]), []
+        for j in range(group):
+            y, h = tick(i * group + j, u[j], dt[j], h)
+            ys.append(y)
+        y_ref[0, rows] = jnp.stack(ys).reshape(group, w)
+        return h
+
+    h = jax.lax.fori_loop(0, tb // group, trip, tuple(h_ref[n] for n in range(n_state)))
+    for n in range(n_state):
+        h_ref[n] = h[n]
+    out_ref[0] = h_ref[...].reshape(n_state, w)
+
+
+def _scalars(b, c, keep, steps: int):
+    """`B`, `C [L, T, N]` and `keep [L, T]` as one float32 row a grid step's
+    `(lane, token block)`, `[B, C, keep]`, each row padded to a multiple of
+    1,024 (a one-dimensional SMEM block is whole tiles of the array's
+    layout), flattened."""
+    rows = lambda z: z.astype(jnp.float32).reshape(keep.shape[0] * steps, -1)  # noqa: E731
+    packed = jnp.concatenate([rows(b), rows(c), rows(keep)], axis=1)
+    width = -(-packed.shape[1] // 1024) * 1024
+    return jnp.pad(packed, ((0, 0), (0, width - packed.shape[1]))).reshape(-1)
+
+
+# The kernel's call is jitted at module level, so that a program whose Mamba
+# layers run in several loops (jamba2-3b: three, between its two attention
+# layers) traces it once and lowers it once, into one private function each
+# loop calls. What a trace depends on besides the operands is a STATIC
+# argument: the blocks and the kernel mode.
+
+
+@functools.partial(jax.jit, static_argnames=("tb", "w", "interpret"))
+def _selective_scan(scalars, state, u, delta, a, *, tb: int, w: int, interpret: bool):
+    """The kernel over `state [L, N, E]`, `u`, `delta [L, T, E]`, `a [N, E]`
+    float32 and `_scalars`' rows, in blocks of `tb` tokens and `w` channels.
+    Returns `(y [L, T, E], state)`."""
+    lanes, t, e = u.shape
+    n, steps = a.shape[0], t // tb
+    tokens = pl.BlockSpec((1, tb, w), lambda i, j, k: (i, k, j))
+    held = pl.BlockSpec((1, n, w), lambda i, j, k: (i, 0, j))
+    elems = lanes * t * n * e
+    return pl.pallas_call(
+        _scan_kernel,
+        grid=(lanes, e // w, steps),
+        in_specs=[pl.BlockSpec((scalars.size // (lanes * steps),), lambda i, j, k: (i * steps + k,),
+                               memory_space=pltpu.SMEM),
+                  held, tokens, tokens, pl.BlockSpec((n, w), lambda i, j, k: (0, j))],
+        out_specs=[tokens, held],
+        out_shape=[jax.ShapeDtypeStruct(u.shape, jnp.float32), jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, SUB, w // SUB), jnp.float32)],
+        compiler_params=tpu_compiler_params("parallel", "parallel", "arbitrary"),
+        cost_estimate=pl.CostEstimate(flops=7 * elems, transcendentals=elems,
+                                      bytes_accessed=4 * (3 * u.size + 2 * state.size + scalars.size + a.size)),
+        interpret=interpret,
+        name="selective_scan",
+    )(scalars, state, u, delta, a)
+
+
 @jax.named_scope("ssm_scan")
 def scan_chunk(state, u, delta, b, c, a, d, keep):
     """A prefill chunk: `state [B, N, E]` float32, `u`, `delta [B, T, E]`,
     `b`, `c [B, T, N]`, `a [N, E]`, `d [E]`, `keep [B, T]` (False where a
-    position's step stays out of the state). A `lax.scan` over the chunk's
-    tokens, the state in the carry. Returns `(state after the chunk, y [B, T,
+    position's step stays out of the state), E a multiple of 8. One
+    `selective_scan` kernel call. Returns `(state after the chunk, y [B, T,
     E])`, all float32."""
+    t, e = u.shape[1:]
+    if e % SUB:
+        raise ValueError(f"the scan's inner width {e} is not a multiple of {SUB}")
+    tb = math.gcd(t, TOKEN_BLOCK)
+    f32 = lambda z: z.astype(jnp.float32)  # noqa: E731
+    y, state = _selective_scan(_scalars(b, c, keep, t // tb), f32(state), f32(u), f32(delta), f32(a), tb=tb,
+                               w=CHANNEL_BLOCK if e % CHANNEL_BLOCK == 0 else e, interpret=_interpret())
+    return state, y + d * u
+
+
+def reference_scan_chunk(state, u, delta, b, c, a, d, keep):
+    """`scan_chunk` as an XLA `lax.scan` over the chunk's tokens, the state
+    in the carry: the reference the kernel's tests compare against."""
 
     def tick(h, xs):
         u_t, dt_t, b_t, c_t, keep_t = xs

@@ -98,6 +98,22 @@ def selection_line(events: list[dict]) -> str | None:
             f"could see kept ({kept / visible:.3f}), {len(quanta)} quanta")
 
 
+def prefix_line(events: list[dict]) -> str | None:
+    """What the page registry served: admissions that hit a shared prefix,
+    and the prompt tokens served from its pages over those admitted (the
+    `admit` events' `shared_tokens`, the quanta's `prefix_tokens` /
+    `admitted_tokens`); None where nothing was shared."""
+    admits = [e for e in events if e.get("ev") == "admit" and "shared_tokens" in e]
+    hits = sum(1 for e in admits if e["shared_tokens"] > 0)
+    quanta = [e for e in events if e.get("ev") == "quantum" and "prefix_tokens" in e]
+    served, admitted = sum(q["prefix_tokens"] for q in quanta), sum(q["admitted_tokens"] for q in quanta)
+    if not hits and not served:
+        return None
+    share = f" ({served / admitted:.3f})" if admitted else ""
+    return (f"prefix: {hits} of {len(admits)} admissions hit the registry; {served} of the {admitted} "
+            f"prompt tokens admitted served from shared pages{share}, {len(quanta)} quanta")
+
+
 def host_lines(events: list[dict]) -> list[str]:
     """The engine's mean host account a quantum: each phase's wall, the
     thread's CPU inside it and the rest (the host waiting), then the sync
@@ -156,9 +172,9 @@ def main(argv=None) -> int:
             return 1
 
     print(render(trees, trace_lib))
-    selected = selection_line(events)
-    if selected:
-        print(selected)
+    for line in (selection_line(events), prefix_line(events)):
+        if line:
+            print(line)
     for line in host_lines(events):
         print(line)
     if args.out:

@@ -56,6 +56,17 @@ config alone:
   FFN: `scale (w1 N(z) + w2 N(z^2) + w3 N(z^3) + clamp(b))`, `N` the RMS
   normalisation over the FFN's width, per token.
 
+A third published form keeps every layer full and every head its own
+(`config_from_hf` on keys with no `layer_types`): no key selection, one KV
+head a query head, no output gate and no latent rescale; YaRN-scaled rotary
+(`AttnSpec.yarn`: the frequencies blended toward `1 / factor` over a ramp,
+the softmax scale times `mscale^2`) on interleaved pairs `(x[2i], x[2i+1])`;
+node-limited routing (`route_groups`, `route_topk_groups`: the experts in
+groups, a group scored by the sum of its two largest biased scores, the
+top-k chosen within the best groups only). With no window layer there is
+ONE page kind (`page_kinds`), so the engine's prefix registry serves its
+latent pages.
+
 It also sends a decode tick's rows through EVERY held expert
 (`tick_experts_every_row`; `held_experts_ffn(every_row=True)`): the grouped
 matmul skips an expert that got no row, so a tick's time followed how the
@@ -84,6 +95,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tpukit.ops.moe_dispatch import held_experts_ffn, sigmoid_topk_route
 from tpukit.ops.pallas_attention import online_softmax_update
@@ -114,9 +126,13 @@ class AttnSpec:
     theta: float
     kv_heads: int = 0  # KV heads `W_kvb` expands the latent to, read by query heads in groups; 0: one a query head
     noise_heads: int = 0  # of `heads`, how many are noise heads (the last of each group): differential attention
-    gate: str = "headwise"  # the sigmoid output gate: one value a head, or "elementwise" (a value a channel)
+    gate: str = "headwise"  # the sigmoid output gate: one value a head, "elementwise" (a value a channel) or "none"
+    rope_interleaved: bool = False  # rotary pairs (x[2i], x[2i+1]); False: (x[i], x[i + R/2])
+    yarn: tuple = ()  # (factor, original_max_position_embeddings, beta_fast, beta_slow, mscale_all_dim); (): plain rotary
 
     def __post_init__(self):
+        if self.gate not in ("headwise", "elementwise", "none"):
+            raise ValueError(f"gate must be 'headwise', 'elementwise' or 'none', got {self.gate!r}")
         if self.kv_heads and (self.heads % self.kv_heads or self.noise_heads not in (0, self.kv_heads)):
             raise ValueError(f"{self.heads} heads in {self.kv_heads} groups with {self.noise_heads} noise heads: "
                              "groups are equal and hold one noise head each, or none")
@@ -136,6 +152,42 @@ class AttnSpec:
     def out_heads(self) -> int:
         """Heads whose output reaches `W_o`: the signal heads."""
         return self.heads - self.noise_heads
+
+    @property
+    def scale(self) -> float:
+        """The softmax scale: `1 / sqrt(nope + rope)`, under YaRN times
+        `mscale^2` (`yarn_mscale`, applied to the scores, not the rotary)."""
+        s = 1.0 / math.sqrt(self.nope + self.rope)
+        if self.yarn:
+            m = yarn_mscale(self.yarn[0], self.yarn[4])
+            s = s * m * m
+        return s
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: `0.1 mscale ln(factor) + 1` (1 at a
+    factor of 1 or less)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(rope: int, theta: float, factor: float, original: int, beta_fast: float,
+                  beta_slow: float) -> np.ndarray:
+    """YaRN's rotary frequencies `[rope / 2]` float32, as DeepSeek-V3's
+    `precompute_freqs_cis` builds them for a model whose longest context
+    passes `original`: each dimension's own frequency `theta^(-2i/R)` where
+    it turns more than `beta_fast` times over `original` positions, that
+    frequency over `factor` where it turns fewer than `beta_slow` times, and
+    a linear blend between (the ramp over the dimensions between the two
+    correction bounds). Computed on the host in float64, rounded once."""
+    def bound(turns):
+        return rope * math.log(original / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low, high = max(math.floor(bound(beta_fast)), 0), min(math.ceil(bound(beta_slow)), rope - 1)
+    if low == high:
+        high += 0.001
+    freq = 1.0 / theta ** (np.arange(0, rope, 2, dtype=np.float64) / rope)
+    keep = 1.0 - np.clip((np.arange(rope // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    return (freq / factor * (1.0 - keep) + freq * keep).astype(np.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,6 +211,8 @@ class LatentConfig:
     rescale_latents: bool = True  # s_q = sqrt(dim / q_rank), s_kv = sqrt(dim / kv_rank)
     select_bias: bool = True  # the router's choice adds a learned per-expert bias
     route_scale: float = 1.0  # the gates, normalised over the chosen, times this
+    route_groups: int = 1  # the experts in this many equal groups ...
+    route_topk_groups: int = 1  # ... of which the choice keeps the best this many (node-limited routing)
     activation: str = "silu"  # of every gated FFN: "silu" or "poly_norm"
     poly_scale: float = 1.0
     poly_bias_clamp: float = 0.5
@@ -182,6 +236,9 @@ class LatentConfig:
             )
         if self.activation not in ("silu", "poly_norm"):
             raise ValueError(f"activation must be 'silu' or 'poly_norm', got {self.activation!r}")
+        if self.n_experts % self.route_groups or not 1 <= self.route_topk_groups <= self.route_groups:
+            raise ValueError(f"{self.n_experts} experts in {self.route_groups} groups, the best "
+                             f"{self.route_topk_groups} kept: groups are equal and at least one is kept")
 
     @property
     def num_layers(self) -> int:
@@ -204,10 +261,14 @@ def config_from_hf(hf: dict, *, experts_held: int | None = None, expert_lo: int 
     benchmark configuration file carries them). `n_routed_experts` there is
     the experts HELD when the file also states `published.n_routed_experts`
     (one chip's share of a deployment); `experts_held` overrides. The family
-    is published under two sets of key names: a config that states a
-    `sliding_window_pattern` is read by `_config_from_pattern_keys`."""
+    is published under three sets of key names: a config that states a
+    `sliding_window_pattern` is read by `_config_from_pattern_keys`, one whose
+    `model_type` is "deepseek_v3" (every layer full) by
+    `_config_from_all_full_keys`, any other by its `layer_types`."""
     if "sliding_window_pattern" in hf:
         return _config_from_pattern_keys(hf, experts_held, expert_lo, compute_dtype, param_dtype)
+    if hf.get("model_type") == "deepseek_v3":
+        return _config_from_all_full_keys(hf, experts_held, expert_lo, compute_dtype, param_dtype)
     routed = hf.get("published", {}).get("n_routed_experts", hf["n_routed_experts"])
     held = experts_held if experts_held is not None else hf["n_routed_experts"]
     layers = hf["num_hidden_layers"]
@@ -272,6 +333,48 @@ def _config_from_pattern_keys(hf: dict, experts_held, expert_lo, compute_dtype, 
     )
 
 
+def _config_from_all_full_keys(hf: dict, experts_held, expert_lo, compute_dtype, param_dtype) -> LatentConfig:
+    """The key names of the all-full form (DeepSeek-V3's): every layer full,
+    every query head its own KV head (`num_key_value_heads` = heads), no key
+    selection, output gate or latent rescale; rotary on interleaved pairs,
+    YaRN-scaled (`rope_scaling` of type "yarn") wherever the published longest
+    context passes the original one; `n_group` / `topk_group` limit the
+    choice to the best groups of experts; `n_routed_experts` the experts HELD
+    beside `published.n_routed_experts`; `first_k_dense_replace` leading dense
+    layers. A decode tick's rows go through every held expert
+    (`tick_experts_every_row`), as in the grouped-differential form."""
+    if "layer_types" in hf:
+        raise ValueError("the all-full form states no layer_types: every layer is full")
+    if hf.get("scoring_func", "sigmoid") != "sigmoid" or hf["num_key_value_heads"] != hf["num_attention_heads"]:
+        raise ValueError("the all-full form is read with sigmoid routing and one KV head a query head")
+    yarn = ()
+    scaling = hf.get("rope_scaling") or {}
+    if scaling:
+        if scaling.get("type", scaling.get("rope_type")) != "yarn" or scaling.get("mscale", 1) != scaling.get("mscale_all_dim", 1):
+            raise ValueError(f"rope_scaling {scaling}: only YaRN with mscale equal to mscale_all_dim is read")
+        if hf["max_position_embeddings"] > scaling["original_max_position_embeddings"]:
+            yarn = (float(scaling["factor"]), int(scaling["original_max_position_embeddings"]),
+                    float(scaling["beta_fast"]), float(scaling["beta_slow"]), float(scaling.get("mscale_all_dim", 1)))
+    spec = AttnSpec(hf["num_attention_heads"], hf["qk_nope_head_dim"], hf["qk_rope_head_dim"], hf["v_head_dim"],
+                    hf["q_lora_rank"], hf["kv_lora_rank"], float(hf["rope_theta"]), gate="none",
+                    rope_interleaved=True, yarn=yarn)
+    routed = hf.get("published", {}).get("n_routed_experts", hf["n_routed_experts"])
+    return LatentConfig(
+        dim=hf["hidden_size"], vocab_size=hf["vocab_size"], layer_types=(FULL,) * hf["num_hidden_layers"],
+        full=spec, window=spec, window_size=0, index_heads=0, index_dim=0, index_topk=0,
+        dense_width=hf["intermediate_size"], expert_width=hf["moe_intermediate_size"],
+        n_experts=routed, experts_per_token=hf["num_experts_per_tok"],
+        experts_held=experts_held if experts_held is not None else hf["n_routed_experts"],
+        expert_lo=expert_lo, first_dense=hf["first_k_dense_replace"], rescale_latents=False,
+        select_bias=hf.get("topk_method") == "noaux_tc",
+        route_scale=float(hf["routed_scaling_factor"]) if hf.get("norm_topk_prob", True) else 1.0,
+        route_groups=hf.get("n_group", 1), route_topk_groups=hf.get("topk_group", 1),
+        tick_experts_every_row=True,
+        norm_eps=hf["rms_norm_eps"], max_position_embeddings=hf["max_position_embeddings"],
+        compute_dtype=jnp.dtype(compute_dtype), param_dtype=jnp.dtype(param_dtype),
+    )
+
+
 def tiny_config(**kw) -> LatentConfig:
     """The CPU tests' preset: five layers of the same kinds, a top-k and a
     window smaller than a test's sequence, more experts than are held."""
@@ -305,6 +408,24 @@ def tiny_diff_config(**kw) -> LatentConfig:
     return LatentConfig(**base)
 
 
+def tiny_full_config(**kw) -> LatentConfig:
+    """The CPU tests' preset of the all-full form: five full layers of 4
+    heads, each its own KV head, no key selection or gate, interleaved
+    rotary under YaRN whose original context (16) a test's sequence passes,
+    so the blend and the softmax's temperature are live; 16 experts in 4
+    routing groups of which the best 2 are kept, 2 experts held."""
+    spec = AttnSpec(heads=4, nope=16, rope=8, v=16, q_rank=32, kv_rank=24, theta=100.0, gate="none",
+                    rope_interleaved=True, yarn=(4.0, 16, 4.0, 1.0, 1.0))
+    base = dict(
+        dim=64, vocab_size=97, layer_types=(FULL,) * 5, full=spec, window=spec, window_size=0,
+        index_heads=0, index_dim=0, index_topk=0, dense_width=128, expert_width=32, n_experts=16,
+        experts_per_token=4, experts_held=2, rescale_latents=False, route_scale=2.5, route_groups=4,
+        route_topk_groups=2, max_position_embeddings=4096, compute_dtype=jnp.float32, param_dtype=jnp.float32,
+    )
+    base.update(kw)
+    return LatentConfig(**base)
+
+
 # -- parameters ---------------------------------------------------------------
 
 
@@ -326,8 +447,10 @@ def _init_attention(rng, cfg: LatentConfig, spec: AttnSpec, indexer: bool) -> di
         "kv_norm": jnp.ones((spec.kv_rank,), jnp.float32),
         "kv_b": _uniform(next(k), (spec.kv_rank, spec.groups, spec.nope + spec.v), spec.kv_rank, dt),
         "o": _uniform(next(k), (out_heads, spec.v, d), out_heads * spec.v, dt),
-        "gate": _uniform(next(k), (d, gate_width), d, dt),
     }
+    gate_key = next(k)  # drawn whether or not there is a gate: the keys after it stay where they were
+    if spec.gate != "none":
+        out["gate"] = _uniform(gate_key, (d, gate_width), d, dt)
     if indexer:
         out.update(
             idx_q=_uniform(next(k), (spec.q_rank, cfg.index_heads, cfg.index_dim), spec.q_rank, dt),
@@ -429,18 +552,20 @@ def _window_back_pages(cfg: LatentConfig, page_size: int) -> int:
 
 
 def page_kinds(cfg: LatentConfig, page_size: int, kv_dtype: str):
-    """Two kinds of page: a full layer's latent row (and indexer key, where
-    keys are selected) a token, for the whole context; a window layer's
-    latent row, as a ring of the pages a window can touch (`ceil(window / P) +
-    1`: the window's span at any alignment, and the page being written)."""
+    """A full layer's latent row (and indexer key, where keys are selected) a
+    token, for the whole context; where there are window layers, a second
+    kind, a window layer's latent row, as a ring of the pages a window can
+    touch (`ceil(window / P) + 1`: the window's span at any alignment, and the
+    page being written). With one kind every layer keeps the whole context in
+    pages, so the engine's prefix registry can serve them."""
     from tpukit.serve import paged as paged_lib  # lazy: tpukit.serve imports tpukit.model
 
     item = jnp.dtype(paged_lib.storage_dtype(kv_dtype)).itemsize
+    full = paged_lib.PageKind("bt", cfg.layers_of(FULL), page_size * (cfg.full.row + cfg.index_dim) * item)
+    if not cfg.layers_of(WINDOW):
+        return (full,)
     ring = -(-cfg.window_size // page_size) + 1
-    return (
-        paged_lib.PageKind("bt", cfg.layers_of(FULL), page_size * (cfg.full.row + cfg.index_dim) * item),
-        paged_lib.PageKind("bt_w", cfg.layers_of(WINDOW), page_size * cfg.window.row * item, ring_pages=ring),
-    )
+    return full, paged_lib.PageKind("bt_w", cfg.layers_of(WINDOW), page_size * cfg.window.row * item, ring_pages=ring)
 
 
 def init_kv_cache(cfg: LatentConfig, batch: int, max_len: int) -> dict:
@@ -457,7 +582,8 @@ def init_paged_cache(cfg: LatentConfig, num_pages: dict, page_size: int, pages_p
     [Lw, NPw, P, kv_rank_w + rope_w]` behind the ring table `bt_w [N, R]`,
     and `moe_rows`, two counters the decode ticks add to (rows the held
     experts computed; the fullest expert's, summed over layers and ticks).
-    `num_pages`: pages of each pool, by block-table key. Without key selection
+    `num_pages`: pages of each pool, by block-table key. Without window
+    layers there is no `win` and no `bt_w`. Without key selection
     there is no `idx` and no `sel_keys` (two counters of the decode ticks'
     selection, `counters`); with hyper-connections there is `health`, two
     float32 gauges of the decode ticks."""
@@ -465,14 +591,15 @@ def init_paged_cache(cfg: LatentConfig, num_pages: dict, page_size: int, pages_p
 
     if kv_dtype == "int8":
         raise ValueError("the latent family's pages are f32 or bf16 rows: no int8 row quantizer exists")
-    full, win = page_kinds(cfg, page_size, kv_dtype)
+    full, *window = page_kinds(cfg, page_size, kv_dtype)
     cache = {
         "lat": paged_lib.init_row_pool(full.layers, num_pages["bt"], page_size, cfg.full.row, kv_dtype),
-        "win": paged_lib.init_row_pool(win.layers, num_pages["bt_w"], page_size, cfg.window.row, kv_dtype),
         "bt": jnp.zeros((slots, pages_per_slot), jnp.int32),
-        "bt_w": jnp.zeros((slots, win.ring_pages), jnp.int32),
         "moe_rows": jnp.zeros((2,), jnp.int32),
     }
+    for win in window:
+        cache["win"] = paged_lib.init_row_pool(win.layers, num_pages["bt_w"], page_size, cfg.window.row, kv_dtype)
+        cache["bt_w"] = jnp.zeros((slots, win.ring_pages), jnp.int32)
     if cfg.index_topk:
         cache["idx"] = paged_lib.init_row_pool(full.layers, num_pages["bt"], page_size, cfg.index_dim, kv_dtype)
         cache["sel_keys"] = jnp.zeros((2,), jnp.int32)
@@ -486,13 +613,14 @@ def select_lanes(cache: dict, slots, prompt_lens) -> dict:
     rows of both tables, and how far each lane's prompt really reaches
     (`valid`): a window layer writes no page that holds only chunk padding,
     because in a ring such a page would land on one still inside the window."""
-    return dict(cache, bt=cache["bt"][slots], bt_w=cache["bt_w"][slots], valid=prompt_lens)
+    tables = {key: cache[key][slots] for key in ("bt", "bt_w") if key in cache}
+    return dict(cache, **tables, valid=prompt_lens)
 
 
 def merge_lanes(cache: dict, sub: dict) -> dict:
     """The whole cache again: the pools carry the chunk's writes, the tables
     are the engine's."""
-    out = dict(sub, bt=cache["bt"], bt_w=cache["bt_w"])
+    out = dict(sub, **{key: cache[key] for key in ("bt", "bt_w") if key in cache})
     del out["valid"]
     return out
 
@@ -555,6 +683,25 @@ def _rope(x, pos, theta: float):
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], axis=-1)
 
 
+def _rotate(x, pos, spec: AttnSpec):
+    """The spec's rotary on the last axis of `x [..., T, (heads,) R]`: `_rope`,
+    or pairs `(x[2i], x[2i+1])` turned by `pos * f_i`, `f` plain or YaRN's
+    (`yarn_inv_freq`, a host constant), float32."""
+    if not spec.yarn and not spec.rope_interleaved:
+        return _rope(x, pos, spec.theta)
+    r = x.shape[-1]
+    with jax.named_scope("yarn" if spec.yarn else "rope"):
+        inv = (yarn_inv_freq(r, spec.theta, *spec.yarn[:4]) if spec.yarn
+               else (1.0 / spec.theta ** (np.arange(0, r, 2, dtype=np.float64) / r)).astype(np.float32))
+        ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(inv)
+        if x.ndim == pos.ndim + 2:
+            ang = ang[..., None, :]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (r // 2, 2))
+        a, b = xf[..., 0], xf[..., 1]
+        return jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1).reshape(x.shape)
+
+
 @jax.named_scope("poly_norm")
 def _poly_norm(z, w, b, cfg: LatentConfig):
     """`scale (w1 N(z) + w2 N(z^2) + w3 N(z^3) + clamp(b))` over the last axis
@@ -583,7 +730,8 @@ def _expert_layer(p, cfg: LatentConfig, x, row_mask, tick: bool = False):
     """`x [T, dim]` -> `(y [T, dim] float32, rows [experts_held])`; `tick`: the
     rows are a decode tick's lanes."""
     with jax.named_scope("router"):
-        idx, gates = sigmoid_topk_route(x, p["router"], p.get("select_bias"), cfg.experts_per_token, cfg.route_scale)
+        idx, gates = sigmoid_topk_route(x, p["router"], p.get("select_bias"), cfg.experts_per_token, cfg.route_scale,
+                                        cfg.route_groups, cfg.route_topk_groups)
     by_row = None
     if cfg.activation == "poly_norm":
         def by_row(z, expert):  # each sorted row under its own expert's weights (a row of no expert: the last's)
@@ -607,11 +755,11 @@ def _latent_qkv(a, cfg: LatentConfig, spec: AttnSpec, h, pos):
     with jax.named_scope("latent_q"):
         c_q = s_q * _rms_norm(_mm(h, a["q_a"], dt), a["q_norm"], cfg.norm_eps)
         q = _mm(c_q, a["q_b"], dt, "btr,rhd->bthd")
-        q = jnp.concatenate([q[..., : spec.nope], _rope(q[..., spec.nope:], pos, spec.theta)], axis=-1).astype(dt)
+        q = jnp.concatenate([q[..., : spec.nope], _rotate(q[..., spec.nope:], pos, spec)], axis=-1).astype(dt)
     with jax.named_scope("latent_kv"):
         kv = _mm(h, a["kv_a"], dt)
         c_kv = s_kv * _rms_norm(kv[..., : spec.kv_rank], a["kv_norm"], cfg.norm_eps)
-        row = jnp.concatenate([c_kv, _rope(kv[..., spec.kv_rank:], pos, spec.theta)], axis=-1).astype(dt)
+        row = jnp.concatenate([c_kv, _rotate(kv[..., spec.kv_rank:], pos, spec)], axis=-1).astype(dt)
     return c_q, q, row
 
 
@@ -621,7 +769,7 @@ def _softmax_latents(q_cat, keys, mask, spec: AttnSpec, dtype):
     latents `[Q, H, kv_rank]` float32. The softmax is float32; a query with
     no key yet (never read) gives 0, not NaN."""
     scores = jnp.einsum("qhr,kr->qhk", q_cat, keys, preferred_element_type=jnp.float32)
-    scores = scores * (1.0 / math.sqrt(spec.nope + spec.rope))
+    scores = scores * spec.scale
     scores = jnp.where(mask[:, None, :], scores, -jnp.inf)
     top = jnp.max(scores, axis=-1, keepdims=True)
     e = jnp.exp(scores - jnp.where(jnp.isfinite(top), top, 0.0))
@@ -674,7 +822,7 @@ def _attend_paged(q_cat, pool, li: int, bt, q_pos, spec: AttnSpec, dtype, select
     or over those `select [B, Q, MP * P]` marks (`SELECT_KEY_BLOCK` at a
     time), by `walk_key_blocks`: a row's whole width is its key, its latent
     the value. Returns the weighted latents `[B, Q, H, kv_rank]` float32."""
-    return walk_key_blocks(q_cat, pool, li, bt, q_pos, dtype, 1.0 / math.sqrt(spec.nope + spec.rope),
+    return walk_key_blocks(q_cat, pool, li, bt, q_pos, dtype, spec.scale,
                            values=slice(None, spec.kv_rank), select=select,
                            key_block=KEY_BLOCK if select is None else SELECT_KEY_BLOCK)
 
@@ -728,11 +876,13 @@ def walk_key_blocks(q, pool, li: int, bt, q_pos, dtype, scale: float, values: sl
 
 def _finish_attention(a, cfg: LatentConfig, spec: AttnSpec, h, o):
     """Attended values `[B, T, H, v]` -> the sigmoid gate (a value a head, or
-    a channel), the output projection. `[B, T, dim]` float32."""
+    a channel; none where the spec has none), the output projection. `[B, T,
+    dim]` float32."""
     dt = cfg.compute_dtype
-    with jax.named_scope("gate"):
-        gate = jax.nn.sigmoid(_mm(h, a["gate"], dt))
-        o = o * (gate.reshape(o.shape) if spec.gate == "elementwise" else gate[..., None])
+    if spec.gate != "none":
+        with jax.named_scope("gate"):
+            gate = jax.nn.sigmoid(_mm(h, a["gate"], dt))
+            o = o * (gate.reshape(o.shape) if spec.gate == "elementwise" else gate[..., None])
     return _mm(o, a["o"], dt, "bthv,hvd->btd")
 
 
@@ -1035,7 +1185,7 @@ def _forward_cached(params: Params, cfg: LatentConfig, input_ids, position_ids, 
         raise ValueError(f"a chunk of {t} tokens is not whole pages of {p}")
     if write_mask is None:
         write_mask = jnp.ones((b,), bool)
-    lat, idx, win, rows_seen = cache["lat"], cache.get("idx"), cache["win"], cache["moe_rows"]
+    lat, idx, win, rows_seen = cache["lat"], cache.get("idx"), cache.get("win"), cache["moe_rows"]
     keys_seen = cache.get("sel_keys")
     valid = cache.get("valid")
     # the decode ticks' gauges, where the cache keeps them: what the sublayers hand out
@@ -1084,7 +1234,9 @@ def _forward_cached(params: Params, cfg: LatentConfig, input_ids, position_ids, 
         with jax.named_scope("ln"):
             x = _rms_norm(x, params["norm_out"], cfg.norm_eps)
         logits = _mm(x, params["lm_head"], cfg.compute_dtype).astype(cfg.compute_dtype)
-    out = dict(cache, lat=lat, win=win, moe_rows=rows_seen)
+    out = dict(cache, lat=lat, moe_rows=rows_seen)
+    if win is not None:
+        out["win"] = win
     if idx is not None:
         out["idx"], out["sel_keys"] = idx, keys_seen
     if health is not None:
@@ -1141,6 +1293,6 @@ def forward(params: Params, cfg: LatentConfig, input_ids, position_ids=None, mas
     cache = init_paged_cache(cfg, {"bt": b * pages + 1, "bt_w": b * pages + 1}, page_size, pages, b, kv)
     own = 1 + jnp.arange(b * pages, dtype=jnp.int32).reshape(b, pages)
     # a ring as wide as the sequence never wraps: the scratch window store holds every page
-    cache = dict(cache, bt=own, bt_w=own, valid=jnp.full((b,), s, jnp.int32))
+    cache = dict(cache, bt=own, valid=jnp.full((b,), s, jnp.int32), **({"bt_w": own} if "bt_w" in cache else {}))
     logits, _ = forward_cached(params, cfg, ids, pos, cache, jnp.zeros((b,), jnp.int32))
     return logits[:, :s]

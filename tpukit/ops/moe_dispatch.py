@@ -402,21 +402,43 @@ def expected_a2a(cfg, data_size: int, expert_size: int, global_batch: int,
 
 
 @jax.named_scope("router")
-def sigmoid_topk_route(x, router_kernel, select_bias, top_k: int, scale: float = 1.0):
+def sigmoid_topk_route(x, router_kernel, select_bias, top_k: int, scale: float = 1.0, groups: int = 1,
+                       topk_groups: int = 1):
     """Sigmoid scores over ALL experts, the `top_k` of largest `score + bias`
     (the bias steers the choice only, `noaux_tc`; `None`: the scores alone
     choose), gates = the chosen scores normalised over the chosen
     (`norm_topk_prob`), whoever holds them, times `scale`.
     x `[T, D]`; returns `(idx [T, k] int32, gates [T, k] float32)`. Scores are
     float32 at full matmul precision: a bf16 pass reorders near-ties at the
-    k-th place."""
+    k-th place.
+
+    `groups` > 1: node-limited routing (DeepSeek-V3's `Gate`): the experts in
+    `groups` equal groups, a group's score the sum of its two largest biased
+    scores (its largest without a bias), and the `top_k` chosen among the
+    experts of the best `topk_groups` groups only."""
     logits = jnp.matmul(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(scores if select_bias is None else scores + select_bias.astype(jnp.float32), top_k)
+    choice = scores if select_bias is None else scores + select_bias.astype(jnp.float32)
+    if groups > 1:
+        choice = _limit_to_groups(choice, groups, topk_groups, biased=select_bias is not None)
+    _, idx = jax.lax.top_k(choice, top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     gates = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
     return idx.astype(jnp.int32), gates if scale == 1.0 else scale * gates
+
+
+@jax.named_scope("group_route")
+def _limit_to_groups(choice, groups: int, topk_groups: int, biased: bool):
+    """`choice [T, E]` with every expert outside the best `topk_groups` of
+    `groups` equal groups set to -inf; a group's score is the sum of its two
+    largest entries (its largest where `biased` is False)."""
+    t, e = choice.shape
+    by_group = choice.reshape(t, groups, e // groups)
+    top = jax.lax.top_k(by_group, 2 if biased else 1)[0]
+    _, best = jax.lax.top_k(jnp.sum(top, axis=-1), topk_groups)  # [T, topk_groups]
+    kept = jnp.any(best[:, :, None] == jnp.arange(groups)[None, None, :], axis=1)  # [T, groups]
+    return jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(t, e)
 
 
 @jax.named_scope("experts")

@@ -700,6 +700,12 @@ class ServeEngine:
         self.buckets_used: set[int] = set()
         self.steps = 0
         self.admitted = 0
+        # prompt tokens of the admitted requests, and of them the ones the
+        # prefix registry served from shared pages (never prefilled); the
+        # quanta carry each since the previous quantum's sync
+        self.admitted_tokens = 0
+        self.prefix_tokens = 0
+        self._tokens_at_sync = (0, 0)
         self.max_live = 0
         self.evicted = {"eos": 0, "length": 0, "deadline": 0}
         # rids pinned past natural retirement (stuck_request@RID chaos,
@@ -800,10 +806,11 @@ class ServeEngine:
             for slot, req, plen in entries:
                 self._lanes[slot] = _Lane(req, now, plen, bucket, active_s=now)
                 self.admitted += 1
+                self.admitted_tokens += plen
                 if tr is not None:
                     tid = trace_id(req)
                     tr.emit("admit", tid, rid=req.rid, t=now, slot=slot,
-                            replica=self.replica)
+                            replica=self.replica, shared_tokens=0)
                     tr.emit("prefill", tid, rid=req.rid, t0=sp.t0, t1=sp.t1,
                             cpu=sp.cpu, chunk=0, replica=self.replica)
                     tr.emit("prefill_done", tid, rid=req.rid, t=sp.t1,
@@ -881,11 +888,14 @@ class ServeEngine:
             key=np.asarray(jax.random.PRNGKey(req.seed), np.uint32),
         )
         self.admitted += 1
+        self.admitted_tokens += plen
+        self.prefix_tokens += s_tokens
         self.max_live = max(self.max_live, len(self._lanes))
         self.buckets_used.add(bucket)
         if self.tracer is not None:
             self.tracer.emit("admit", trace_id(req), rid=req.rid, t=now,
-                             slot=slot, replica=self.replica)
+                             slot=slot, replica=self.replica,
+                             shared_tokens=s_tokens)
         if shared:
             self.allocator.stats.prefix_hits += 1
             self.allocator.stats.prefix_pages_reused += len(shared)
@@ -1343,6 +1353,10 @@ class ServeEngine:
         since, self._host_from = self._host_from, s1
         if q is None:  # a sync with nothing dispatched (step primitives driven by hand)
             return
+        # prompt tokens admitted, and served from the registry, since the last quantum's sync
+        admitted0, prefix0 = self._tokens_at_sync
+        q["admitted_tokens"], q["prefix_tokens"] = self.admitted_tokens - admitted0, self.prefix_tokens - prefix0
+        self._tokens_at_sync = (self.admitted_tokens, self.prefix_tokens)
         host["other"] = max(s0 - since - sum(host.values()), 0.0)
         period = s1 - since
         if len(self._periods) >= SLOW_QUANTUM_MIN_HISTORY:
